@@ -135,6 +135,9 @@ def run() -> List[str]:
 
 
 def main():
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None)
     args = ap.parse_args()

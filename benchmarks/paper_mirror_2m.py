@@ -16,17 +16,16 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-
 from repro.configs import ShapeConfig, get_config
 from repro.core.parallel import ParallelContext
 from repro.launch import steps as ST
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.dryrun import parse_collectives
 from repro.launch.mesh import dp_axes_of, make_production_mesh
 
 
 def main():
+    setup_compile_cache()
     shape = ShapeConfig("train_2m", 2_097_152, 1, "train")
     mesh = make_production_mesh(multi_pod=False)
     par = ParallelContext(mesh=mesh, dp_axes=dp_axes_of(mesh),

@@ -34,12 +34,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-
 from repro.configs import ASSIGNED_ARCHS, SHAPES, get_config, shape_applicable
 from repro.core.parallel import ParallelContext
 from repro.launch import steps as ST
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import dp_axes_of, make_production_mesh
 
 from benchmarks import flops_model as FM
@@ -142,6 +140,7 @@ def analyze_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

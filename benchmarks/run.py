@@ -48,6 +48,9 @@ def main() -> None:
                          "perf-trajectory format)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     from benchmarks import compile_scaling
     from benchmarks import kernels_bench
     from benchmarks import paper_tables as PT

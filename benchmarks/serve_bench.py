@@ -956,6 +956,14 @@ def run_serve_mesh() -> List[str]:
     count, plus routed-vs-round-robin tok/s and prefix-hit."""
     import subprocess
 
+    if jax.default_backend() != "cpu":
+        # the worker forces 8 fake CPU devices in a child process; a child
+        # cannot use the accelerator this process already holds
+        msg = (f"serve_mesh runs only on CPU (its worker forces 8 host "
+               f"devices in a child process); this process holds the "
+               f"{jax.default_backend()} backend -- rerun with JAX_PLATFORMS=cpu")
+        print(msg)
+        return ["bench,name,value,derived", f"bench,ERROR,1,{msg}"]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -1319,6 +1327,9 @@ def run() -> List[str]:
 
 
 def main():
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None)
     ap.add_argument("--mesh-worker", action="store_true",

@@ -16,9 +16,10 @@ One implementation covers both the paper's baseline and its contribution:
                      j+1 is issued before the chunk-j kernel, so the async
                      copy-start/copy-done pair overlaps chunk compute by
                      program order.  All residency decisions route through
-                     ``runtime.placement.PlacementPolicy`` — on a backend
-                     with no pinned-host pool (e.g. CPU) offload degrades
-                     to a no-op and the pipeline still matches u=1 exactly.
+                     ``runtime.placement.PlacementPolicy`` (a CPU without a
+                     pinned-host pool degrades offload to a no-op; an
+                     accelerator without one refuses it), and the pipeline
+                     matches u=1 either way.
 
 Two compilation strategies for the u>1 pipeline:
 
@@ -128,6 +129,44 @@ def _host_spec_kv(par: ParallelContext, kind: str, n_heads: int, chunk_len: int)
     return (par.dp_axes, None, None, None)
 
 
+def _chunk_kernels(par: ParallelContext, kind: str, impl: str, n_kv_heads: int):
+    """(chunk_fwd, chunk_bwd_dq, chunk_bwd_dkv) as the pipeline calls them.
+
+    GSPMD cannot partition a Mosaic kernel, so on a mesh where both q and KV
+    heads shard over the model axis (Ulysses) each Pallas kernel runs under
+    ``shard_map`` on its device's heads; GQA groups stay whole per device
+    because ``hkv % sp == 0``.  Elsewhere the ops are called as they are.
+    """
+    if par.mesh is None or kind != "ulysses" or impl != "pallas" or n_kv_heads % par.sp:
+        return fa.chunk_fwd, fa.chunk_bwd_dq, fa.chunk_bwd_dkv
+    from jax.sharding import PartitionSpec as P
+
+    heads = P(par.dp_axes, par.sp_axis, None, None)
+    rows = P(par.dp_axes, par.sp_axis, None)
+
+    def per_device(op, n_in, out_specs):
+        def call(*args, q_offset, k_offset, **kw):
+            def body(*a):
+                return op(*a[:n_in], q_offset=a[n_in], k_offset=a[n_in + 1], **kw)
+
+            specs = tuple(rows if a.ndim == 3 else heads for a in args[:n_in])
+            return jax.shard_map(body, mesh=par.mesh, in_specs=specs + (P(), P()),
+                                 out_specs=out_specs, check_vma=False)(
+                *args[:n_in], jnp.asarray(q_offset, jnp.int32),
+                jnp.asarray(k_offset, jnp.int32))
+        return call
+
+    fwd = per_device(lambda q, k, v, acc, m, l, **kw: fa.chunk_fwd(q, k, v, (acc, m, l), **kw),
+                     6, (heads, rows, rows))
+
+    def chunk_fwd(q, k, v, carry=None, **kw):
+        carry = tuple(zero_state_like(q)) if carry is None else tuple(carry)
+        return fwd(q, k, v, *carry, **kw)
+
+    return (chunk_fwd, per_device(fa.chunk_bwd_dq, 6, heads),
+            per_device(fa.chunk_bwd_dkv, 6, (heads, heads)))
+
+
 # ---------------------------------------------------------------------------
 # chunk-pair liveness (window band / block sparsity)
 # ---------------------------------------------------------------------------
@@ -184,6 +223,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     hq, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     rep = _kv_rep(cfg, par, kind)
     impl = par.attn_impl
+    chunk_fwd, chunk_bwd_dq, chunk_bwd_dkv = _chunk_kernels(par, kind, impl, hkv * rep)
     bq, bk = cfg.block_q, cfg.block_k
     assert seq_len % u == 0, (seq_len, u)
     cq = seq_len // u
@@ -254,8 +294,8 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
                 return to_dev(kj), to_dev(vj)
 
             for j, (kj, vj) in zip(live, double_buffered(live, fetch_kv)):
-                carry = fa.chunk_fwd(qi, kj, vj, carry, **pair_kwargs(i, j))
-            carry = fa.chunk_fwd(qi, ki, vi, carry, **pair_kwargs(i, i))
+                carry = chunk_fwd(qi, kj, vj, carry, **pair_kwargs(i, j))
+            carry = chunk_fwd(qi, ki, vi, carry, **pair_kwargs(i, i))
             st = SoftmaxState(*carry)
             oi = finalize(st)  # [b, h, cq, dh] fp32
             Li = lse(st)
@@ -304,8 +344,8 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             inner = [i for i in range(j, u) if live_py(i, j)]
             for i, qi in zip(inner, double_buffered(inner, fetch_q)):
                 kwargs = pair_kwargs(i, j)
-                dk_c, dv_c = fa.chunk_bwd_dkv(qi, kj, vj, dos[i], Ls[i], deltas[i], **kwargs)
-                dq_c = fa.chunk_bwd_dq(qi, kj, vj, dos[i], Ls[i], deltas[i], **kwargs)
+                dk_c, dv_c = chunk_bwd_dkv(qi, kj, vj, dos[i], Ls[i], deltas[i], **kwargs)
+                dq_c = chunk_bwd_dq(qi, kj, vj, dos[i], Ls[i], deltas[i], **kwargs)
                 dks[j] = dk_c if dks[j] is None else dks[j] + dk_c
                 dvs[j] = dv_c if dvs[j] is None else dvs[j] + dv_c
                 dqs[i] = dq_c if dqs[i] is None else dqs[i] + dq_c
@@ -340,16 +380,17 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         dkf = dk.reshape(b, cq, hkv * dh)
         dvf = dv.reshape(b, cq, hkv * dh)
         dx = dqf @ p["wq"].T + dkf @ p["wk"].T + dvf @ p["wv"].T
+        # fp32 contributions: dW sums over u chunks, cast once at the end
         contrib = {
-            "wq": jnp.einsum("bsd,bse->de", xi, dqf),
-            "wk": jnp.einsum("bsd,bse->de", xi, dkf),
-            "wv": jnp.einsum("bsd,bse->de", xi, dvf),
-        }
+            n: jnp.einsum("bsd,bse->de", xi, g, preferred_element_type=jnp.float32)
+            for n, g in (("wq", dqf), ("wk", dkf), ("wv", dvf))}
         if cfg.qkv_bias:
-            contrib["bq"] = jnp.sum(dqf, axis=(0, 1))
-            contrib["bk"] = jnp.sum(dkf, axis=(0, 1))
-            contrib["bv"] = jnp.sum(dvf, axis=(0, 1))
+            contrib.update({n: jnp.sum(g.astype(jnp.float32), axis=(0, 1))
+                            for n, g in (("bq", dqf), ("bk", dkf), ("bv", dvf))})
         return dx, contrib
+
+    def _cast_like(dp, p):
+        return {n: g.astype(p[n].dtype) for n, g in dp.items()}
 
     def _unproject_unrolled(x, p, dq_stack, dk_stack, dv_stack):
         b = x.shape[0]
@@ -364,7 +405,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         if par.mesh is not None:
             dx = par.seq_sharded(dx)
         # wo is not part of this custom_vjp (out_proj applied by caller)
-        return dx, dp
+        return dx, _cast_like(dp, p)
 
     # ================= scan-compiled path (default for u > 1) ===============
     #
@@ -374,9 +415,13 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     # dynamic_update_slice and read back chunk-by-chunk through the
     # double-buffered fori_loop.  HLO contains ONE copy of the chunk body.
 
-    def _store_kv(store, chunk, i):
-        store = jax.lax.dynamic_update_index_in_dim(store, chunk, i, axis=0)
-        return to_host(store, kv_store_spec)
+    # Stores live in the offload pool and no op mixes memory spaces: a chunk
+    # moves host-ward first and is written host-into-host; a read slices the
+    # host store and then fetches the slice (XLA's host-offload pass turns
+    # both into DMAs).
+    def _store(store, chunk, i, spec):
+        return jax.lax.dynamic_update_index_in_dim(store, to_host(chunk, spec),
+                                                   i, axis=0)
 
     def _load(store, j, spec):
         return to_dev(jax.lax.dynamic_index_in_dim(store, j, axis=0,
@@ -404,18 +449,17 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
 
             def pair(j, kv, st):
                 kj, vj = kv
-                return tuple(fa.chunk_fwd(qi, kj, vj, tuple(st), **pair_kwargs(i, j)))
+                return tuple(chunk_fwd(qi, kj, vj, tuple(st), **pair_kwargs(i, j)))
 
             st = fori_double_buffered(
                 0, i, fetch_kv, pair, tuple(zero_state_like(qi)),
                 live=lambda j: live_tr(i, j))
-            st = SoftmaxState(*fa.chunk_fwd(qi, ki, vi, st, **pair_kwargs(i, i)))
+            st = SoftmaxState(*chunk_fwd(qi, ki, vi, st, **pair_kwargs(i, i)))
             oi = finalize(st)  # [b, hq, cq, dh] fp32
             Li = lse(st)
-            kst = _store_kv(kst, ki, i)
-            vst = _store_kv(vst, vi, i)
-            qst = to_host(jax.lax.dynamic_update_index_in_dim(qst, qi, i, axis=0),
-                          q_store_spec)
+            kst = _store(kst, ki, i, kv_spec)
+            vst = _store(vst, vi, i, kv_spec)
+            qst = _store(qst, qi, i, q_spec)
             # back to token layout + seq sharding (inverse all-to-all)
             ot = oi.astype(x.dtype).transpose(0, 2, 1, 3)  # [b, cq, hq, dh]
             if par.mesh is not None and kind != "local":
@@ -457,8 +501,8 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
                 Li = jax.lax.dynamic_index_in_dim(Lst, i, axis=0, keepdims=False)
                 di = jax.lax.dynamic_index_in_dim(deltas, i, axis=0, keepdims=False)
                 kwargs = pair_kwargs(i, j)
-                dk_c, dv_c = fa.chunk_bwd_dkv(qi, kj, vj, doi, Li, di, **kwargs)
-                dq_c = fa.chunk_bwd_dq(qi, kj, vj, doi, Li, di, **kwargs)
+                dk_c, dv_c = chunk_bwd_dkv(qi, kj, vj, doi, Li, di, **kwargs)
+                dq_c = chunk_bwd_dq(qi, kj, vj, doi, Li, di, **kwargs)
                 dq_i = jax.lax.dynamic_index_in_dim(dq_acc, i, axis=0, keepdims=False)
                 dq_acc = jax.lax.dynamic_update_index_in_dim(dq_acc, dq_i + dq_c, i, axis=0)
                 return dk + dk_c, dv + dv_c, dq_acc
@@ -482,17 +526,12 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             dx, contrib = _unproject_body(p, i, xi, dq, dk, dv, b)
             return jax.tree.map(jnp.add, carry, contrib), dx
 
-        dp0 = {"wq": jnp.zeros_like(p["wq"]), "wk": jnp.zeros_like(p["wk"]),
-               "wv": jnp.zeros_like(p["wv"])}
-        if cfg.qkv_bias:
-            dp0.update({"bq": jnp.zeros_like(p["bq"]),
-                        "bk": jnp.zeros_like(p["bk"]),
-                        "bv": jnp.zeros_like(p["bv"])})
+        dp0 = {n: jnp.zeros(w.shape, jnp.float32) for n, w in p.items()}
         dp, dxs = jax.lax.scan(unproj, dp0, (jnp.arange(u), xs, dqs, dks, dvs))
         dx = dxs.swapaxes(0, 1).reshape(b, seq_len, -1)
         if par.mesh is not None:
             dx = par.seq_sharded(dx)
-        return dx, dp
+        return dx, _cast_like(dp, p)
 
     fwd, bwd = (fwd_unrolled, bwd_unrolled) if unroll else (fwd_scan, bwd_scan)
 
@@ -501,7 +540,10 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         return fwd(x, p)[0]
 
     f.defvjp(fwd, bwd)
-    return f
+    # jit so fwd/bwd always trace: jax 0.9's eager slice of a host array on
+    # CPU keeps a host aval under a device sharding, which device_put then
+    # treats as already placed.
+    return jax.jit(f)
 
 
 def fpdt_attention(

@@ -8,6 +8,13 @@ Design (TPU-native, see DESIGN.md §2):
     dim (64/128/256 in our archs); block_q/block_k default 512 so a tile set
     (q + k + v + acc + p) stays well under VMEM (~4 MB at d=128, bf16 in /
     fp32 accum).
+  * Per-row softmax state (m, l, and the backward's L, delta) crosses the
+    kernel boundary lane-dense, as ``[b, h, 1, s]`` rows tiled (1, block_q),
+    so every block's last two dims are legal Mosaic tiles.  Inside a kernel
+    a row becomes a lane-replicated ``(block_q, 128)`` column (one XLU
+    transpose per q tile, at init/write) wherever it must broadcast across
+    a score row; the dkv kernel works on transposed scores ``[block_k,
+    block_q]`` so its rows broadcast over sublanes with no transpose at all.
   * Carry-in (acc, m, l) inputs let the FPDT sequence-chunk pipeline continue
     one softmax across chunk boundaries; outputs are the *unnormalized*
     running state, normalized once per chunk row at the JAX level.
@@ -21,9 +28,8 @@ Design (TPU-native, see DESIGN.md §2):
     the dkv backward kernel accumulates over the q heads of each group in its
     sequential inner grid dimension.
 
-On non-TPU backends the kernels run with interpret=True (pure-Python
-execution) — used by every test in this repo; real-TPU compilation is the
-deployment target.
+On a TPU backend the kernels compile through Mosaic; elsewhere they run with
+interpret=True (pure-Python execution), which is how the CPU tests run them.
 """
 from __future__ import annotations
 
@@ -33,17 +39,29 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128  # TPU vreg lane width: compiled blocks are multiples of it
 
 
-
-def _fit_block(n: int, block: int) -> int:
-    """Largest divisor of n that is <= block (kernel grids need divisibility)."""
+def _fit_block(n: int, block: int, *, tiled: bool = False) -> int:
+    """Block size along an axis of length ``n`` (kernel grids need
+    divisibility).  A compiled kernel (``tiled``) takes ``min(block, n)``
+    and refuses it unless it divides ``n`` and is a multiple of the 128-lane
+    tile; interpret mode and the XLA path shrink it to the largest divisor
+    of ``n`` that is ``<= block``."""
     b = min(block, n)
+    if tiled:
+        if n % b or b % LANES:
+            raise ValueError(
+                f"block {b} over an axis of length {n} does not divide it "
+                f"into multiples of the {LANES}-lane TPU tile")
+        return b
     while n % b:
         b -= 1
     return b
+
 
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -60,12 +78,69 @@ def _offsets_operand(q_offset, k_offset) -> jnp.ndarray:
 
 
 def _grid_spec(grid, in_specs, out_specs, scratch_shapes):
-    from jax.experimental.pallas import tpu as pltpu
-
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
         out_specs=out_specs, scratch_shapes=scratch_shapes,
     )
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    )
+
+
+def _rows(x: jnp.ndarray) -> jnp.ndarray:
+    """[b, h, s] row state -> the kernels' lane-dense [b, h, 1, s]."""
+    return x[:, :, None, :]
+
+
+def _unrows(x: jnp.ndarray) -> jnp.ndarray:
+    return x[:, :, 0, :]
+
+
+def _row_to_col(row: jnp.ndarray) -> jnp.ndarray:
+    """(1, n) lane row -> (n, 128) column, replicated across lanes."""
+    return jnp.broadcast_to(row, (LANES, row.shape[1])).T
+
+
+def _col_to_row(col: jnp.ndarray) -> jnp.ndarray:
+    """(n, 128) lane-replicated column -> (1, n) lane row."""
+    return col.T[:1, :]
+
+
+def _dead(offs_ref, iq, ik, *, causal, window, block_q, block_k):
+    """Block fully above the diagonal, or fully left of the window band."""
+    q_start = offs_ref[0] + iq * block_q
+    k_start = offs_ref[1] + ik * block_k
+    dead = causal & (q_start + block_q - 1 < k_start)
+    if window:
+        dead = dead | (k_start + block_k - 1 < q_start - window + 1)
+    return q_start, k_start, dead
+
+
+def _mask(s, q_start, k_start, *, causal, window, q_axis):
+    """Causal/window mask of a score tile; ``q_axis`` is the q dim of ``s``."""
+    if not causal:
+        return s
+    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    ok = qpos >= kpos
+    if window:
+        ok = ok & (qpos - kpos < window)
+    return jnp.where(ok, s, NEG_INF)
+
+
+def _nt(a, b):
+    """a @ b.T with fp32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    """a @ b with fp32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 # ===========================================================================
@@ -84,51 +159,34 @@ def _fwd_kernel(
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[...] = m_in_ref[...].astype(jnp.float32)
-        l_scr[...] = l_in_ref[...].astype(jnp.float32)
+        m_scr[...] = _row_to_col(m_in_ref[...])
+        l_scr[...] = _row_to_col(l_in_ref[...])
         acc_scr[...] = acc_in_ref[...].astype(jnp.float32)
 
-    q_start = offs_ref[0] + iq * block_q
-    k_start = offs_ref[1] + ik * block_k
-    # dead block: fully above the diagonal, or fully left of the window band
-    dead = causal & (q_start + block_q - 1 < k_start)
-    if window:
-        dead = dead | (k_start + block_k - 1 < q_start - window + 1)
+    q_start, k_start, dead = _dead(offs_ref, iq, ik, causal=causal, window=window,
+                                   block_q=block_q, block_k=block_k)
 
     @pl.when(~dead)
     def _compute():
         q = q_ref[...].astype(jnp.float32)
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [block_q, block_k]
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            ok = qpos >= kpos
-            if window:
-                ok = ok & (qpos - kpos < window)
-            s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
+        s = _nt(q, k) * sm_scale  # [block_q, block_k]
+        s = _mask(s, q_start, k_start, causal=causal, window=window, q_axis=0)
+        m_prev = m_scr[...]  # (block_q, 128), lane-replicated
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # explicit mask (don't rely on exp underflow of NEG_INF - NEG_INF)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new[:, None]))
+        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new[:, :1]))
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _nn(p, v)
         m_scr[...] = m_new
-        l_scr[...] = l_new
-        acc_scr[...] = acc_new
 
     @pl.when(ik == nk - 1)
     def _write():
         acc_out_ref[...] = acc_scr[...]
-        m_out_ref[...] = m_scr[...]
-        l_out_ref[...] = l_scr[...]
+        m_out_ref[...] = _col_to_row(m_scr[...])
+        l_out_ref[...] = _col_to_row(l_scr[...])
 
 
 def flash_fwd(
@@ -154,11 +212,11 @@ def flash_fwd(
     _, hkv, sk, _ = k.shape
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
-    block_q = _fit_block(sq, block_q)
-    block_k = _fit_block(sk, block_k)
+    interpret = _default_interpret() if interpret is None else interpret
+    block_q = _fit_block(sq, block_q, tiled=not interpret)
+    block_k = _fit_block(sk, block_k, tiled=not interpret)
     nq, nk = sq // block_q, sk // block_k
     scale = sm_scale if sm_scale is not None else d ** -0.5
-    interpret = _default_interpret() if interpret is None else interpret
 
     if carry is None:
         acc0 = jnp.zeros((b, hq, sq, d), jnp.float32)
@@ -175,46 +233,26 @@ def flash_fwd(
     grid = (b, hq, nq, nk)
     q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, h, iq, 0))
     kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, iq, ik, offs: (b_, h // g, ik, 0))
-    vec_spec = pl.BlockSpec((None, None, block_q), lambda b_, h, iq, ik, offs: (b_, h, iq))
+    row_spec = pl.BlockSpec((None, None, 1, block_q), lambda b_, h, iq, ik, offs: (b_, h, 0, iq))
+    row_shape = jax.ShapeDtypeStruct((b, hq, 1, sq), jnp.float32)
 
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
             grid,
-            [q_spec, kv_spec, kv_spec, q_spec, vec_spec, vec_spec],
-            [q_spec, vec_spec, vec_spec],
+            [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            [q_spec, row_spec, row_spec],
             [
-                _vmem((block_q,), jnp.float32),
-                _vmem((block_q,), jnp.float32),
-                _vmem((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32), row_shape, row_shape],
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(_offsets_operand(q_offset, k_offset), q, k, v, acc0, m0, l0)
-    return acc, m, l
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _compiler_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        )
-    except Exception:  # pragma: no cover
-        return None
+    )(_offsets_operand(q_offset, k_offset), q, k, v, acc0, _rows(m0), _rows(l0))
+    return acc, _unrows(m), _unrows(l)
 
 
 # ===========================================================================
@@ -225,7 +263,7 @@ def _compiler_params():
 def _dq_kernel(
     offs_ref, q_ref, k_ref, v_ref, do_ref, L_ref, delta_ref,
     dq_ref,
-    dq_scr,
+    dq_scr, L_scr, delta_scr,
     *, sm_scale, causal, window, block_q, block_k, nk,
 ):
     ik = pl.program_id(3)
@@ -234,12 +272,11 @@ def _dq_kernel(
     @pl.when(ik == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        L_scr[...] = _row_to_col(L_ref[...])
+        delta_scr[...] = _row_to_col(delta_ref[...])
 
-    q_start = offs_ref[0] + iq * block_q
-    k_start = offs_ref[1] + ik * block_k
-    dead = causal & (q_start + block_q - 1 < k_start)
-    if window:
-        dead = dead | (k_start + block_k - 1 < q_start - window + 1)
+    q_start, k_start, dead = _dead(offs_ref, iq, ik, causal=causal, window=window,
+                                   block_q=block_q, block_k=block_k)
 
     @pl.when(~dead)
     def _compute():
@@ -247,23 +284,11 @@ def _dq_kernel(
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
         do = do_ref[...].astype(jnp.float32)
-        L = L_ref[...]
-        delta = delta_ref[...]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            ok = qpos >= kpos
-            if window:
-                ok = ok & (qpos - kpos < window)
-            s = jnp.where(ok, s, NEG_INF)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - L[:, None]))
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        s = _nt(q, k) * sm_scale
+        s = _mask(s, q_start, k_start, causal=causal, window=window, q_axis=0)
+        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - L_scr[...][:, :1]))
+        ds = p * (_nt(do, v) - delta_scr[...][:, :1]) * sm_scale
+        dq_scr[...] += _nn(ds, k)
 
     @pl.when(ik == nk - 1)
     def _write():
@@ -279,11 +304,11 @@ def flash_bwd_dq(
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv
-    block_q = _fit_block(sq, block_q)
-    block_k = _fit_block(sk, block_k)
+    interpret = _default_interpret() if interpret is None else interpret
+    block_q = _fit_block(sq, block_q, tiled=not interpret)
+    block_k = _fit_block(sk, block_k, tiled=not interpret)
     nq, nk = sq // block_q, sk // block_k
     scale = sm_scale if sm_scale is not None else d ** -0.5
-    interpret = _default_interpret() if interpret is None else interpret
 
     kernel = functools.partial(
         _dq_kernel, sm_scale=scale, causal=causal, window=window,
@@ -291,19 +316,21 @@ def flash_bwd_dq(
     )
     q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, h, iq, 0))
     kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, iq, ik, offs: (b_, h // g, ik, 0))
-    vec_spec = pl.BlockSpec((None, None, block_q), lambda b_, h, iq, ik, offs: (b_, h, iq))
+    row_spec = pl.BlockSpec((None, None, 1, block_q), lambda b_, h, iq, ik, offs: (b_, h, 0, iq))
     return pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
             (b, hq, nq, nk),
-            [q_spec, kv_spec, kv_spec, q_spec, vec_spec, vec_spec],
+            [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             q_spec,
-            [_vmem((block_q, d), jnp.float32)],
+            [pltpu.VMEM((block_q, d), jnp.float32),
+             pltpu.VMEM((block_q, LANES), jnp.float32),
+             pltpu.VMEM((block_q, LANES), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(_offsets_operand(q_offset, k_offset), q, k, v, do, L, delta)
+    )(_offsets_operand(q_offset, k_offset), q, k, v, do, _rows(L), _rows(delta))
 
 
 # ===========================================================================
@@ -326,37 +353,23 @@ def _dkv_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q_start = offs_ref[0] + iq * block_q
-    k_start = offs_ref[1] + ik * block_k
-    dead = causal & (q_start + block_q - 1 < k_start)
-    if window:
-        dead = dead | (k_start + block_k - 1 < q_start - window + 1)
+    q_start, k_start, dead = _dead(offs_ref, iq, ik, causal=causal, window=window,
+                                   block_q=block_q, block_k=block_k)
 
     @pl.when(~dead)
     def _compute():
+        # transposed scores [block_k, block_q]: the (1, block_q) rows L and
+        # delta broadcast over sublanes, and every matmul is NN or NT
         q = q_ref[...].astype(jnp.float32)
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
         do = do_ref[...].astype(jnp.float32)
-        L = L_ref[...]
-        delta = delta_ref[...]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            ok = qpos >= kpos
-            if window:
-                ok = ok & (qpos - kpos < window)
-            s = jnp.where(ok, s, NEG_INF)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - L[:, None]))  # [bq, bk]
-        dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        st = _nt(k, q) * sm_scale
+        st = _mask(st, q_start, k_start, causal=causal, window=window, q_axis=1)
+        pt = jnp.where(st <= NEG_INF / 2, 0.0, jnp.exp(st - L_ref[...]))
+        dv_scr[...] += _nn(pt, do)
+        dst = pt * (_nt(v, do) - delta_ref[...]) * sm_scale
+        dk_scr[...] += _nn(dst, q)
 
     @pl.when(t == g * nq - 1)
     def _write():
@@ -373,11 +386,11 @@ def flash_bwd_dkv(
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv
-    block_q = _fit_block(sq, block_q)
-    block_k = _fit_block(sk, block_k)
+    interpret = _default_interpret() if interpret is None else interpret
+    block_q = _fit_block(sq, block_q, tiled=not interpret)
+    block_k = _fit_block(sk, block_k, tiled=not interpret)
     nq, nk = sq // block_q, sk // block_k
     scale = sm_scale if sm_scale is not None else d ** -0.5
-    interpret = _default_interpret() if interpret is None else interpret
 
     kernel = functools.partial(
         _dkv_kernel, sm_scale=scale, causal=causal, window=window,
@@ -388,16 +401,16 @@ def flash_bwd_dkv(
         (None, None, block_q, d), lambda b_, h, ik, t, offs: (b_, h * g + t // nq, t % nq, 0)
     )
     kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, ik, t, offs: (b_, h, ik, 0))
-    vec_spec = pl.BlockSpec(
-        (None, None, block_q), lambda b_, h, ik, t, offs: (b_, h * g + t // nq, t % nq)
+    row_spec = pl.BlockSpec(
+        (None, None, 1, block_q), lambda b_, h, ik, t, offs: (b_, h * g + t // nq, 0, t % nq)
     )
     return pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
             (b, hkv, nk, g * nq),
-            [q_spec, kv_spec, kv_spec, q_spec, vec_spec, vec_spec],
+            [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             [kv_spec, kv_spec],
-            [_vmem((block_k, d), jnp.float32), _vmem((block_k, d), jnp.float32)],
+            [pltpu.VMEM((block_k, d), jnp.float32), pltpu.VMEM((block_k, d), jnp.float32)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, sk, d), jnp.float32),
@@ -405,4 +418,4 @@ def flash_bwd_dkv(
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(_offsets_operand(q_offset, k_offset), q, k, v, do, L, delta)
+    )(_offsets_operand(q_offset, k_offset), q, k, v, do, _rows(L), _rows(delta))

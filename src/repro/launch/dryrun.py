@@ -26,12 +26,10 @@ import traceback
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-
 from repro.configs import ASSIGNED_ARCHS, SHAPES, get_config, shape_applicable
 from repro.core.parallel import ParallelContext
 from repro.launch import steps as ST
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.hlo import count_ops
 from repro.launch.mesh import dp_axes_of, make_production_mesh
 from repro.runtime.placement import PlacementPolicy
@@ -156,8 +154,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, chunks=None, offload=N
             "host_argument_bytes": ma.host_argument_size_in_bytes,
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per program
-            ca = ca[0] if ca else {}
         rec["cost"] = {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
@@ -186,6 +182,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, chunks=None, offload=N
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -1,40 +1,26 @@
-"""Production meshes (assignment-mandated shapes) + mesh version compat.
+"""Production meshes (assignment-mandated shapes) + the one mesh constructor.
 
 A FUNCTION, not a module constant: importing this module never touches jax
-device state.
-
-``jax.sharding.AxisType`` (explicit axis-type meshes) only exists on newer
-jax releases; on older installs meshes are built without explicit axis
-types — every axis there is Auto-typed already, so semantics are identical.
-All mesh construction in the repo goes through :func:`make_compat_mesh`.
+device state.  Every mesh in the repo is built by :func:`make_compat_mesh`
+with explicitly Auto-typed axes.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5-ish
-    from jax.sharding import AxisType
-except ImportError:  # older jax: all mesh axes are implicitly Auto
-    AxisType = None
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_compat_mesh(shape, axes, devices=None):
-    """Version-portable mesh constructor (explicit Auto axis types when the
-    installed jax supports them, plain mesh otherwise)."""
+    """Mesh of ``shape`` over ``devices`` (default: all) with Auto axes."""
+    axis_types = (AxisType.Auto,) * len(axes)
     if devices is None:
-        return jax.make_mesh(tuple(shape), tuple(axes), **_axis_type_kwargs(len(axes)))
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
     import numpy as np
 
     from jax.sharding import Mesh
 
     devs = np.asarray(devices).reshape(shape)
-    return Mesh(devs, tuple(axes), **_axis_type_kwargs(len(axes)))
+    return Mesh(devs, tuple(axes), axis_types=axis_types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

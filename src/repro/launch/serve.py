@@ -38,8 +38,8 @@ runs the same engine in arrival-order mode for A/B.
 N`` runs N such engines on disjoint device slices behind the
 session-affine router (``launch/router.py``; ``--router rr`` is the
 locality-shredding baseline), with per-replica request/prefix-hit stats.
-On CPU the device count is forced automatically (train.py's host8
-pattern).
+On CPU the device count is forced automatically (as train.py's
+``--mesh`` does).
 
 The router survives replica failure by default: ``--fault-plan
 "1:raise@2"`` injects a deterministic crash (``launch/faults.py``) to
@@ -319,6 +319,9 @@ def _mesh_engine_main(args, cfg, params, prompts):
 
 
 def main():
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -432,8 +435,8 @@ def main():
             ap.error(f"--mesh must look like AxB, got {args.mesh!r}")
         import os
 
-        # force enough fake CPU devices BEFORE jax import (train.py host8
-        # pattern); a real accelerator fleet ignores this via its own flags
+        # force enough fake CPU devices BEFORE jax import (as train.py's
+        # --mesh does); the flag only applies to the CPU backend
         if "--xla_force_host_platform_device_count" not in os.environ.get(
                 "XLA_FLAGS", ""):
             os.environ["XLA_FLAGS"] = (

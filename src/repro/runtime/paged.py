@@ -954,6 +954,24 @@ class PagedServeEngine(DL.ServeEngine):
 
         return jax.tree_util.tree_map_with_path(offload, cache)
 
+    def _onload_pool(self, cache):
+        """Fetch a host-parked pool into device memory before a program
+        runs on it.  No op may mix memory kinds, so the page gathers and
+        scatters need the pool on device, and a sharded jit refuses an
+        argument whose memory kind differs from its in-sharding (a host
+        kind in the in-shardings instead makes jit annotate every output's
+        placement, which the SPMD partitioner rejects for the replicated
+        ``pkpos`` leaves)."""
+        if self._cache_sh is not None:
+            return self.par.pol.put(cache, self._cache_sh)
+        if self.par is None or not self.n_host_chunks:
+            return cache
+        return jax.tree.map(self.par.to_device, cache)
+
+    def _pool_call(self, program, cache, *args):
+        """Run a pool program on the device-resident pool; park it again."""
+        return self._offload_pool(program(self._onload_pool(cache), *args))
+
     # -- compiled programs ----------------------------------------------
     def _segment_shardings(self):
         """Pool-layout shardings over the CONCRETE pool (its shapes never
@@ -1053,12 +1071,12 @@ class PagedServeEngine(DL.ServeEngine):
             raise ValueError(str(e)) from None
         ids = np.full(self.n_pages, self.n_pages + 1, np.int32)  # pad -> OOB
         ids[: len(plan.fresh_pages)] = plan.fresh_pages
-        cache = self._reset(cache, s, jnp.asarray(ids))
+        cache = self._pool_call(self._reset, cache, s, jnp.asarray(ids))
         for sid, dst, keep in plan.promote:
             rows = {k: jnp.asarray(v)
                     for k, v in self.kv.spill.read(sid).items()}
-            cache = self._promote(cache, jnp.int32(dst), rows,
-                                  jnp.int32(keep))
+            cache = self._pool_call(self._promote, cache, jnp.int32(dst),
+                                    rows, jnp.int32(keep))
             st["spill_promotes"] += 1
         if plan.promote:
             self.telemetry.event("pool.promote", request=idx, slot=s,
@@ -1066,8 +1084,9 @@ class PagedServeEngine(DL.ServeEngine):
         for sid in plan.free_spill:  # scatter dispatched: slot reusable
             self.kv.spill.free(sid)
         for src, dst in plan.cow:
-            cache = self._copy(cache, jnp.int32(src), jnp.int32(dst),
-                               jnp.int32(plan.resume % self.page_size))
+            cache = self._pool_call(self._copy, cache, jnp.int32(src),
+                                    jnp.int32(dst),
+                                    jnp.int32(plan.resume % self.page_size))
             st["cow_copies"] += 1
             self.telemetry.event("pool.cow", request=idx, slot=s,
                                  src=int(src), dst=int(dst))
@@ -1088,8 +1107,9 @@ class PagedServeEngine(DL.ServeEngine):
     def _dispatch(self, cache, mode, tok, pos, key, rem, pfill, pend, plen):
         if self._table_dev is None:
             self._table_dev = jnp.asarray(self.kv.table)
-        emits, valids, aux = self._segment(cache, mode, tok, pos, key, rem,
-                                           pfill, pend, plen, self._table_dev)
+        emits, valids, aux = self._segment(self._onload_pool(cache), mode,
+                                           tok, pos, key, rem, pfill, pend,
+                                           plen, self._table_dev)
         aux["cache"] = self._offload_pool(aux["cache"])
         # keep the persistent pool pointing at the freshest value: pages
         # published to the radix tree mid-workload must survive a failure

@@ -6,20 +6,23 @@ host memory and double-buffering the fetch so it hides behind chunk compute
 (FPDT Fig. 6-7).  Whether that is *possible* — and which memory-kind strings
 name the two pools — is a backend property:
 
-  backend   | memory kinds advertised          | offload
-  ----------|----------------------------------|---------------------------
-  TPU       | ``device``, ``pinned_host``      | supported
-  GPU       | ``device``, ``pinned_host``      | supported
-  CPU       | ``unpinned_host`` (default only) | no distinct pool -> no-op
+  backend        | memory kinds advertised                        | offload
+  ---------------|------------------------------------------------|----------
+  TPU            | ``device``, ``pinned_host``, ``unpinned_host`` | supported
+  GPU            | ``device``, ``pinned_host``                    | supported
+  CPU (jax 0.9)  | ``device``, ``pinned_host``, ``unpinned_host`` | supported
 
-The seed hardcoded ``memory_kind="device"/"pinned_host"`` at every call
-site, which crashes with ``ValueError: Could not find memory addressable by
-device cpu`` anywhere the backend doesn't advertise those kinds.
 ``PlacementPolicy`` probes the backend once (``device.addressable_memories()``
-/ ``device.default_memory()``), records the compute and offload memory
-kinds, and degrades gracefully: on a backend with no distinct host pool,
-``to_host``/``to_device`` are identity functions and a warning is logged
-once, so the FPDT pipeline runs the same program on CPU, GPU, and TPU.
+/ ``device.default_memory()``) and records the compute and offload memory
+kinds.  Offload requested on a backend with no distinct host pool is an
+error, except on CPU, where ``to_host``/``to_device`` become identity
+functions and a warning is logged once (a CPU build without the pool still
+runs every test).  An accelerator run never trains with offload silently
+off.
+
+No op may mix memory kinds (jax refuses it): data moves between the pools
+only through ``to_host``/``to_device``, and stores kept in the host pool are
+written and read host-side (see ``core/fpdt.py``).
 
 All ``jax.device_put`` / ``memory_kind`` decisions route through this
 module (enforced: ``grep -rn 'memory_kind=' src/ | grep -v placement``
@@ -63,12 +66,15 @@ class PlacementPolicy:
                         no host pool distinct from its default memory.
     ``offload_enabled`` — operator switch; ``False`` forces no-op placement
                         even on capable backends (the dry-run uses this).
+    ``device``        — the probed device, target of mesh-less placement
+                        (``None``: the first visible device).
     """
 
     device_kind: Optional[str] = None
     host_kind: Optional[str] = None
     backend: str = "unknown"
     offload_enabled: bool = True
+    device: Optional[Any] = None
 
     # -- capability probe ------------------------------------------------
     @classmethod
@@ -77,14 +83,8 @@ class PlacementPolicy:
         """Inspect one device's memory spaces (once; result is immutable)."""
         if device is None:
             device = jax.devices()[0]
-        try:
-            kinds = {m.kind for m in device.addressable_memories()}
-        except Exception:  # very old jax: no memories API at all
-            kinds = set()
-        try:
-            default = device.default_memory().kind
-        except Exception:
-            default = None
+        kinds = {m.kind for m in device.addressable_memories()}
+        default = device.default_memory().kind
         host = HOST_MEMORY_KIND if HOST_MEMORY_KIND in kinds else None
         if host is not None and host == default:
             # a "host" pool that IS the default memory is not an offload
@@ -92,7 +92,7 @@ class PlacementPolicy:
             host = None
         return cls(device_kind=default, host_kind=host,
                    backend=getattr(device, "platform", "unknown"),
-                   offload_enabled=offload_enabled)
+                   offload_enabled=offload_enabled, device=device)
 
     # -- capabilities ----------------------------------------------------
     @property
@@ -106,6 +106,11 @@ class PlacementPolicy:
         return self.offload_enabled and self.supports_pinned_host
 
     def _noop(self, verb: str):
+        if self.offload_enabled and self.backend != "cpu":
+            raise RuntimeError(
+                f"[placement] {verb} requested but backend '{self.backend}' "
+                f"has no '{HOST_MEMORY_KIND}' memory distinct from its "
+                f"default ('{self.device_kind}')")
         _warn_once(
             f"{self.backend}:{verb}",
             f"[placement] {verb} requested but backend '{self.backend}' has "
@@ -137,8 +142,9 @@ class PlacementPolicy:
 
     def _single(self, on_host: bool):
         kind = self.host_kind if on_host else self.device_kind
+        device = self.device if self.device is not None else jax.devices()[0]
         return jax.sharding.SingleDeviceSharding(
-            jax.devices()[0], **({"memory_kind": kind} if kind else {}))
+            device, **({"memory_kind": kind} if kind else {}))
 
     # -- placement ops ---------------------------------------------------
     def to_host(self, x, mesh: Optional[Mesh] = None,

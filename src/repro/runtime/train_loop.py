@@ -138,7 +138,10 @@ class TrainLoop:
             except ValueError:
                 pass  # not main thread
 
-    def run(self, params, opt_state, start_step: int = 0, put_batch=None):
+    def run(self, params, opt_state, start_step: int = 0, put_batch=None,
+            on_step: Optional[Callable[[int, Any, Dict], None]] = None):
+        """Train to ``tc.steps``; ``on_step(step, params, metrics)`` runs
+        after each step's result is ready."""
         self._install_signals()
         if put_batch is None:
             # default batch staging routes through the placement layer
@@ -160,6 +163,8 @@ class TrainLoop:
             self.telemetry.registry.gauge("train_loss").set(float(metrics["loss"]))
             self.telemetry.event("train.step", step=step, dur_ms=dt * 1e3,
                                  loss=float(metrics["loss"]))
+            if on_step is not None:
+                on_step(step, params, metrics)
             if step % self.tc.log_every == 0:
                 print(f"step {step:6d} loss {float(metrics['loss']):.4f} "
                       f"gnorm {float(metrics['grad_norm']):.3f} {dt*1000:.0f}ms")

@@ -26,7 +26,7 @@ def _dense_window_live(i, j, cq, window):
     return bool(ok.any())
 
 
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(u=st.integers(min_value=1, max_value=8),
        cq=st.sampled_from([1, 4, 8]),
        window=st.sampled_from([0, 1, 5, 8, 17]),
@@ -39,7 +39,7 @@ def test_traced_matches_static(u, cq, window, sparsity):
         assert static == traced, (i, j, kw)
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(u=st.integers(min_value=1, max_value=8),
        cq=st.sampled_from([1, 4, 8]),
        window=st.sampled_from([0, 1, 5, 8, 17]))
@@ -50,7 +50,7 @@ def test_window_equals_dense_mask(u, cq, window):
             _dense_window_live(i, j, cq, window), (i, j, cq, window)
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(u=st.integers(min_value=2, max_value=8),
        cq=st.sampled_from([4, 8]),
        sparsity=st.sampled_from([0.3, 0.5, 0.75, 0.9]))

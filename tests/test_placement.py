@@ -1,6 +1,6 @@
-"""PlacementPolicy: capability probing, graceful no-op degradation, spec
-pass-through with a mesh, and the FPDT offload regression on a host with no
-pinned memory (the seed crashed here with ValueError)."""
+"""PlacementPolicy: capability probing, no-op degradation on CPU only (an
+accelerator without a host pool refuses offload), spec pass-through with a
+mesh, and the FPDT offload regression against the u=1 baseline."""
 import dataclasses
 
 import jax
@@ -29,7 +29,7 @@ def test_probe_cpu_backend():
     assert pol.backend == jax.devices()[0].platform
     assert pol.device_kind == jax.devices()[0].default_memory().kind
     kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-    # on this CPU-only container there is no pinned_host pool
+    # jax 0.9's CPU backend advertises a pinned_host pool; older ones did not
     if "pinned_host" not in kinds:
         assert not pol.supports_pinned_host
         assert not pol.can_offload
@@ -61,20 +61,38 @@ def test_noop_degradation_without_host_pool():
     assert pol.to_device(x) is x
 
 
-def test_noop_logs_warning_once(caplog):
+def test_noop_logs_warning_once(caplog, monkeypatch):
+    from repro.runtime import placement
+
+    monkeypatch.setattr(placement, "_WARNED", set())
     pol = PlacementPolicy(device_kind="unpinned_host", host_kind=None,
-                          backend="test-warn-backend")
+                          backend="cpu")
     x = jnp.arange(4.0)
     with caplog.at_level("WARNING", logger="repro.runtime.placement"):
         pol.to_host(x)
         pol.to_host(x)
-    hits = [r for r in caplog.records if "test-warn-backend" in r.message]
+    hits = [r for r in caplog.records if "backend 'cpu'" in r.message]
     assert len(hits) == 1  # warn once, not per chunk
 
 
 def test_remat_policy_degrades_to_full_remat():
-    pol = PlacementPolicy(device_kind="unpinned_host", host_kind=None)
+    pol = PlacementPolicy(device_kind="unpinned_host", host_kind=None,
+                          backend="cpu")
     assert pol.remat_policy() is jax.checkpoint_policies.nothing_saveable
+
+
+@pytest.mark.parametrize("verb", ["to_host", "to_device", "remat_policy"])
+def test_accelerator_without_host_pool_refuses_offload(verb):
+    """Requested offload on a TPU with no pinned_host pool is an error, not
+    a silent no-op; an operator who disabled offload still gets identity."""
+    pol = PlacementPolicy(device_kind="device", host_kind=None, backend="tpu")
+    x = jnp.arange(4.0)
+    args = () if verb == "remat_policy" else (x,)
+    with pytest.raises(RuntimeError, match="tpu"):
+        getattr(pol, verb)(*args)
+    off = dataclasses.replace(pol, offload_enabled=False)
+    if verb != "remat_policy":
+        assert getattr(off, verb)(x) is x
 
 
 # ---------------------------------------------------------------------------
