@@ -18,13 +18,20 @@ One chip, in order:
       Pallas attention; the compiled step must hold Mosaic kernels
       (``tpu_custom_call``);
   (f) prints compile time, step times, peak HBM, losses (finite; the
-      parameters change every step), the device kind and the depth.
+      parameters change every step), the device kind and the depth;
+  (g) serves llama3.2-1b widths (depth cut) through the paged engine with
+      its KV pool parked in pinned host memory (pages streamed into the
+      program, written back whole) and checks the generated tokens against
+      the same engine with the pool in device memory, over two workloads
+      (the second reuses radix-cached prefix pages).
 
 ``--four-chips`` runs only the mesh phase: a (1 data, 4 model) mesh, the
 FPDT step (u=8, offload) against the u=1 Ulysses baseline at one sequence
 (loss and grad-norm within the stated tolerance), then a few steps at a
-4x longer sequence; parameters must span all 4 devices, and the per-device
-peak HBM is printed.
+4x longer sequence.  It checks what the mesh splits 4 ways (the embedding
+and its Adam moments, the batch's sequence dim, the FPDT q/K/V stores in
+the compiled step), names what stays replicated, and prints the
+per-device peak HBM.
 
 The last line of stdout is ``{"ok": true, "device": {...}}``; any failed
 phase exits non-zero without it.
@@ -53,6 +60,10 @@ PARITY_SEQ, PARITY_U = 8192, 4
 TOL = 2e-2
 MESH_LAYERS = 4
 MESH_SEQ, MESH_LONG_SEQ = 32768, 131072
+# serve phase: f32 weights, so host-streamed and on-device attention (which
+# differ only in summation order) pick the same greedy tokens
+SERVE_LAYERS, SERVE_SLOTS, SERVE_NEW = 4, 2, 16
+SERVE_PROMPTS = (300, 170, 450)
 
 
 class SmokeFailure(RuntimeError):
@@ -190,6 +201,54 @@ def _init_sample(tr):
     return out
 
 
+def serve_phase():
+    """(g) paged serving with the KV pool host-parked vs in device memory."""
+    import jax
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.core.parallel import ParallelContext
+    from repro.models import transformer as T
+    from repro.runtime.paged import PagedServeEngine
+
+    cfg = dataclasses.replace(get_config(ARCH), num_layers=SERVE_LAYERS,
+                              param_dtype="float32", remat="none")
+    params = jax.jit(lambda k: T.init_params(cfg, k))(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    shared = rng.integers(2, cfg.vocab_size, 128).tolist()
+    prompts = [shared + rng.integers(2, cfg.vocab_size, n - 128).tolist()
+               for n in SERVE_PROMPTS]
+    kw = dict(slots=SERVE_SLOTS, bucket=512, max_new_tokens=SERVE_NEW,
+              page_size=16, prefill_chunk=64, segment=8)
+    par = ParallelContext(mesh=None)
+    engines = {"host pool": PagedServeEngine(cfg, params, n_host_chunks=1, par=par, **kw),
+               "device pool": PagedServeEngine(cfg, params, **kw)}
+    outs = {}
+    for name, eng in engines.items():
+        outs[name] = []
+        for run_i in range(2):
+            t0 = time.perf_counter()
+            outs[name].append(eng.generate(prompts))
+            st = eng.last_stats
+            log(f"serve {name}, workload {run_i + 1}: {time.perf_counter() - t0:.2f} s "
+                f"(compile included in workload 1), prefix-hit tokens {st['prefix_hit_tokens']}, "
+                f"COW copies {st['cow_copies']}, programs {eng.compiled_programs()}")
+    kinds = {str(path[-1].key): leaf.sharding.memory_kind for path, leaf
+             in jax.tree_util.tree_flatten_with_path(engines["host pool"]._pool_cache)[0]}
+    check(kinds["pk"] == kinds["pv"] == "pinned_host",
+          f"the host-streamed pool's K/V are in {kinds['pk']}/{kinds['pv']}, not pinned_host")
+    check(engines["host pool"].last_stats["prefix_hit_tokens"] > 0,
+          "the second workload reused no cached prefix page")
+    progs = engines["host pool"].compiled_programs()
+    check(progs["segment"] == progs["reset"] == 1, f"the serve programs recompiled: {progs}")
+    got, want = outs["host pool"], outs["device pool"]
+    same = sum(a == b for w in range(2) for ga, gb in zip(got[w], want[w]) for a, b in zip(ga, gb))
+    total = sum(len(g) for w in want for g in w)
+    log(f"serve: {cfg.name} widths, {SERVE_LAYERS} layers, f32, prompts {list(SERVE_PROMPTS)}, "
+        f"{SERVE_NEW} new tokens each; host-pool tokens equal device-pool tokens: {same}/{total}")
+    check(got == want, "the host-streamed pool generated different tokens")
+
+
 def four_chip_phase():
     """FPDT (u=8, offload) vs Ulysses (u=1) on a (1, 4) mesh, then a longer run."""
     import jax
@@ -205,10 +264,9 @@ def four_chip_phase():
         tr = run(train_args("--mesh", "1x4", "--seq", str(seq), "--chunks", str(chunks),
                             "--steps", str(steps), "--num-layers", str(MESH_LAYERS), *extra),
                  on_step=lambda s, p, m: rec.append((float(m["loss"]), float(m["grad_norm"]))))
-        spans = {len(l.sharding.device_set) for l in jax.tree.leaves(tr.params)}
-        check(spans == {4}, f"parameter leaves span {sorted(spans)} devices, not all 4")
         check("tpu_custom_call" in tr.compiled.as_text(),
               "the mesh step holds no Mosaic kernel (tpu_custom_call)")
+        placement_report(tr, seq, chunks)
         check(all(math.isfinite(v) for r in rec for v in r), f"non-finite loss/grad norm: {rec}")
         times = [h["dt"] for h in tr.loop.history]
         log(f"mesh 1x4 seq {seq} u={chunks} offload={offload}: compile {tr.compile_s:.1f} s, "
@@ -228,6 +286,48 @@ def four_chip_phase():
     peaks = [d.memory_stats()["peak_bytes_in_use"] for d in devs[:4]]
     log("per-device peak HBM in use (GiB): " + ", ".join(f"{d.id}: {b / 2**30:.2f}" for d, b in zip(devs, peaks)))
     check(min(peaks) > 0.5 * max(peaks), "device memory is not spread over the 4 chips")
+
+
+def placement_report(tr, seq, chunks):
+    """What the (1, 4) mesh splits 4 ways, and what it replicates.
+
+    Weights and their Adam moments shard over the data axis, which has one
+    device on this mesh, so they stay replicated on purpose; the embedding
+    splits d over the model axis.  The batch and the FPDT stores split the
+    sequence / heads over the model axis (Ulysses)."""
+    import jax
+
+    def split4(leaf):
+        return leaf.addressable_shards[0].data.size * 4 == leaf.size
+
+    def leaves(tree):
+        return [(jax.tree_util.keystr(p), l) for p, l in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+    for what, tree in (("parameter", tr.params), ("Adam moment", tr.opt_state.m)):
+        emb = [l for k, l in leaves(tree) if "embed" in k]
+        check(emb and all(split4(l) for l in emb), f"the {what} embedding is not split 4 ways")
+        rest = [k for k, l in leaves(tree) if not split4(l)]
+        log(f"{what}s: embedding split 4 ways; replicated on purpose (data axis of 1): "
+            f"{len(rest)} of {len(leaves(tree))} leaves")
+    tokens = tr.compiled.input_shardings[0][2]["tokens"]
+    check(tokens.shard_shape((1, seq)) == (1, seq // 4),
+          f"the batch is not sequence-split: {tokens.spec}")
+    log(f"batch tokens per device {tokens.shard_shape((1, seq))}")
+    if chunks == 1:
+        return
+    cfg, hlo = tr.cfg, tr.compiled.as_text()
+    cq, hq, hkv = seq // chunks, cfg.num_heads, cfg.num_kv_heads
+
+    def store(heads, length):
+        return f"[{chunks},1,{heads},{length},{cfg.head_dim}]"
+
+    # heads over the model axis where they divide it, else the chunk's sequence
+    kv = store(hkv // 4, cq) if hkv % 4 == 0 else store(hkv, cq // 4)
+    per_dev = [store(hq // 4, cq), kv]
+    check(all(s in hlo for s in per_dev) and store(hq, cq) not in hlo,
+          f"the compiled step holds no 4-way split q/K/V stores {per_dev}")
+    log("FPDT q/K/V stores per device " + ", ".join(per_dev)
+        + f" (the whole q store {store(hq, cq)} is absent)")
 
 
 def main():
@@ -262,6 +362,7 @@ def main():
         else:
             parity_phase()
             train_phase(dev)
+            serve_phase()
         log(f"all phases passed in {time.perf_counter() - t0:.1f} s")
     except SmokeFailure as e:
         print(f"[smoke] FAIL: {e}", file=sys.stderr)

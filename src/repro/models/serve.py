@@ -26,8 +26,8 @@ long_500k cell); see ``docs/serving.md``.
 
 Paged layout (``init_paged_cache`` + ``table=...`` on the step entry
 points): full-attention blocks swap the per-slot ``[b, S]`` rows for one
-slot-SHARED page pool ``pk``/``pv`` ``[n_pages+1, page_size, hkv, dh]``
-(+ ``pkpos [n_pages+1, page_size]`` filled positions) indexed through a
+slot-SHARED page pool ``pk``/``pv`` ``[n_pages+1, page_size, hkv*dh]``
+(lane-dense rows; + ``pkpos [n_pages+1, page_size]`` filled positions) indexed through a
 per-slot page table ``[b, max_pages] int32`` owned by
 ``runtime/paged.py``: entry ``-1`` = unmapped (masked out of attention),
 and the extra physical page (index ``n_pages``, the *trash* page) is
@@ -37,6 +37,15 @@ reuse) — reads are free to share; the manager guarantees written pages
 are exclusively owned (copy-on-write).  Recurrent states and local_attn
 rings stay per-slot dense — they are O(1)/O(window) per slot, paging
 buys nothing.
+
+With ``n_host_chunks > 0`` the pool's ``pk``/``pv`` stay in the offload
+pool for the whole program: every layer's pages are viewed as one row
+array ``[C * (n_pages+1), page_size, hkv*dh]`` carried through the layer
+scan, pages are fetched device-ward one page per slot (a host slice of
+the page dim, then ``to_device``), and writes are whole-page
+read-modify-writes (``to_host``, then a host-into-host update of the page
+dim).  The host side only ever sees page-dim slices of a lane-dense
+array, which is what the TPU host-offload DMAs accept.
 """
 from __future__ import annotations
 
@@ -45,7 +54,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import ModelConfig
 from repro.core.online_softmax import NEG_INF, SoftmaxState, finalize, merge, zero_state
@@ -116,10 +124,13 @@ def _paged_attn_cache(cfg: ModelConfig, n_pages: int, page_size: int, dtype):
     physical pages: the last one is the TRASH page — FREE slots' table rows
     point every logical page at it, so their dummy decode writes land
     somewhere harmless; it is never mapped by a live slot.  ``pkpos = -1``
-    marks unfilled page entries, exactly like the dense ``kpos``."""
+    marks unfilled page entries, exactly like the dense ``kpos``.  A page
+    row holds all kv heads side by side (``hkv * dh`` wide): lane-dense on
+    the TPU, and the shape the host-offload DMAs take a page of."""
+    width = cfg.num_kv_heads * cfg.head_dim
     return {
-        "pk": jnp.zeros((n_pages + 1, page_size, cfg.num_kv_heads, cfg.head_dim), dtype),
-        "pv": jnp.zeros((n_pages + 1, page_size, cfg.num_kv_heads, cfg.head_dim), dtype),
+        "pk": jnp.zeros((n_pages + 1, page_size, width), dtype),
+        "pv": jnp.zeros((n_pages + 1, page_size, width), dtype),
         "pkpos": jnp.full((n_pages + 1, page_size), -1, jnp.int32),
     }
 
@@ -164,22 +175,138 @@ def dense_kv_spec(par: ParallelContext, shape) -> tuple:
 
 
 def paged_pool_spec(par: ParallelContext, page_size: int, hkv: int) -> tuple:
-    """Spec components for one pool K/V leaf ``[n_pages+1, page_size, hkv,
-    dh]`` — the dense rule transposed to the paged layout: kv heads over
-    the model axis when divisible, else the in-page sequence dim.  The
-    physical-page dim is ``n_pages + 1`` (trash page) and the page table
-    maps pages to slots arbitrarily, so it is NEVER sharded — every device
-    holds its head (or in-page) slice of every page, and the pool stays
-    replicated over the data axis (it has no batch dim; slots split over
-    data through the per-slot dense leaves instead)."""
+    """Spec components for one pool K/V leaf ``[n_pages+1, page_size,
+    hkv*dh]`` — the dense rule transposed to the paged layout: kv heads
+    (whole ``dh`` runs of the row) over the model axis when divisible, else
+    the in-page sequence dim.  The physical-page dim is ``n_pages + 1``
+    (trash page) and the page table maps pages to slots arbitrarily, so it
+    is NEVER sharded — every device holds its head (or in-page) slice of
+    every page, and the pool stays replicated over the data axis (it has
+    no batch dim; slots split over data through the per-slot dense leaves
+    instead)."""
     if hkv % par.sp == 0 and hkv >= par.sp:
-        return (None, None, par.sp_axis, None)
+        return (None, None, par.sp_axis)
     if page_size % par.sp == 0 and page_size >= par.sp:
-        return (None, par.sp_axis, None, None)
-    return (None, None, None, None)
+        return (None, par.sp_axis, None)
+    return (None, None, None)
 
 
-def cache_shardings(cfg: ModelConfig, par: ParallelContext, cache):
+def pool_host_spec(par: Optional[ParallelContext], ndim: int,
+                   width: int) -> tuple:
+    """Spec of a host-parked pool array (``ndim`` dims, rows ``width``
+    wide): placement custom-calls refuse partial replication, so on a mesh
+    the row dim is split over EVERY axis; off-mesh, no spec."""
+    if par is None or par.mesh is None:
+        return ()
+    n = par.mesh.size
+    if width % n:
+        raise ValueError(
+            f"a host-parked KV pool splits its {width}-wide page rows "
+            f"(kv_heads * head_dim) over all {n} devices of the mesh, and "
+            f"{width} % {n} != 0")
+    return (None,) * (ndim - 1) + (tuple(par.mesh.axis_names),)
+
+
+def _to_host(par, x):
+    if par is None:
+        return x
+    return par.to_host(x, *pool_host_spec(par, x.ndim, x.shape[-1]))
+
+
+def _to_dev(par, x):
+    if par is None:
+        return x
+    return par.to_device(x, *pool_host_spec(par, x.ndim, x.shape[-1]))
+
+
+def park_pool(par: Optional[ParallelContext], cache: Params) -> Params:
+    """Place every pool ``pk``/``pv`` leaf in the offload pool (identity
+    with ``par=None`` or offload off).  Used eagerly to park a pool and
+    inside programs to give a host-resident argument its host memory
+    space."""
+    def park(path, leaf):
+        names = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
+        return _to_host(par, leaf) if names[-1] in ("pk", "pv") else leaf
+
+    return jax.tree_util.tree_map_with_path(park, cache)
+
+
+def pool_rows(par: Optional[ParallelContext], leaf):
+    """One pool K/V leaf (``[C, n, ps, W]`` stacked or ``[n, ps, W]``) as
+    page rows ``[C*n, ps, W]`` in the offload pool: layer ``c``'s
+    physical page ``p`` is row ``c*n + p``, so every host access slices
+    the page dim only."""
+    return _to_host(par, leaf.reshape(-1, *leaf.shape[-2:]))
+
+
+def get_page(par: Optional[ParallelContext], leaf, pid):
+    """Physical page ``pid`` of every layer of one pool K/V leaf, in device
+    memory: ``[C, ps, W]`` (``[ps, W]`` for a tail leaf)."""
+    n = leaf.shape[-3]
+    rows = pool_rows(par, leaf)
+    pages = [_to_dev(par, jax.lax.dynamic_slice_in_dim(rows, c * n + pid, 1, 0))[0]
+             for c in range(rows.shape[0] // n)]
+    return jnp.stack(pages) if leaf.ndim == 4 else pages[0]
+
+
+def set_page(par: Optional[ParallelContext], leaf, pid, page):
+    """Write ``page`` (``get_page``'s shape) into physical page ``pid`` of
+    every layer of one pool K/V leaf; whole pages, host-into-host."""
+    n = leaf.shape[-3]
+    rows = pool_rows(par, leaf)
+    pages = page if leaf.ndim == 4 else page[None]
+    for c in range(pages.shape[0]):
+        blk = _to_host(par, pages[c][None].astype(rows.dtype))
+        rows = jax.lax.dynamic_update_slice_in_dim(rows, blk, c * n + pid, 0)
+    return rows.reshape(leaf.shape)
+
+
+def _fetch_pages(par, rows, base, pids):
+    """Pages ``base + pids[i]`` of host-parked rows into device memory, one
+    page-dim slice per slot: ``[b, ps, W]``."""
+    return jnp.stack([
+        _to_dev(par, jax.lax.dynamic_slice_in_dim(rows, base + pids[i], 1, 0))[0]
+        for i in range(pids.shape[0])])
+
+
+def _write_pages(par, kv_rows, base, table, tpos, vals, live, n_phys):
+    """Write token rows into host-parked page rows through the page table.
+
+    ``kv_rows`` = (pk rows, pv rows) ``[R, ps, W]``; ``tpos`` [b, t] the
+    token positions (consecutive per slot), ``vals`` = (k, v) ``[b, t, W]``,
+    ``live`` [b, t] which tokens land (the rest, and tokens on unmapped
+    pages, are dropped, as the device path's ``mode="drop"`` scatter).
+    Each touched page is fetched, updated on the device, and written back
+    whole (a select, not an update-slice: the host side takes only
+    page-dim slices); a page with nothing to write rewrites the trash
+    page unchanged."""
+    b, t = tpos.shape
+    ps, max_pages = kv_rows[0].shape[1], table.shape[1]
+    span = (ps + t - 2) // ps + 1  # pages a run of t positions can touch
+    tj = jnp.minimum(tpos // ps, max_pages - 1)  # as _paged_write_ids clamps
+    at_off = (tpos % ps)[..., None] == jnp.arange(ps)  # [b, t, ps]
+
+    def body(i, kv_rows):
+        s, j = i // span, tj[i // span, 0] + i % span
+        pid = table[s, jnp.minimum(j, max_pages - 1)]
+        hit = live[s] & (tj[s] == j) & (pid >= 0)  # [t]
+        m = hit[:, None] & at_off[s]  # [t, ps]
+        row = base + jnp.where(jnp.any(hit), pid, n_phys - 1)
+        src = jnp.argmax(m, axis=0)  # the token landing at each offset
+        out = []
+        for rows, val in zip(kv_rows, vals):
+            page = _to_dev(par, jax.lax.dynamic_slice_in_dim(rows, row, 1, 0))
+            page = jnp.where(m.any(0)[None, :, None],
+                             val[s][src][None].astype(rows.dtype), page)
+            out.append(jax.lax.dynamic_update_slice_in_dim(
+                rows, _to_host(par, page), row, 0))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, b * span, body, tuple(kv_rows))
+
+
+def cache_shardings(cfg: ModelConfig, par: ParallelContext, cache, *,
+                    host_pool: bool = False):
     """NamedShardings for a cache pytree (heads/seq/channels per DESIGN.md).
 
     Shape-aware: a dim is only sharded when divisible by its axis (kv heads
@@ -187,7 +314,8 @@ def cache_shardings(cfg: ModelConfig, par: ParallelContext, cache):
     long-context decode leaves batch unsharded).  Covers BOTH layouts:
     dense per-slot rows (``init_cache``) and the slot-shared paged pool
     (``init_paged_cache`` — ``pk``/``pv`` follow ``paged_pool_spec``,
-    ``pkpos`` co-shards its in-page dim with them)."""
+    ``pkpos`` co-shards its in-page dim with them).  ``host_pool``: the
+    pool's ``pk``/``pv`` live in the offload pool (``pool_host_spec``)."""
 
     def dp_if(n):
         return par.dp_axes if n % par.dp == 0 and n >= par.dp else None
@@ -201,8 +329,11 @@ def cache_shardings(cfg: ModelConfig, par: ParallelContext, cache):
         lead = (None,) if stacked else ()
         off = 1 if stacked else 0
         shape = leaf.shape[off:]
-        if names[-1] in ("pk", "pv"):  # [*, n_pages+1, ps, hkv, dh]
-            return par.ns(*lead, *paged_pool_spec(par, shape[1], shape[2]))
+        if names[-1] in ("pk", "pv"):  # [*, n_pages+1, ps, hkv*dh]
+            if host_pool:
+                return par.pol.ns(par.mesh, *pool_host_spec(par, leaf.ndim, shape[2]),
+                                  on_host=True)
+            return par.ns(*lead, *paged_pool_spec(par, shape[1], cfg.num_kv_heads))
         if "pkpos" in names:  # [*, n_pages+1, ps]
             sub = paged_pool_spec(par, shape[1], cfg.num_kv_heads)
             return par.ns(*lead, None, sub[1])
@@ -238,20 +369,21 @@ def pool_leaf_key(path) -> str:
     return "/".join(parts)
 
 
-def page_rows(cache, pid: int) -> Dict[str, Any]:
-    """One physical page's payload as host numpy rows keyed by
-    ``pool_leaf_key`` — the demotion/persistence read path.  Only pool
+def page_rows(par: Optional[ParallelContext], cache, pid) -> Dict[str, Any]:
+    """One physical page's payload keyed by ``pool_leaf_key`` — the
+    demotion/persistence read path (traceable; ``par`` places a
+    host-parked pool, ``None`` for a pool in device memory).  Only pool
     leaves (``pk``/``pv``/``pkpos``) appear; per-slot dense leaves carry
     no page state.  Stacked leaves keep their leading cycle dim, so a row
-    is ``[C, ps, hkv, dh]`` / ``[C, ps]`` (or without ``C`` for tail)."""
+    is ``[C, ps, hkv*dh]`` / ``[C, ps]`` (or without ``C`` for tail)."""
     leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
     rows = {}
     for path, leaf in leaves:
         names = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
-        if names[-1] not in ("pk", "pv") and "pkpos" not in names:
-            continue
-        row = leaf[:, pid] if names[0] != "tail" else leaf[pid]
-        rows[pool_leaf_key(path)] = np.asarray(jax.device_get(row))
+        if names[-1] in ("pk", "pv"):
+            rows[pool_leaf_key(path)] = get_page(par, leaf, pid)
+        elif "pkpos" in names:
+            rows[pool_leaf_key(path)] = leaf[:, pid] if names[0] != "tail" else leaf[pid]
     return rows
 
 
@@ -300,6 +432,7 @@ def _decode_attention(cfg: ModelConfig, par: Optional[ParallelContext], p: Param
         return SoftmaxState(acc[:, :, None, :], m[:, :, None], l[:, :, None])
 
     if n_host_chunks and S % n_host_chunks == 0:
+        _refuse_dense_offload(par)
         # FPDT-for-inference: stream host-resident KV chunk by chunk through
         # the scan-carry Fig. 6 pipeline — the chunk body is traced ONCE, so
         # decode program size is flat in n_host_chunks (the generator-based
@@ -345,6 +478,18 @@ def _decode_attention(cfg: ModelConfig, par: Optional[ParallelContext], p: Param
     return out, new_cache
 
 
+def _refuse_dense_offload(par: Optional[ParallelContext]) -> None:
+    """The dense cache is never parked in host memory (only the paged pool
+    is): host-chunked attention over it streams device-resident chunks.
+    That is a numerics twin of offload on CPU, where tests use it; on an
+    accelerator it would be offload silently not done, so it is refused."""
+    if par is not None and par.offload_active and par.pol.backend != "cpu":
+        raise NotImplementedError(
+            "n_host_chunks > 0 over the dense KV cache keeps the cache in "
+            "device memory; host-parked KV serving is the paged engine's "
+            "(PagedServeEngine(..., n_host_chunks=...))")
+
+
 def _paged_write_ids(table: jnp.ndarray, pos: jnp.ndarray, page_size: int,
                      n_phys: int):
     """(physical page, in-page offset) for writing at ``pos`` through the
@@ -359,33 +504,55 @@ def _paged_write_ids(table: jnp.ndarray, pos: jnp.ndarray, page_size: int,
     return pid, pos % page_size
 
 
-def _paged_gather(ck, cv, kpos, table, j):
-    """Fetch logical page ``j`` of every slot: ([b, ps, hkv, dh]) k/v, the
-    page's filled positions, and the page-mapped mask (``-1`` table entries
-    clamp to page 0 for the gather and are masked out here)."""
-    pid = table[:, j]
-    safe = jnp.clip(pid, 0, None)
-    kc = jnp.take(ck, safe, axis=0)
-    vc = jnp.take(cv, safe, axis=0)
-    kp = jnp.take(kpos, safe, axis=0)
-    okp = jnp.broadcast_to((pid >= 0)[:, None], kp.shape)
-    return kc, vc, kp, okp
+def _paged_history(cfg: ModelConfig, par, pk, pv, kpos, table, base, attend,
+                   init: SoftmaxState, hi_pos):
+    """Online-softmax state of ``attend(k, v, kpos, mapped)`` over every
+    mapped page of each slot (``[b, S, hkv, dh]`` K/V runs; ``mapped``
+    masks entries whose logical page is unmapped, ``-1`` in the table).
+
+    ``base is None``: ``pk``/``pv`` are this layer's pool in device memory,
+    and the whole mapped range is gathered at once (bit-comparable to dense
+    attention).  Otherwise they are every layer's page rows parked in the
+    offload pool (this layer's pages start at row ``base``), streamed one
+    logical page per iteration through ``fori_double_buffered`` — the fetch
+    of page j+1 is issued before page j's merge (Fig. 6), program size is
+    flat in ``n_pages`` and ``max_pages``, and pages past every slot's
+    highest position ``hi_pos`` are skipped."""
+    b, max_pages = table.shape
+    ps = kpos.shape[1]
+    hkv, dh = cfg.num_kv_heads, cfg.head_dim
+    if base is None:
+        safe = jnp.clip(table, 0, None)  # [b, max_pages]
+        kall = jnp.take(pk, safe, axis=0).reshape(b, max_pages * ps, hkv, dh)
+        vall = jnp.take(pv, safe, axis=0).reshape(b, max_pages * ps, hkv, dh)
+        kpall = jnp.take(kpos, safe, axis=0).reshape(b, max_pages * ps)
+        return attend(kall, vall, kpall, jnp.repeat(table >= 0, ps, axis=1))
+
+    def fetch(j):
+        pid = jnp.clip(table[:, j], 0, None)
+        kc = _fetch_pages(par, pk, base, pid).reshape(b, ps, hkv, dh)
+        vc = _fetch_pages(par, pv, base, pid).reshape(b, ps, hkv, dh)
+        kp = jnp.take(kpos, pid, axis=0)
+        return kc, vc, kp, jnp.broadcast_to((table[:, j] >= 0)[:, None], kp.shape)
+
+    return fori_double_buffered(
+        0, max_pages, fetch, lambda j, buf, st: merge(st, attend(*buf)), init,
+        live=lambda j: (j * ps) <= hi_pos)
 
 
 def _decode_attention_paged(cfg: ModelConfig, par: Optional[ParallelContext],
                             p: Params, x: jnp.ndarray, cache: Params, pos,
-                            table: jnp.ndarray, *, n_host_chunks: int = 0):
+                            table: jnp.ndarray, *, pool_base=None):
     """Paged twin of ``_decode_attention``: K/V are gathered through the
     per-slot page table instead of sliced from a dense ``[b, S]`` row.
 
     x [b, 1, d]; pos scalar or [b]; table [b, max_pages] int32 (physical
     page of each logical page; -1 = unmapped -> masked; FREE rows point at
-    the trash page).  With ``n_host_chunks > 0`` the pool is host-resident
-    and pages stream device-ward one logical page at a time through
-    ``fori_double_buffered`` — the same scan-carry Fig. 6 pipeline as the
-    dense host-chunked path, so program size is flat in BOTH ``n_pages``
-    and ``max_pages``; with 0 the whole mapped range is gathered at once
-    (on-device fast path, bit-comparable to dense attention).
+    the trash page).  ``pool_base`` selects the pool's placement (see
+    ``_paged_history``): ``None`` for this layer's pool in device memory,
+    else the first page row of this layer in the host-parked rows that
+    ``cache["pk"]``/``["pv"]`` then hold (the new token is written there by
+    a page read-modify-write before the pages stream back).
     Returns (attn_out [b, 1, qd], new pool leaves)."""
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
@@ -393,15 +560,20 @@ def _decode_attention_paged(cfg: ModelConfig, par: Optional[ParallelContext],
     q = L.apply_rope(q, pos[:, None], cfg.rope_theta)
     k = L.apply_rope(k, pos[:, None], cfg.rope_theta)
     n_phys, ps = cache["pkpos"].shape
-    max_pages = table.shape[1]
+    k, v = k.reshape(b, 1, -1), v.reshape(b, 1, -1)  # [b, 1, hkv*dh] page rows
     pid_w, off = _paged_write_ids(table, pos, ps, n_phys)  # [b], [b]
-    ck = cache["pk"].at[pid_w, off].set(k[:, 0].astype(cache["pk"].dtype), mode="drop")
-    cv = cache["pv"].at[pid_w, off].set(v[:, 0].astype(cache["pv"].dtype), mode="drop")
     kpos = cache["pkpos"].at[pid_w, off].set(pos, mode="drop")
+    if pool_base is None:
+        ck = cache["pk"].at[pid_w, off].set(k[:, 0].astype(cache["pk"].dtype), mode="drop")
+        cv = cache["pv"].at[pid_w, off].set(v[:, 0].astype(cache["pv"].dtype), mode="drop")
+    else:
+        ck, cv = _write_pages(par, (cache["pk"], cache["pv"]), pool_base, table,
+                              pos[:, None], (k, v), jnp.ones((b, 1), bool), n_phys)
     if par is not None and par.mesh is not None:
-        pspec = paged_pool_spec(par, ps, ck.shape[2])
-        ck = par.constrain(ck, *pspec)
-        cv = par.constrain(cv, *pspec)
+        pspec = paged_pool_spec(par, ps, cfg.num_kv_heads)
+        if pool_base is None:
+            ck = par.constrain(ck, *pspec)
+            cv = par.constrain(cv, *pspec)
         kpos = par.constrain(kpos, None, pspec[1])
 
     g = cfg.num_heads // cfg.num_kv_heads
@@ -422,50 +594,23 @@ def _decode_attention_paged(cfg: ModelConfig, par: Optional[ParallelContext],
         acc = jnp.einsum("bhs,bshd->bhd", pr, ve)
         return SoftmaxState(acc[:, :, None, :], m[:, :, None], l[:, :, None])
 
-    if n_host_chunks:
-        # two-tier pool: cold pages live host-side; stream one logical page
-        # per iteration, fetch j+1 issued before page j's merge (Fig. 6)
-        slab_spec = None
-        if par is not None and par.mesh is not None:
-            all_axes = tuple(par.mesh.axis_names)
-            if ps % par.mesh.size == 0:  # host custom-calls need FULL sharding
-                slab_spec = (None, all_axes, None, None)
-
-        def fetch(j):
-            kc, vc, kp, okp = _paged_gather(ck, cv, kpos, table, j)
-            if par is not None:
-                kc = par.to_device(kc, *(slab_spec or ()))
-                vc = par.to_device(vc, *(slab_spec or ()))
-            return kc, vc, kp, okp
-
-        hi_pos = jnp.max(pos)
-        state = fori_double_buffered(
-            0, max_pages, fetch,
-            lambda j, buf, st: merge(st, attend(*buf)),
-            zero_state((b, cfg.num_heads, 1, cfg.head_dim)),
-            live=lambda j: (j * ps) <= hi_pos,
-        )
-        o = finalize(state)[:, :, 0]  # [b, h, d]
-    else:
-        safe = jnp.clip(table, 0, None)  # [b, max_pages]
-        kall = jnp.take(ck, safe, axis=0).reshape(b, max_pages * ps, *ck.shape[2:])
-        vall = jnp.take(cv, safe, axis=0).reshape(b, max_pages * ps, *cv.shape[2:])
-        kpall = jnp.take(kpos, safe, axis=0).reshape(b, max_pages * ps)
-        okall = jnp.repeat(table >= 0, ps, axis=1)
-        o = finalize(attend(kall, vall, kpall, okall))[:, :, 0]
+    state = _paged_history(cfg, par, ck, cv, kpos, table, pool_base, attend,
+                           zero_state((b, cfg.num_heads, 1, cfg.head_dim)),
+                           jnp.max(pos))
+    o = finalize(state)[:, :, 0]  # [b, h, d]
     o = o.reshape(b, 1, cfg.q_dim).astype(x.dtype)
     out = o @ p["wo"]
     return out, {"pk": ck, "pv": cv, "pkpos": kpos}
 
 
-def _decode_block(cfg, par, kind, p, h, cache, pos, n_host_chunks=0, table=None):
+def _decode_block(cfg, par, kind, p, h, cache, pos, n_host_chunks=0, table=None,
+                  pool_base=None):
     if kind in ("attn", "local_attn"):
         window = cfg.window if kind == "local_attn" else 0
         hn = L.apply_norm(cfg, p["norm1"], h)
         if table is not None and "pk" in cache:  # paged pool (attn only)
             o, cache = _decode_attention_paged(cfg, par, p["attn"], hn, cache,
-                                               pos, table,
-                                               n_host_chunks=n_host_chunks)
+                                               pos, table, pool_base=pool_base)
         else:
             o, cache = _decode_attention(cfg, par, p["attn"], hn, cache, pos,
                                          window=window,
@@ -490,6 +635,60 @@ def _decode_block(cfg, par, kind, p, h, cache, pos, n_host_chunks=0, table=None)
         hn2 = L.apply_norm(cfg, p["norm2"], h)
         return h + L.mlp_block(cfg, p["mlp"], hn2), st
     raise ValueError(kind)
+
+
+def _run_blocks(cfg: ModelConfig, par, params: Params, cache: Params, h,
+                block, *, host_pool: bool):
+    """Run every block over ``h``: the layer cycles in one ``lax.scan``,
+    then the tail.  ``block(kind, p, h, cache, pool_base)`` returns
+    ``(h, new block cache)``.
+
+    With ``host_pool`` the paged pool's ``pk``/``pv`` are not sliced per
+    cycle (slicing a layer out of a host array would copy it): each leaf
+    rides the scan carry whole, as ``pool_rows`` in the offload pool, and
+    cycle ``c`` addresses its pages from row ``c * (n_pages + 1)``."""
+    pat, n_cycles, tail = layout_of(cfg)
+    cyc = {k: cache[k] for k in cache if k != "tail"}
+    rows = {}
+    if host_pool:
+        for k, sub in cyc.items():
+            if "pk" in sub:
+                rows[k] = {n: pool_rows(par, sub[n]) for n in ("pk", "pv")}
+                cyc[k] = {"pkpos": sub["pkpos"]}
+
+    def cycle_body(carry, scans):
+        h, rows = carry
+        cyc_p, cyc_cache, c = scans
+        new_caches, new_rows = {}, {}
+        for i, kind in enumerate(pat):
+            key, base = f"pos{i}", None
+            lc = cyc_cache[key]
+            if key in rows:
+                base = c * lc["pkpos"].shape[0]
+                lc = {**lc, **rows[key]}
+            h, nc = block(kind, cyc_p[key], h, lc, base)
+            if key in rows:
+                new_rows[key] = {n: nc.pop(n) for n in ("pk", "pv")}
+            new_caches[key] = nc
+        return (h, new_rows), new_caches
+
+    (h, rows), new_cache = jax.lax.scan(
+        cycle_body, (h, rows), (params["cycles"], cyc, jnp.arange(n_cycles)))
+    new_cache = dict(new_cache)
+    for k, r in rows.items():
+        new_cache[k] = {**new_cache[k],
+                        **{n: r[n].reshape(cache[k][n].shape) for n in r}}
+    if tail:
+        new_tail = []
+        for i, kind in enumerate(tail):
+            lc, base = cache["tail"][i], None
+            if host_pool and "pk" in lc:
+                lc = {**lc, **{n: pool_rows(par, lc[n]) for n in ("pk", "pv")}}
+                base = 0
+            h, nc = block(kind, params["tail"][i], h, lc, base)
+            new_tail.append(nc)
+        new_cache["tail"] = new_tail
+    return h, new_cache
 
 
 def decode_step(cfg: ModelConfig, par: Optional[ParallelContext], params: Params,
@@ -526,29 +725,12 @@ def decode_step(cfg: ModelConfig, par: Optional[ParallelContext], params: Params
         h = h + pe.astype(h.dtype)[:, None]
     else:
         h = params["embed"][inp["tokens"]].astype(jnp.dtype(cfg.param_dtype))
-    pat, n_cycles, tail = layout_of(cfg)
+    def block(kind, p, h, c, base):
+        return _decode_block(cfg, par, kind, p, h, c, pos, n_host_chunks, table,
+                             pool_base=base)
 
-    def cycle_body(h, scans):
-        cyc_p, cyc_cache = scans
-        new_caches = {}
-        for i, kind in enumerate(pat):
-            h, nc = _decode_block(cfg, par, kind, cyc_p[f"pos{i}"], h,
-                                  cyc_cache[f"pos{i}"], pos, n_host_chunks,
-                                  table)
-            new_caches[f"pos{i}"] = nc
-        return h, new_caches
-
-    h, new_cycle_caches = jax.lax.scan(
-        cycle_body, h, (params["cycles"], {k: cache[k] for k in cache if k != "tail"})
-    )
-    new_cache = dict(new_cycle_caches)
-    if tail:
-        new_tail = []
-        for i, kind in enumerate(tail):
-            h, nc = _decode_block(cfg, par, kind, params["tail"][i], h,
-                                  cache["tail"][i], pos, n_host_chunks, table)
-            new_tail.append(nc)
-        new_cache["tail"] = new_tail
+    h, new_cache = _run_blocks(cfg, par, params, cache, h, block,
+                               host_pool=bool(n_host_chunks) and table is not None)
     h = L.apply_norm(cfg, params["final_norm"], h)
     logits = (h[:, 0] @ head_matrix(cfg, params)).astype(jnp.float32)
     return logits, new_cache
@@ -622,6 +804,7 @@ def _chunk_attention(cfg: ModelConfig, par: Optional[ParallelContext], p: Params
         return SoftmaxState(acc, m, l)
 
     if n_host_chunks and S % n_host_chunks == 0 and not window:
+        _refuse_dense_offload(par)
         # FPDT-for-inference, mixed-step flavor: stream the pre-write cache
         # slab by slab (chunk body traced once — program size flat in the
         # slab count), merge with the intra-window part at the end.
@@ -681,12 +864,13 @@ def _chunk_attention(cfg: ModelConfig, par: Optional[ParallelContext], p: Params
 def _chunk_attention_paged(cfg: ModelConfig, par: Optional[ParallelContext],
                            p: Params, x: jnp.ndarray, cache: Params,
                            qpos: jnp.ndarray, live: jnp.ndarray,
-                           table: jnp.ndarray, *, n_host_chunks: int = 0):
+                           table: jnp.ndarray, *, pool_base=None):
     """Paged twin of ``_chunk_attention``: the history pass gathers the
-    PRE-write pool through the page table (page by page, host-streamed,
-    when ``n_host_chunks > 0``; one gather otherwise), the intra-window
-    pass is identical to dense, and the live window tokens scatter back
-    through the table (dead positions -> out-of-bounds, dropped).  Shared
+    PRE-write pool through the page table (``_paged_history``: page by
+    page from the host-parked rows when ``pool_base`` is given, one gather
+    otherwise), the intra-window pass is identical to dense, and the live
+    window tokens are written back through the table (dead positions
+    dropped).  Shared
     (radix) pages are only ever read — the page manager guarantees every
     written page is exclusively owned (COW).  Returns
     (attn out [b, cp, qd], new pool leaves)."""
@@ -695,7 +879,6 @@ def _chunk_attention_paged(cfg: ModelConfig, par: Optional[ParallelContext],
     q = L.apply_rope(q, qpos, cfg.rope_theta)
     k = L.apply_rope(k, qpos, cfg.rope_theta)
     n_phys, ps = cache["pkpos"].shape
-    max_pages = table.shape[1]
     g = cfg.num_heads // cfg.num_kv_heads
     qt = q.astype(jnp.float32).transpose(0, 2, 1, 3)  # [b, hq, cp, dh]
     scale = cfg.head_dim ** -0.5
@@ -731,37 +914,10 @@ def _chunk_attention_paged(cfg: ModelConfig, par: Optional[ParallelContext],
         acc = jnp.einsum("bhqk,bkhd->bhqd", pr, ve)
         return SoftmaxState(acc, m, l)
 
-    if n_host_chunks:
-        slab_spec = None
-        if par is not None and par.mesh is not None:
-            all_axes = tuple(par.mesh.axis_names)
-            if ps % par.mesh.size == 0:  # host custom-calls need FULL sharding
-                slab_spec = (None, all_axes, None, None)
-
-        def fetch(j):
-            kc, vc, kp, okp = _paged_gather(cache["pk"], cache["pv"],
-                                            cache["pkpos"], table, j)
-            if par is not None:
-                kc = par.to_device(kc, *(slab_spec or ()))
-                vc = par.to_device(vc, *(slab_spec or ()))
-            return kc, vc, kp, okp
-
-        hi_pos = jnp.max(jnp.where(key_live, qpos, -1))
-        hist = fori_double_buffered(
-            0, max_pages, fetch,
-            lambda j, buf, st: merge(st, attend(*buf)),
-            zero_state((b, cfg.num_heads, cp, cfg.head_dim)),
-            live=lambda j: (j * ps) <= hi_pos,
-        )
-    else:
-        safe = jnp.clip(table, 0, None)
-        kall = jnp.take(cache["pk"], safe, axis=0).reshape(
-            b, max_pages * ps, *cache["pk"].shape[2:])
-        vall = jnp.take(cache["pv"], safe, axis=0).reshape(
-            b, max_pages * ps, *cache["pv"].shape[2:])
-        kpall = jnp.take(cache["pkpos"], safe, axis=0).reshape(b, max_pages * ps)
-        okall = jnp.repeat(table >= 0, ps, axis=1)
-        hist = attend(kall, vall, kpall, okall)
+    hist = _paged_history(cfg, par, cache["pk"], cache["pv"], cache["pkpos"],
+                          table, pool_base, attend,
+                          zero_state((b, cfg.num_heads, cp, cfg.head_dim)),
+                          jnp.max(jnp.where(key_live, qpos, -1)))
 
     o = finalize(merge(hist, attend_intra()))  # [b, h, cp, dh]
     o = o.transpose(0, 2, 1, 3).reshape(b, cp, cfg.q_dim).astype(x.dtype)
@@ -770,26 +926,32 @@ def _chunk_attention_paged(cfg: ModelConfig, par: Optional[ParallelContext],
     # write the live window through the table (after attention)
     pid_w, off = _paged_write_ids(table, qpos, ps, n_phys)  # [b, cp] each
     pid_w = jnp.where(key_live, pid_w, n_phys)  # dead -> OOB, dropped
-    ck = cache["pk"].at[pid_w, off].set(k.astype(cache["pk"].dtype), mode="drop")
-    cv = cache["pv"].at[pid_w, off].set(v.astype(cache["pv"].dtype), mode="drop")
     kpos = cache["pkpos"].at[pid_w, off].set(qpos, mode="drop")
+    k, v = k.reshape(b, cp, -1), v.reshape(b, cp, -1)  # [b, cp, hkv*dh] rows
+    if pool_base is None:
+        ck = cache["pk"].at[pid_w, off].set(k.astype(cache["pk"].dtype), mode="drop")
+        cv = cache["pv"].at[pid_w, off].set(v.astype(cache["pv"].dtype), mode="drop")
+    else:
+        ck, cv = _write_pages(par, (cache["pk"], cache["pv"]), pool_base, table,
+                              qpos, (k, v), key_live, n_phys)
     if par is not None and par.mesh is not None:
-        pspec = paged_pool_spec(par, ps, ck.shape[2])
-        ck = par.constrain(ck, *pspec)
-        cv = par.constrain(cv, *pspec)
+        pspec = paged_pool_spec(par, ps, cfg.num_kv_heads)
+        if pool_base is None:
+            ck = par.constrain(ck, *pspec)
+            cv = par.constrain(cv, *pspec)
         kpos = par.constrain(kpos, None, pspec[1])
     return out, {"pk": ck, "pv": cv, "pkpos": kpos}
 
 
 def _chunk_block(cfg, par, kind, p, h, cache, qpos, live, n_host_chunks=0,
-                 table=None):
+                 table=None, pool_base=None):
     if kind in ("attn", "local_attn"):
         window = cfg.window if kind == "local_attn" else 0
         hn = L.apply_norm(cfg, p["norm1"], h)
         if table is not None and "pk" in cache:  # paged pool (attn only)
             o, cache = _chunk_attention_paged(cfg, par, p["attn"], hn, cache,
                                               qpos, live, table,
-                                              n_host_chunks=n_host_chunks)
+                                              pool_base=pool_base)
         else:
             o, cache = _chunk_attention(cfg, par, p["attn"], hn, cache, qpos, live,
                                         window=window,
@@ -854,30 +1016,12 @@ def chunk_step(cfg: ModelConfig, par: Optional[ParallelContext], params: Params,
     live = jnp.broadcast_to(jnp.asarray(live, jnp.int32), (b,))
     qpos = offset[:, None] + jnp.arange(cp)[None, :]  # [b, cp]
     h = params["embed"][toks].astype(jnp.dtype(cfg.param_dtype))
-    pat, n_cycles, tail = layout_of(cfg)
+    def block(kind, p, h, c, base):
+        return _chunk_block(cfg, par, kind, p, h, c, qpos, live, n_host_chunks,
+                            table, pool_base=base)
 
-    def cycle_body(h, scans):
-        cyc_p, cyc_cache = scans
-        new_caches = {}
-        for i, kind in enumerate(pat):
-            h, nc = _chunk_block(cfg, par, kind, cyc_p[f"pos{i}"], h,
-                                 cyc_cache[f"pos{i}"], qpos, live, n_host_chunks,
-                                 table)
-            new_caches[f"pos{i}"] = nc
-        return h, new_caches
-
-    h, new_cycle_caches = jax.lax.scan(
-        cycle_body, h, (params["cycles"], {k: cache[k] for k in cache if k != "tail"})
-    )
-    new_cache = dict(new_cycle_caches)
-    if tail:
-        new_tail = []
-        for i, kind in enumerate(tail):
-            h, nc = _chunk_block(cfg, par, kind, params["tail"][i], h,
-                                 cache["tail"][i], qpos, live, n_host_chunks,
-                                 table)
-            new_tail.append(nc)
-        new_cache["tail"] = new_tail
+    h, new_cache = _run_blocks(cfg, par, params, cache, h, block,
+                               host_pool=bool(n_host_chunks) and table is not None)
     h = L.apply_norm(cfg, params["final_norm"], h)
     li = jnp.clip(live - 1, 0, cp - 1)
     last = h[jnp.arange(b), li]

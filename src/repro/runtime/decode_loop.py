@@ -280,7 +280,7 @@ def per_engine(fn, telemetry: Optional[TM.Telemetry] = None,
             telemetry.compile_event(label)
         return fn(*args)
 
-    wrapped.__name__ = fn.__name__
+    wrapped.__name__ = getattr(fn, "__name__", label)
     return wrapped
 
 
@@ -325,6 +325,10 @@ def mixed_segment(cfg: ModelConfig, par: Optional[ParallelContext], params: Para
     b = tok.shape[0]
     cp = int(prefill_chunk)
     P = pend.shape[1]
+    if n_host_chunks and table is not None:
+        # the paged pool's K/V stay host-side: give them their memory
+        # space before they enter the scan carry
+        cache = SV.park_pool(par, cache)
     stop = jnp.asarray(tuple(stop_tokens), jnp.int32)
     V = cfg.vocab_size
 
@@ -376,7 +380,7 @@ def mixed_segment(cfg: ModelConfig, par: Optional[ParallelContext], params: Para
 
 
 def segment_shardings(cfg, par: Optional[ParallelContext], cache, *,
-                      table: bool = False):
+                      table: bool = False, host_pool: bool = False):
     """``(in_shardings, out_shardings)`` for the mixed-segment jit on a
     mesh, or ``None`` off-mesh.
 
@@ -388,12 +392,22 @@ def segment_shardings(cfg, par: Optional[ParallelContext], cache, *,
     ``NamedSharding`` is shape-free, so ONE jitted segment still serves
     every workload capacity: the exactly-2-programs guarantee survives
     meshing.  ``cache`` may be real arrays or ``jax.eval_shape`` structs —
-    only shapes are read."""
+    only shapes are read.
+
+    ``host_pool``: the paged pool's ``pk``/``pv`` enter in the offload
+    pool (their own memory kind) and ``out_shardings`` is ``None``: with a
+    host memory kind among its arguments jit would annotate the placement
+    of every explicitly-sharded output, and the SPMD partitioner refuses
+    those annotations on replicated outputs.  The engine puts the returned
+    cache back onto the in-shardings."""
     if par is None or par.mesh is None:
         return None
-    csh = SV.cache_shardings(cfg, par, cache)
+    csh = SV.cache_shardings(cfg, par, cache, host_pool=host_pool)
     r = par.ns()
-    in_sh = (csh,) + (r,) * (8 + (1 if table else 0))
+    # trailing argument: the weights, replicated (one prefix for the pytree)
+    in_sh = (csh,) + (r,) * (8 + (1 if table else 0)) + (r,)
+    if host_pool:
+        return in_sh, None
     out_sh = (r, r, {"cache": csh, "mode": r, "tok": r, "pos": r, "key": r,
                      "rem": r, "pfill": r})
     return in_sh, out_sh
@@ -460,7 +474,9 @@ class ServeEngine:
     def _build_programs(self) -> None:
         cfg, par, params = self.cfg, self.par, self.params
 
-        def seg(cache, mode, tok, pos, key, rem, pfill, pend, plen):
+        # the weights are an argument, not a closed-over constant: a
+        # constant would be embedded in the executable (GBs at full width)
+        def seg(cache, mode, tok, pos, key, rem, pfill, pend, plen, params):
             return mixed_segment(cfg, par, params, cache, mode, tok, pos, key,
                                  rem, pfill, pend, plen, num_steps=self.segment,
                                  prefill_chunk=self.cp,
@@ -472,12 +488,14 @@ class ServeEngine:
         sh = self._segment_shardings()
         if sh is None:
             self._cache_sh = None
+            self._seg_params = params
             self._segment = jax.jit(per_engine(seg, tel, "segment"))
             self._reset = jax.jit(per_engine(reset_slot, tel, "reset"))
         else:
             in_sh, out_sh = sh
             csh, r = in_sh[0], self.par.ns()
             self._cache_sh = csh
+            self._seg_params = jax.device_put(params, r)
             self._segment = jax.jit(per_engine(seg, tel, "segment"),
                                     in_shardings=in_sh,
                                     out_shardings=out_sh)
@@ -540,7 +558,8 @@ class ServeEngine:
         return self._reset(cache, s), 0
 
     def _dispatch(self, cache, mode, tok, pos, key, rem, pfill, pend, plen):
-        return self._segment(cache, mode, tok, pos, key, rem, pfill, pend, plen)
+        return self._segment(cache, mode, tok, pos, key, rem, pfill, pend, plen,
+                             self._seg_params)
 
     def _post_dispatch(self, mode, pfill, plen, pend, owner) -> None:
         """Host-side bookkeeping after each segment (paged: radix publish)."""

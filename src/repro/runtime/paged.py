@@ -48,6 +48,7 @@ See ``docs/serving.md`` (paged-pool section) for the lifecycle diagram.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -734,34 +735,53 @@ def paged_reset(cache: Params, i, page_ids: jnp.ndarray) -> Params:
     return jax.tree_util.tree_map_with_path(fix, cache)
 
 
-def copy_page(cache: Params, src, dst, drop_from) -> Params:
+def pool_kv(cache: Params, keep: bool) -> Params:
+    """``cache`` with only (``keep=True``) or without (``False``) the
+    pool's ``pk``/``pv`` leaves; the dropped leaves become ``None``.
+    Programs that never touch the pool K/V (``paged_reset``) run on the
+    rest, so a host-parked pool is not passed through them."""
+    def pick(path, leaf):
+        return leaf if (_leaf_names(path)[-1] in ("pk", "pv")) == keep else None
+
+    return jax.tree_util.tree_map_with_path(pick, cache)
+
+
+def join_kv(kv: Params, rest: Params) -> Params:
+    """Inverse of ``pool_kv``: merge the two halves of a cache."""
+    return jax.tree.map(lambda a, b: b if a is None else a, kv, rest,
+                        is_leaf=lambda x: x is None)
+
+
+def copy_page(cache: Params, src, dst, drop_from, *,
+              par: Optional[ParallelContext] = None) -> Params:
     """Copy physical page ``src`` -> ``dst`` in every attention layer (the
     COW primitive).  Entries at in-page offsets ``>= drop_from`` are
     invalidated in the copy: they are the COW'd tail the resumed prefill
     will recompute and rewrite, and leaving them valid would double-count
-    against the chunk program's own intra-window keys."""
+    against the chunk program's own intra-window keys.  ``par`` places a
+    host-parked pool (``None``: the pool is in device memory)."""
     keep = None
 
     def fix(path, leaf):
         nonlocal keep
         names = _leaf_names(path)
         kind = names[-1]
-        if kind not in ("pk", "pv", "pkpos"):
+        if kind in ("pk", "pv"):
+            return SV.set_page(par, leaf, dst, SV.get_page(par, leaf, src))
+        if kind != "pkpos":
             return leaf
         stacked = names[0] != "tail"
         row = leaf[:, src] if stacked else leaf[src]
-        if kind == "pkpos":
-            ps = leaf.shape[-1]
-            if keep is None:
-                keep = jnp.arange(ps) < drop_from
-            row = jnp.where(keep, row, -1)
+        if keep is None:
+            keep = jnp.arange(leaf.shape[-1]) < drop_from
+        row = jnp.where(keep, row, -1)
         return leaf.at[:, dst].set(row) if stacked else leaf.at[dst].set(row)
 
     return jax.tree_util.tree_map_with_path(fix, cache)
 
 
 def promote_page(cache: Params, dst, rows: Dict[str, jnp.ndarray],
-                 keep_below) -> Params:
+                 keep_below, *, par: Optional[ParallelContext] = None) -> Params:
     """Scatter one spilled page's host rows into physical page ``dst`` —
     the spill tier's re-admit primitive, the exact inverse of the
     ``models/serve.py::page_rows`` demotion gather (``rows`` is keyed by
@@ -769,7 +789,8 @@ def promote_page(cache: Params, dst, rows: Dict[str, jnp.ndarray],
     ``>= keep_below`` are invalidated in the scatter: a plain re-admit
     passes ``page_size`` (keep everything), the spilled-COW path passes
     the resume offset so the tail the resumed prefill recomputes is not
-    double-counted (mirroring ``copy_page``'s ``drop_from``)."""
+    double-counted (mirroring ``copy_page``'s ``drop_from``).  ``par``
+    places a host-parked pool."""
     keep = None
 
     def fix(path, leaf):
@@ -779,11 +800,11 @@ def promote_page(cache: Params, dst, rows: Dict[str, jnp.ndarray],
         if kind not in ("pk", "pv", "pkpos"):
             return leaf
         row = jnp.asarray(rows[SV.pool_leaf_key(path)])
-        if kind == "pkpos":
-            ps = leaf.shape[-1]
-            if keep is None:
-                keep = jnp.arange(ps) < keep_below
-            row = jnp.where(keep, row, -1)
+        if kind != "pkpos":
+            return SV.set_page(par, leaf, dst, row)
+        if keep is None:
+            keep = jnp.arange(leaf.shape[-1]) < keep_below
+        row = jnp.where(keep, row, -1)
         stacked = names[0] != "tail"
         return leaf.at[:, dst].set(row) if stacked else leaf.at[dst].set(row)
 
@@ -879,14 +900,21 @@ class PagedServeEngine(DL.ServeEngine):
                 f"at a page boundary, and only a mutually-dividing grid "
                 f"keeps every chunk window inside the slot's allocated page "
                 f"reserve")
-        # two-tier placement: the cold pool lives host-side; attention
-        # streams gathered pages device-ward (no-op on CPU)
+        # two-tier placement: the pool's K/V live host-side and attention
+        # streams pages device-ward
         self._pool_cache = self._offload_pool(self._pool_cache)
         self._cur_cache = self._pool_cache
 
+    @property
+    def _pool_par(self) -> Optional[ParallelContext]:
+        """Placement of the host-parked pool; ``None`` when the pool is
+        in device memory."""
+        return self.par if self.n_host_chunks else None
+
     def _read_page(self, pid: int) -> Dict[str, np.ndarray]:
         """Fetch one physical page's K/V rows host-side (demotion + save)."""
-        return SV.page_rows(self._cur_cache, int(pid))
+        rows = self._page_rows(self._cur_cache, jnp.int32(pid))
+        return {k: np.asarray(jax.device_get(v)) for k, v in rows.items()}
 
     # -- prefix-cache persistence ----------------------------------------
     # cumulative counters (kv_store_saved_pages / kv_store_restored_pages)
@@ -912,65 +940,21 @@ class PagedServeEngine(DL.ServeEngine):
         return n
 
     def _offload_pool(self, cache):
-        """Park the pool's K/V leaves in the offload tier when the engine
-        is host-streaming — applied at init AND after every dispatch (the
-        segment's outputs land in default memory; re-offloading mirrors
-        ``launch/steps.py``'s per-step cache re-offload)."""
-        if self.par is None or not self.n_host_chunks:
-            return cache
-
-        # host-placement custom-calls reject PARTIAL replication: on a
-        # mesh the parked pool must shard over EVERY axis.  Prefer the
-        # in-page dim (pages always divide evenly when ps does), fall back
-        # to kv heads, then the page-count dim; when NO dim divides, a
-        # single-device spec would silently gather a mesh-sharded pool to
-        # one host buffer — skip the offload instead and say so once
-        spec = ()
-        if self.par.mesh is not None:
-            n = self.par.mesh.size
-            all_axes = tuple(self.par.mesh.axis_names)
-            if self.page_size % n == 0:
-                spec = (None, all_axes, None, None)
-            elif self.cfg.num_kv_heads % n == 0:
-                spec = (None, None, all_axes, None)
-            elif (self.n_pages + 1) % n == 0:
-                spec = (all_axes, None, None, None)
-            else:
-                from repro.runtime.placement import _warn_once
-                _warn_once(
-                    "paged-offload-indivisible",
-                    f"pool offload skipped: no pool dim (page_size="
-                    f"{self.page_size}, kv_heads={self.cfg.num_kv_heads}, "
-                    f"pages+1={self.n_pages + 1}) divides mesh size {n}; "
-                    f"the pool stays in device memory")
-                return cache
-
-        def offload(path, leaf):
-            names = _leaf_names(path)
-            if names[-1] not in ("pk", "pv"):
-                return leaf
-            lead = (None,) if names[0] != "tail" else ()
-            return self.par.to_host(leaf, *(lead + spec if spec else ()))
-
-        return jax.tree_util.tree_map_with_path(offload, cache)
-
-    def _onload_pool(self, cache):
-        """Fetch a host-parked pool into device memory before a program
-        runs on it.  No op may mix memory kinds, so the page gathers and
-        scatters need the pool on device, and a sharded jit refuses an
-        argument whose memory kind differs from its in-sharding (a host
-        kind in the in-shardings instead makes jit annotate every output's
-        placement, which the SPMD partitioner rejects for the replicated
-        ``pkpos`` leaves)."""
+        """Put a returned pool back where the programs take it — on a mesh
+        the in-shardings, else the pool's K/V parked in the offload tier
+        when the engine is host-streaming.  Called at init and after every
+        program that returns the pool; a no-op where the output already
+        is there.  A host-parked pool on a mesh splits its page rows over
+        every axis (``SV.pool_host_spec``), which raises when it cannot."""
         if self._cache_sh is not None:
-            return self.par.pol.put(cache, self._cache_sh)
-        if self.par is None or not self.n_host_chunks:
+            return jax.device_put(cache, self._cache_sh)
+        if self._pool_par is None:
             return cache
-        return jax.tree.map(self.par.to_device, cache)
-
-    def _pool_call(self, program, cache, *args):
-        """Run a pool program on the device-resident pool; park it again."""
-        return self._offload_pool(program(self._onload_pool(cache), *args))
+        # commit every leaf where it is: the parked K/V are committed, so
+        # every program output is, and commitment is part of a program's
+        # signature (an uncommitted first pool would compile twice)
+        cache = jax.tree.map(lambda l: jax.device_put(l, l.sharding), cache)
+        return SV.park_pool(self._pool_par, cache)
 
     # -- compiled programs ----------------------------------------------
     def _segment_shardings(self):
@@ -980,12 +964,14 @@ class PagedServeEngine(DL.ServeEngine):
         if self.par is None or self.par.mesh is None:
             return None
         return DL.segment_shardings(self.cfg, self.par, self._pool_cache,
-                                    table=True)
+                                    table=True,
+                                    host_pool=self._pool_par is not None)
 
     def _build_programs(self) -> None:
         cfg, par, params = self.cfg, self.par, self.params
 
-        def seg(cache, mode, tok, pos, key, rem, pfill, pend, plen, table):
+        def seg(cache, mode, tok, pos, key, rem, pfill, pend, plen, table,
+                params):
             return DL.mixed_segment(cfg, par, params, cache, mode, tok, pos,
                                     key, rem, pfill, pend, plen,
                                     num_steps=self.segment,
@@ -996,36 +982,46 @@ class PagedServeEngine(DL.ServeEngine):
                                     pad_id=self.pad_id, table=table)
 
         tel = self.telemetry
+        pool_par = self._pool_par
+        copy = functools.partial(copy_page, par=pool_par)
+        promote = functools.partial(promote_page, par=pool_par)
+        self._page_rows = jax.jit(functools.partial(SV.page_rows, pool_par))
         sh = self._segment_shardings()
         if sh is None:
             self._cache_sh = None
+            self._seg_params = params
             self._segment = jax.jit(DL.per_engine(seg, tel, "segment"))
             self._reset = jax.jit(DL.per_engine(paged_reset, tel, "reset"))
-            self._copy = jax.jit(DL.per_engine(copy_page, tel, "copy"))
-            self._promote = jax.jit(
-                DL.per_engine(promote_page, tel, "promote"))
+            self._copy = jax.jit(DL.per_engine(copy, tel, "copy"))
+            self._promote = jax.jit(DL.per_engine(promote, tel, "promote"))
         else:
             # page copy/COW become sharded programs over the same pool
             # layout — each device moves only its own head (or in-page)
-            # slice, no gather to one device
+            # slice, no gather to one device.  Reset never sees the pool
+            # K/V (``pool_kv``); with a host-parked pool the programs leave
+            # their output placement to the partitioner (see
+            # ``DL.segment_shardings``) and ``_offload_pool`` restores it
             in_sh, out_sh = sh
             csh, r = in_sh[0], par.ns()
+            osh = None if out_sh is None else csh
+            rest = pool_kv(csh, keep=False)
             self._cache_sh = csh
+            self._seg_params = jax.device_put(params, r)
             self._segment = jax.jit(DL.per_engine(seg, tel, "segment"),
                                     in_shardings=in_sh,
                                     out_shardings=out_sh)
             self._reset = jax.jit(DL.per_engine(paged_reset, tel, "reset"),
-                                  in_shardings=(csh, r, r), out_shardings=csh)
-            self._copy = jax.jit(DL.per_engine(copy_page, tel, "copy"),
+                                  in_shardings=(rest, r, r),
+                                  out_shardings=rest)
+            self._copy = jax.jit(DL.per_engine(copy, tel, "copy"),
                                  in_shardings=(csh, r, r, r),
-                                 out_shardings=csh)
+                                 out_shardings=osh)
             # the promoted rows dict gets `r` as a pytree PREFIX: every
             # host-staged row enters replicated, the scatter re-shards it
             # into the pool's own layout
-            self._promote = jax.jit(
-                DL.per_engine(promote_page, tel, "promote"),
-                in_shardings=(csh, r, r, r),
-                out_shardings=csh)
+            self._promote = jax.jit(DL.per_engine(promote, tel, "promote"),
+                                    in_shardings=(csh, r, r, r),
+                                    out_shardings=osh)
             # commit the persistent pool to its sharding NOW: the first
             # admit otherwise sees uncommitted arrays and compiles a second
             # reset signature, breaking the bounded-program guarantee
@@ -1071,12 +1067,14 @@ class PagedServeEngine(DL.ServeEngine):
             raise ValueError(str(e)) from None
         ids = np.full(self.n_pages, self.n_pages + 1, np.int32)  # pad -> OOB
         ids[: len(plan.fresh_pages)] = plan.fresh_pages
-        cache = self._pool_call(self._reset, cache, s, jnp.asarray(ids))
+        cache = join_kv(pool_kv(cache, keep=True),
+                        self._reset(pool_kv(cache, keep=False), s,
+                                    jnp.asarray(ids)))
         for sid, dst, keep in plan.promote:
             rows = {k: jnp.asarray(v)
                     for k, v in self.kv.spill.read(sid).items()}
-            cache = self._pool_call(self._promote, cache, jnp.int32(dst),
-                                    rows, jnp.int32(keep))
+            cache = self._offload_pool(self._promote(
+                cache, jnp.int32(dst), rows, jnp.int32(keep)))
             st["spill_promotes"] += 1
         if plan.promote:
             self.telemetry.event("pool.promote", request=idx, slot=s,
@@ -1084,9 +1082,9 @@ class PagedServeEngine(DL.ServeEngine):
         for sid in plan.free_spill:  # scatter dispatched: slot reusable
             self.kv.spill.free(sid)
         for src, dst in plan.cow:
-            cache = self._pool_call(self._copy, cache, jnp.int32(src),
-                                    jnp.int32(dst),
-                                    jnp.int32(plan.resume % self.page_size))
+            cache = self._offload_pool(self._copy(
+                cache, jnp.int32(src), jnp.int32(dst),
+                jnp.int32(plan.resume % self.page_size)))
             st["cow_copies"] += 1
             self.telemetry.event("pool.cow", request=idx, slot=s,
                                  src=int(src), dst=int(dst))
@@ -1107,9 +1105,9 @@ class PagedServeEngine(DL.ServeEngine):
     def _dispatch(self, cache, mode, tok, pos, key, rem, pfill, pend, plen):
         if self._table_dev is None:
             self._table_dev = jnp.asarray(self.kv.table)
-        emits, valids, aux = self._segment(self._onload_pool(cache), mode,
-                                           tok, pos, key, rem, pfill, pend,
-                                           plen, self._table_dev)
+        emits, valids, aux = self._segment(cache, mode, tok, pos, key, rem,
+                                           pfill, pend, plen, self._table_dev,
+                                           self._seg_params)
         aux["cache"] = self._offload_pool(aux["cache"])
         # keep the persistent pool pointing at the freshest value: pages
         # published to the radix tree mid-workload must survive a failure

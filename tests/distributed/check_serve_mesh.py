@@ -15,8 +15,9 @@ kv-heads over ``model`` per ``cache_shardings``, segment jit carrying
   * ssm (falcon-mamba): recurrent per-slot state on the mesh, token
     parity (paged pool degrades to per-slot dense state there);
   * dense (non-paged) ServeEngine on the mesh, token parity;
-  * llama ps=4 + host streaming: page_size does NOT divide the 8-device
-    mesh, so the pool-offload placement falls back to the kv-head dim;
+  * llama ps=4 + host streaming: the pool's K/V stay host-side with
+    their page rows (kv_heads * head_dim wide) split over all 8 devices,
+    though page_size does not divide the mesh;
   * llama-spill: a tight pool demotes radix pages to the spill tier and
     re-serves them through the sharded promote scatter, matching the
     single-device oracle across the whole three-workload sequence.
@@ -114,6 +115,11 @@ def engine_parity(arch, name, *, paged=True, n_host_chunks=0, page_size=8,
             assert hit > 0, f"{name}: second run should radix-hit"
             want2 = e0.generate(prompts)
             assert got2 == want2, f"{name}: post-radix-hit tokens diverge"
+        if n_host_chunks:
+            kinds = {leaf.sharding.memory_kind for path, leaf
+                     in jax.tree_util.tree_flatten_with_path(e1._pool_cache)[0]
+                     if str(path[-1].key) in ("pk", "pv")}
+            assert kinds == {"pinned_host"}, f"{name}: pool K/V in {kinds}"
         progs = e1.compiled_programs()
         expect = ({"segment", "reset", "copy", "promote"} if paged
                   else {"segment", "reset"})
@@ -177,9 +183,8 @@ if __name__ == "__main__":
     # dense engine path (no pool) also carries mesh shardings
     engine_parity("llama3.2-1b", "llama-dense", paged=False, num_heads=4,
                   num_kv_heads=4)
-    # ps=4 does NOT divide the 8-device mesh while host-streaming: the
-    # pool-offload spec must fall back to the kv-head dim (hkv=8 % 8 == 0)
-    # instead of silently building a single-device sharding
+    # host streaming on the mesh: ps=4 does NOT divide the 8 devices, the
+    # page rows (hkv * dh) do, and the pool must stay host-side
     engine_parity("llama3.2-1b", "llama-psindiv-stream", num_heads=8,
                   num_kv_heads=8, page_size=4, n_pages=48, n_host_chunks=2)
     # demote/promote round-trip + persistence program bound on the mesh

@@ -312,17 +312,25 @@ def test_paged_engine_matches_dense(name):
 
 
 def test_paged_engine_host_streamed():
-    """n_host_chunks > 0: pages stream through fori_double_buffered (the
-    two-tier path; placement no-ops on CPU) — same ids as dense."""
+    """n_host_chunks > 0: the pool's K/V stay in the offload pool between
+    AND inside programs, pages stream through fori_double_buffered and
+    writes are whole-page read-modify-writes — same ids as dense."""
     from repro.core.parallel import ParallelContext
 
     cfg, params = setup("llama3.2-1b")
     prompts = prompts_for(cfg, (5, 9, 3), seed=4)
     kw = dict(slots=2, bucket=8, max_new_tokens=4, segment=2, prefill_chunk=4)
     ref = DL.ServeEngine(cfg, params, **kw).generate(prompts)
+    par = ParallelContext(mesh=None)
     eng = PG.PagedServeEngine(cfg, params, page_size=4, n_host_chunks=2,
-                              par=ParallelContext(mesh=None), **kw)
+                              par=par, **kw)
     assert eng.generate(prompts) == ref
+    assert eng.compiled_programs()["segment"] == eng.compiled_programs()["reset"] == 1
+    if par.offload_active:
+        kinds = {str(path[-1].key): leaf.sharding.memory_kind for path, leaf
+                 in jax.tree_util.tree_flatten_with_path(eng._pool_cache)[0]}
+        assert kinds["pk"] == kinds["pv"] == par.pol.host_kind
+        assert kinds["pkpos"] != par.pol.host_kind
 
 
 # ---------------------------------------------------------------------------

@@ -73,6 +73,22 @@ def test_host_chunked_decode_matches_plain():
     np.testing.assert_allclose(np.asarray(l8), np.asarray(l0), rtol=1e-4, atol=1e-4)
 
 
+def test_dense_host_chunks_refused_on_accelerator():
+    """The dense cache is never host-parked, so host-chunked attention over
+    it would be offload silently not done on an accelerator: it raises."""
+    from repro.runtime.placement import PlacementPolicy
+
+    cfg = dataclasses.replace(reduced(get_config("llama3.2-1b")), remat="none")
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: SV.init_cache(cfg, 1, 16))
+    tpu = PlacementPolicy(device_kind="device", host_kind="pinned_host", backend="tpu")
+    par = ParallelContext(mesh=None, placement=tpu)
+    with pytest.raises(NotImplementedError, match="PagedServeEngine"):
+        jax.eval_shape(lambda p, c: SV.decode_step(
+            cfg, par, p, c, {"tokens": jnp.zeros((1, 1), jnp.int32)}, 3,
+            n_host_chunks=2), params, cache)
+
+
 def _per_token_loop(cfg, par, params, cache, tok0, pos0, steps, n_host_chunks=0):
     """Reference: one decode_step dispatch per token, greedy."""
     outs, logits_all = [tok0], []

@@ -116,3 +116,61 @@ def test_fpdt_layer_compiles_with_offload(one_chip, monkeypatch):
     hlo = _hlo(jax.grad(loss, argnums=(0, 1)), x, p)
     assert "tpu_custom_call" in hlo
     assert "pinned_host" in hlo or "S(5)" in hlo  # the KV/q stores live on host
+
+
+def test_paged_serve_keeps_host_pool_off_device(one_chip):
+    """The host-streamed paged serve programs at llama3.2-1b widths: the
+    pool's K/V enter and leave in pinned host memory, and no program
+    copies the pool into device memory (device temporaries stay well under
+    the pool's size)."""
+    import functools
+
+    from repro.core.parallel import ParallelContext
+    from repro.models import serve as SV
+    from repro.models import transformer as T
+    from repro.runtime import decode_loop as DL
+    from repro.runtime import paged as PG
+    from repro.runtime.placement import PlacementPolicy
+
+    cfg = dataclasses.replace(get_config("llama3.2-1b"), remat="none")
+    par = ParallelContext(mesh=None, placement=PlacementPolicy.probe(one_chip))
+    assert par.offload_active
+    slots, n_pages, ps, max_pages, cp = 4, 2048, 16, 64, 64
+    host = SingleDeviceSharding(one_chip, memory_kind="pinned_host")
+
+    def place(path, s):
+        on_host = str(getattr(path[-1], "key", "")) in ("pk", "pv")
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=(
+            host if on_host else SingleDeviceSharding(one_chip)))
+
+    cache = jax.tree_util.tree_map_with_path(place, jax.eval_shape(
+        lambda: SV.init_paged_cache(cfg, slots, n_pages, ps)))
+    pool = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(cache)
+               if l.sharding.memory_kind == "pinned_host")
+    params = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    i32 = functools.partial(_sds, one_chip, dtype=jnp.int32)
+
+    def seg(params, cache, mode, tok, pos, key, rem, pfill, pend, plen, table):
+        return DL.mixed_segment(cfg, par, params, cache, mode, tok, pos, key, rem,
+                                pfill, pend, plen, num_steps=2, prefill_chunk=cp,
+                                n_host_chunks=1, table=table)
+
+    seg_args = (params, cache, i32((slots,)), i32((slots, 1)), i32((slots,)),
+                _sds(one_chip, (2,), jnp.uint32), i32((slots,)), i32((slots,)),
+                i32((slots, 4 * cp)), i32((slots,)), i32((slots, max_pages)))
+    rows = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), jax.eval_shape(
+        lambda c: SV.page_rows(None, c, 0), cache))
+    i = i32(())
+    programs = {
+        "segment": (seg, seg_args),
+        "copy": (functools.partial(PG.copy_page, par=par), (cache, i, i, i)),
+        "promote": (functools.partial(PG.promote_page, par=par), (cache, i, rows, i)),
+        "page_rows": (functools.partial(SV.page_rows, par), (cache, i)),
+    }
+    for name, (fn, args) in programs.items():
+        c = jax.jit(fn).lower(*args).compile()
+        assert c.memory_analysis().temp_size_in_bytes < pool / 4, name
+        if name != "page_rows":
+            kinds = [s.memory_kind for s in jax.tree.leaves(c.output_shardings)]
+            assert kinds.count("pinned_host") == 2, (name, kinds)  # pk, pv
