@@ -236,6 +236,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     kv_spec = _host_spec_kv(par, kind, hkv * rep, seq_len // u)
     q_spec = _host_spec_kv(par, kind, hq, seq_len // u)
 
+    @jax.named_scope("fpdt.project")
     def project(p, xi, i):
         """(q, k, v) of hidden chunk i in head layout; i may be traced."""
         q, k, v = qkv_proj(cfg, p, xi)  # [b, cq, h, dh]
@@ -289,6 +290,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             # overlaps compute by program order, not XLA scheduling luck.
             live = [j for j in range(i) if live_py(i, j)]
 
+            @jax.named_scope("fpdt.fetch")
             def fetch_kv(j):
                 kj, vj = kv_store[j]
                 return to_dev(kj), to_dev(vj)
@@ -299,8 +301,9 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             st = SoftmaxState(*carry)
             oi = finalize(st)  # [b, h, cq, dh] fp32
             Li = lse(st)
-            kv_store.append((to_host(ki), to_host(vi)))
-            res_q.append(to_host(qi, q_spec))
+            with jax.named_scope("fpdt.store"):
+                kv_store.append((to_host(ki), to_host(vi)))
+                res_q.append(to_host(qi, q_spec))
             res_o.append(oi)
             Ls.append(Li)
             # back to token layout + seq sharding (inverse all-to-all)
@@ -333,10 +336,12 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         # next KV chunk's fetch is issued before this chunk's inner loop,
         # and the next query chunk's fetch before the current (i, j) pair's
         # kernels — each copy overlaps the preceding chunk's compute.
+        @jax.named_scope("fpdt.fetch")
         def fetch_kv(j):
             kj, vj = kv_store[j]
             return to_dev(kj), to_dev(vj)
 
+        @jax.named_scope("fpdt.fetch")
         def fetch_q(i):
             return to_dev(res_q[i], q_spec)
 
@@ -361,6 +366,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         dv_stack = jnp.stack([dv if dv is not None else zkv for dv in dvs])
         return _unproject_unrolled(x, p, dq_stack, dk_stack, dv_stack)
 
+    @jax.named_scope("fpdt.unproject")
     def _unproject_body(p, i, xi, dq, dk, dv, b):
         """Shared per-chunk grad epilogue: a2a back, un-rope, un-project.
         Returns (dx_i, dW contributions).  i may be traced (scan path)."""
@@ -419,10 +425,12 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     # moves host-ward first and is written host-into-host; a read slices the
     # host store and then fetches the slice (XLA's host-offload pass turns
     # both into DMAs).
+    @jax.named_scope("fpdt.store")
     def _store(store, chunk, i, spec):
         return jax.lax.dynamic_update_index_in_dim(store, to_host(chunk, spec),
                                                    i, axis=0)
 
+    @jax.named_scope("fpdt.fetch")
     def _load(store, j, spec):
         return to_dev(jax.lax.dynamic_index_in_dim(store, j, axis=0,
                                                    keepdims=False), spec)
@@ -433,11 +441,12 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         proj_dtype = jnp.result_type(x.dtype, p["wq"].dtype)
         # stores start in the offload pool so the scan carry's memory
         # placement agrees between loop entry and the to_host'd body outputs
-        kst0 = to_host(jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype),
-                       kv_store_spec)
-        vst0 = to_host(jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype),
-                       kv_store_spec)
-        qst0 = to_host(jnp.zeros((u, b, hq, cq, dh), proj_dtype), q_store_spec)
+        with jax.named_scope("fpdt.store"):
+            kst0 = to_host(jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype),
+                           kv_store_spec)
+            vst0 = to_host(jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype),
+                           kv_store_spec)
+            qst0 = to_host(jnp.zeros((u, b, hq, cq, dh), proj_dtype), q_store_spec)
 
         def body(carry, inp):
             kst, vst, qst = carry

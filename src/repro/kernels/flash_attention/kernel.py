@@ -43,6 +43,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128  # TPU vreg lane width: compiled blocks are multiples of it
+# Each kernel's ``pallas_call`` name.  The call pushes it onto the name stack
+# (the Mosaic custom call's op_name reads ``.../flash_attention_fwd/pallas_call``)
+# and XLA names the custom-call instruction after it (``%flash_attention_fwd.4``),
+# so a profiler trace names each kernel.  No other instruction or scope in the
+# program has a name beginning ``flash_attention``.
+FWD, DQ, DKV = "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"
 
 
 def _fit_block(n: int, block: int, *, tiled: bool = False) -> int:
@@ -251,6 +257,7 @@ def flash_fwd(
         out_shape=[jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32), row_shape, row_shape],
         interpret=interpret,
         compiler_params=_compiler_params(),
+        name=FWD,
     )(_offsets_operand(q_offset, k_offset), q, k, v, acc0, _rows(m0), _rows(l0))
     return acc, _unrows(m), _unrows(l)
 
@@ -330,6 +337,7 @@ def flash_bwd_dq(
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
         interpret=interpret,
         compiler_params=_compiler_params(),
+        name=DQ,
     )(_offsets_operand(q_offset, k_offset), q, k, v, do, _rows(L), _rows(delta))
 
 
@@ -418,4 +426,5 @@ def flash_bwd_dkv(
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
+        name=DKV,
     )(_offsets_operand(q_offset, k_offset), q, k, v, do, _rows(L), _rows(delta))

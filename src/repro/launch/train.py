@@ -185,6 +185,7 @@ def run(args: argparse.Namespace,
               f"u={cfg.fpdt_chunks}, offload={cfg.fpdt_offload}, "
               f"mesh={args.mesh}; step compiled in {compile_s:.1f}s")
         loop = TrainLoop(cfg, par, oc, tc, compiled, CheckpointableIterator(bf), mgr)
+        loop.telemetry.compile_event("train_step")  # the ahead-of-time compile
         params, opt_state, _ = loop.run(params, opt_state, start_step=start,
                                            put_batch=put, on_step=on_step)
     if args.trace_out or args.metrics_out:

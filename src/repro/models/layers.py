@@ -145,6 +145,7 @@ def mlp_block(cfg: ModelConfig, p: Params, x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.gelu(x @ p["wu"]) @ p["wd"]
 
 
+@jax.named_scope("mlp")
 def mlp_chunked(cfg: ModelConfig, p: Params, x: jnp.ndarray, n_chunks: int) -> jnp.ndarray:
     """Paper §5.4: token-wise ops chunked along the sequence (no offload —
     O(N) compute can never hide transfer latency).  Implemented as a

@@ -234,7 +234,9 @@ def loss_fn(cfg: ModelConfig, par: Optional[ParallelContext],
         labels = jnp.concatenate([pad, labels], axis=1)
     n_chunks = cfg.loss_chunks or auto_chunks(
         cfg, h.shape[1], sp=par.sp if par is not None else 1)
-    loss_sum, count = softmax_xent_chunked(h, head_matrix(cfg, params), labels, n_chunks, par=par)
+    with jax.named_scope("loss_head"):
+        loss_sum, count = softmax_xent_chunked(h, head_matrix(cfg, params), labels, n_chunks,
+                                               par=par)
     loss = loss_sum / jnp.maximum(count, 1.0)
     total = loss + 0.01 * aux
     return total, {"loss": loss, "aux": aux, "tokens": count}

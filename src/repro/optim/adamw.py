@@ -57,6 +57,7 @@ def global_norm(grads) -> jnp.ndarray:
     )
 
 
+@jax.named_scope("adamw")
 def apply(oc: OptConfig, params, grads, state: OptState):
     """Returns (new_params, new_state, metrics)."""
     gnorm = global_norm(grads)

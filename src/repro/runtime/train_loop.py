@@ -11,6 +11,7 @@ Fault tolerance for 1000+ nodes (DESIGN.md §7):
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import signal
 import time
@@ -96,15 +97,17 @@ def make_train_step(cfg: ModelConfig, par: Optional[ParallelContext],
 class HeartbeatMonitor:
     """Detects persistent stragglers from per-step wall time."""
 
+    HISTORY = 100  # steps a new step time is scored against
+
     def __init__(self, zscore: float, patience: int):
-        self.times: list = []
+        self.times: collections.deque = collections.deque(maxlen=self.HISTORY + 1)
         self.z = zscore
         self.patience = patience
         self.bad = 0
 
     def record(self, dt: float) -> None:
         self.times.append(dt)
-        hist = self.times[:-1][-100:]
+        hist = list(self.times)[:-1]
         if len(hist) >= 10:
             mu, sd = float(np.mean(hist)), float(np.std(hist)) + 1e-9
             if (dt - mu) / sd > self.z:
@@ -141,22 +144,36 @@ class TrainLoop:
     def run(self, params, opt_state, start_step: int = 0, put_batch=None,
             on_step: Optional[Callable[[int, Any, Dict], None]] = None):
         """Train to ``tc.steps``; ``on_step(step, params, metrics)`` runs
-        after each step's result is ready."""
+        after each step's result is ready.
+
+        Each step is a ``jax.profiler.StepTraceAnnotation("train")`` holding
+        ``train.feed``, ``train.dispatch`` and ``train.block`` spans, so a
+        profiler trace puts the host's phases beside the device's ops.  A
+        jitted ``step_fn`` that compiles a new program counts it through
+        ``Telemetry.compile_event("train_step")``."""
         self._install_signals()
         if put_batch is None:
             # default batch staging routes through the placement layer
             pol = self.par.pol if self.par is not None else placement.default_policy()
             put_batch = lambda b: {k: pol.put(jnp.asarray(v)) for k, v in b.items()}  # noqa: E731
+        # a jit's count of compiled programs; an AOT-compiled step has none
+        programs = getattr(self.step_fn, "_cache_size", lambda: 0)
         step = start_step
         self.data.restore(start_step)
         while step < self.tc.steps and not self._stop:
             t0 = time.perf_counter()
-            batch = next(self.data)
-            batch = put_batch(batch)
-            params, opt_state, metrics = self.step_fn(params, opt_state, batch)[:3]
-            jax.block_until_ready(metrics["loss"])
+            with jax.profiler.StepTraceAnnotation("train", step_num=step + 1):
+                with jax.profiler.TraceAnnotation("train.feed"):
+                    batch = put_batch(next(self.data))
+                n_programs = programs()
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    params, opt_state, metrics = self.step_fn(params, opt_state, batch)[:3]
+                with jax.profiler.TraceAnnotation("train.block"):
+                    jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
             step += 1
+            for _ in range(programs() - n_programs):
+                self.telemetry.compile_event("train_step", step=step)
             self.history.append({"step": step, "loss": float(metrics["loss"]), "dt": dt})
             self.telemetry.registry.histogram("train_step_ms").observe(dt * 1e3)
             self.telemetry.registry.counter("train_steps").inc()

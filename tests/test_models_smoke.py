@@ -75,3 +75,39 @@ def test_full_config_matches_assignment(name):
         assert cfg.ssm_state == 16
     if name == "recurrentgemma-9b":
         assert cfg.window == 2048 and cfg.block_pattern == ("rglru", "rglru", "local_attn")
+
+
+def _scope_paths(hlo: str):
+    """Each op_name as its list of components, a component ``transpose(jvp(x))``
+    read as the names ``transpose``, ``jvp``, ``x``."""
+    import re
+
+    return [[re.findall(r"[^()]+", c) for c in n.split("/")]
+            for n in set(re.findall(r'op_name="([^"]*)"', hlo))]
+
+
+def test_train_step_keeps_named_scopes_in_hlo():
+    """The train step's compiled HLO keeps the program's scopes in op_name
+    metadata, backward and recompute included: ``loss_head`` and ``mlp``
+    under ``transpose(...)``, the FPDT projections, and ``adamw``."""
+    from repro.optim import adamw
+    from repro.runtime.train_loop import make_train_step
+
+    cfg = dataclasses.replace(reduced(get_config("llama3.2-1b")), num_layers=1,
+                              fpdt_chunks=2, mlp_chunks=2)
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    oc = adamw.OptConfig()
+    opt = jax.eval_shape(lambda p: adamw.init(oc, p), params)
+    batch = {k: jax.ShapeDtypeStruct((1, 64), jnp.int32) for k in ("tokens", "labels")}
+    hlo = jax.jit(make_train_step(cfg, None, oc)).lower(params, opt, batch).compile().as_text()
+    paths = _scope_paths(hlo)
+
+    def under(scope, backward):
+        return [p for p in paths if any(scope in c for c in p)
+                and any("transpose" in c for c in p) == backward]
+
+    for scope in ("loss_head", "mlp", "fpdt.project"):
+        assert under(scope, backward=False), scope
+    for scope in ("loss_head", "mlp", "fpdt.project", "fpdt.unproject"):
+        assert under(scope, backward=True), scope
+    assert under("adamw", backward=False)

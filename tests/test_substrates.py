@@ -131,8 +131,9 @@ def test_straggler_monitor():
     from repro.runtime.train_loop import HeartbeatMonitor, StragglerAlert
 
     mon = HeartbeatMonitor(zscore=3.0, patience=2)
-    for _ in range(20):
+    for _ in range(200):
         mon.record(0.1 + np.random.default_rng(0).uniform(0, 0.001))
+    assert len(mon.times) == HeartbeatMonitor.HISTORY + 1  # bounded history
     with pytest.raises(StragglerAlert):
         mon.record(5.0)
         mon.record(5.0)

@@ -414,3 +414,45 @@ def test_compile_counters_match_program_cache():
         assert eng.telemetry.registry.value(f"compiles_{name}") == cached, \
             name
     assert eng.telemetry.alerts() == 0
+
+
+def test_train_loop_counts_compiles_and_profiles_steps(tmp_path):
+    """Three steps of a jitted train step compile one program
+    (``compiles_train_step``, no alert), and a ``jax.profiler`` trace holds
+    each step's ``train`` annotation with its step number around the
+    ``train.feed``/``train.dispatch``/``train.block`` spans."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from repro.configs import ShapeConfig, get_config, reduced
+    from repro.data.pipeline import CheckpointableIterator, make_batch_fn
+    from repro.models import transformer as T
+    from repro.optim import adamw
+    from repro.runtime.train_loop import TrainConfig, TrainLoop, make_train_step
+
+    cfg = dataclasses.replace(reduced(get_config("llama3.2-1b")), num_layers=1)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    oc = adamw.OptConfig(lr=1e-3, warmup_steps=2, total_steps=10)
+    tc = TrainConfig(steps=3, log_every=100)
+    loop = TrainLoop(cfg, None, oc, tc, jax.jit(make_train_step(cfg, None, oc, tc)),
+                     CheckpointableIterator(make_batch_fn(cfg, ShapeConfig("t", 32, 2, "train"))))
+    with jax.profiler.trace(str(tmp_path)):
+        loop.run(params, adamw.init(oc, params),
+                 put_batch=lambda b: {k: jnp.asarray(v) for k, v in b.items()})
+    assert loop.telemetry.registry.value("compiles_train_step") == 1
+    assert loop.telemetry.alerts() == 0
+    assert [h["step"] for h in loop.history] == [1, 2, 3]
+
+    pd = ProfileData.from_file(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[-1])
+    events = sorted(((e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+                     for p in pd.planes if p.name.startswith("/host:")
+                     for ln in p.lines for e in ln.events
+                     if e.name == "train" or e.name.startswith("train.")), key=lambda e: e[0])
+    steps = [e for e in events if e[2] == "train"]
+    assert [e[3].get("step_num") for e in steps] == [1, 2, 3]
+    for s, t, _, _ in steps:
+        inside = [e[2] for e in events if s <= e[0] and e[1] <= t and e[2] != "train"]
+        assert inside == ["train.feed", "train.dispatch", "train.block"]

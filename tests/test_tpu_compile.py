@@ -7,6 +7,7 @@ inside a fixture (skipping where it cannot be), never at import.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,15 +93,15 @@ def test_linear_scan_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def test_fpdt_layer_compiles_with_offload(one_chip, monkeypatch):
-    """One scan-compiled FPDT attention layer, fwd+bwd, u=8, offload on."""
+@pytest.fixture(scope="module")
+def fpdt_offload_hlo(one_chip):
+    """Compiled HLO of one scan-compiled FPDT attention layer, fwd+bwd, u=8,
+    offload on."""
     from repro.core import fpdt
     from repro.core.parallel import ParallelContext
     from repro.models import layers as L
     from repro.runtime.placement import PlacementPolicy
 
-    # the kernels pick interpret mode from the process's (CPU) backend
-    monkeypatch.setattr(FK, "_default_interpret", lambda: False)
     cfg = dataclasses.replace(get_config("llama3.2-1b"), fpdt_chunks=8, fpdt_offload=True)
     par = ParallelContext(mesh=None, attn_impl="pallas",
                           placement=PlacementPolicy.probe(one_chip))
@@ -113,9 +114,62 @@ def test_fpdt_layer_compiles_with_offload(one_chip, monkeypatch):
     def loss(x, p):
         return fpdt.fpdt_attention(cfg, par, p, x, kind="local").astype(jnp.float32).sum()
 
-    hlo = _hlo(jax.grad(loss, argnums=(0, 1)), x, p)
+    # the kernels pick interpret mode from the process's (CPU) backend
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(FK, "_default_interpret", lambda: False)
+        return _hlo(jax.grad(loss, argnums=(0, 1)), x, p)
+
+
+def test_fpdt_layer_compiles_with_offload(fpdt_offload_hlo):
+    """One scan-compiled FPDT attention layer, fwd+bwd, u=8, offload on."""
+    hlo = fpdt_offload_hlo
     assert "tpu_custom_call" in hlo
     assert "pinned_host" in hlo or "S(5)" in hlo  # the KV/q stores live on host
+
+
+KERNELS = (FK.FWD, FK.DQ, FK.DKV)
+
+
+def _instructions(hlo):
+    """(name, opcode, op_name components, line) of each HLO instruction."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = .*?[\]\}\)] ([a-z][a-z0-9-]*)\(", line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(1), m.group(2), op.group(1).split("/") if op else [], line))
+    return out
+
+
+def test_fpdt_layer_names_its_kernels_and_offload_ops(fpdt_offload_hlo):
+    """Each Mosaic kernel's op_name ends ``<kernel>/pallas_call`` with one of
+    the three kernel names and its instruction is named after it; no other
+    instruction or scope is named ``flash_attention...``; the host stores and
+    fetches sit under ``fpdt.store`` and ``fpdt.fetch``."""
+    ins = _instructions(fpdt_offload_hlo)
+    kernels = [i for i in ins if 'custom_call_target="tpu_custom_call"' in i[3]]
+    assert {i[2][-2] for i in kernels} == set(KERNELS)
+    for name, _, comps, _ in kernels:
+        assert comps[-1] == "pallas_call"
+        assert sum(c in KERNELS for c in comps) == 1
+        assert name.split(".")[0] == comps[-2]
+    for name, opcode, comps, _ in ins:
+        if (name, opcode) in {(k[0], k[1]) for k in kernels}:
+            continue
+        assert not name.startswith("flash_attention"), name
+        named = [c for c in comps if c.startswith("flash_attention")]
+        # only the kernels' own tuple outputs share their op_name
+        assert not named or (opcode == "get-tuple-element" and comps[-1] == "pallas_call"), name
+    host_starts = {i[0] for i in ins if i[1].endswith("-start") and "S(5)" in i[3]}
+    stores = [i for i in ins if i[1] in ("dynamic-update-slice-start", "dynamic-update-slice-done")]
+    fetches = [i for i in ins if (i[1] == "dynamic-slice-start" and i[0] in host_starts) or (
+        i[1] == "dynamic-slice-done" and re.search(r"\(%(\S+)\)", i[3]).group(1) in host_starts)]
+    assert stores and fetches
+    assert all("fpdt.store" in i[2] for i in stores)
+    # the Fig. 7 backward's first K and V fetch (chunk 0, a constant index) is
+    # rebuilt by XLA without an op_name; every other fetch is under fpdt.fetch
+    assert all("fpdt.fetch" in i[2] for i in fetches if i[2])
+    assert len([i for i in fetches if not i[2]]) <= 4
 
 
 def test_paged_serve_keeps_host_pool_off_device(one_chip):
