@@ -442,11 +442,14 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         # stores start in the offload pool so the scan carry's memory
         # placement agrees between loop entry and the to_host'd body outputs
         with jax.named_scope("fpdt.store"):
-            kst0 = to_host(jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype),
-                           kv_store_spec)
-            vst0 = to_host(jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype),
-                           kv_store_spec)
-            qst0 = to_host(jnp.zeros((u, b, hq, cq, dh), proj_dtype), q_store_spec)
+            kv0 = jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype)
+            q0 = jnp.zeros((u, b, hq, cq, dh), proj_dtype)
+            # tied to this layer's x: a pinned-host zero hoisted out of the
+            # layer loop is copied to each layer by a host-executed call
+            (kv0, q0), _ = jax.lax.optimization_barrier(((kv0, q0), x))
+            kst0 = to_host(kv0, kv_store_spec)
+            vst0 = to_host(kv0, kv_store_spec)
+            qst0 = to_host(q0, q_store_spec)
 
         def body(carry, inp):
             kst, vst, qst = carry

@@ -172,6 +172,41 @@ def test_fpdt_layer_names_its_kernels_and_offload_ops(fpdt_offload_hlo):
     assert len([i for i in fetches if not i[2]]) <= 4
 
 
+def test_fpdt_train_step_has_no_host_executed_copies(one_chip):
+    """A 2-layer layer-scanned dense train step, u=4, offload on, remat full:
+    the FPDT stores stay in pinned host memory, and no instruction runs on the
+    host.  A zero store hoisted out of the layer loop would be copied to each
+    layer by a ``HostExecute`` call; one layer has no loop to hoist out of."""
+    from repro.configs import ModelConfig
+    from repro.core.parallel import ParallelContext
+    from repro.models import transformer as T
+    from repro.optim import adamw
+    from repro.runtime import train_loop
+    from repro.runtime.placement import PlacementPolicy
+
+    u, seq = 4, 8192
+    cfg = ModelConfig(name="dense-2l", family="dense", num_layers=2, d_model=512,
+                      num_heads=4, num_kv_heads=4, head_dim=128, d_ff=2048,
+                      vocab_size=1024, fpdt_chunks=u, mlp_chunks=2 * u,
+                      fpdt_offload=True, remat="full")
+    par = ParallelContext(mesh=None, attn_impl="pallas",
+                          placement=PlacementPolicy.probe(one_chip))
+    assert par.offload_active
+    oc = adamw.OptConfig()
+    params = jax.eval_shape(lambda k: T.init_params(cfg, k), jax.random.PRNGKey(0))
+    opt = jax.eval_shape(lambda p: adamw.init(oc, p), params)
+    params, opt = (jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), t)
+                   for t in (params, opt))
+    batch = {k: _sds(one_chip, (1, seq), jnp.int32) for k in ("tokens", "labels")}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(FK, "_default_interpret", lambda: False)
+        hlo = _hlo(train_loop.make_train_step(cfg, par, oc), params, opt, batch)
+    assert "HostExecute" not in hlo
+    assert 'async_execution_thread="host"' not in hlo
+    stores = [i for i in _instructions(hlo) if i[1] == "dynamic-update-slice-start"]
+    assert stores and all("S(5)" in i[3] for i in stores)
+
+
 def test_paged_serve_keeps_host_pool_off_device(one_chip):
     """The host-streamed paged serve programs at llama3.2-1b widths: the
     pool's K/V enter and leave in pinned host memory, and no program
