@@ -10,10 +10,14 @@ three compared numbers (``check.py``) of:
     of ``run.py`` compares them (no window);
   * ``control``: the reference computed in float8 (``reference.py``
     ``mode="fp8"``) in the program's place;
+  * ``no_exchange`` (cells on several chips, with each control seed): the
+    reference with its layers' partial outputs not summed over the chips
+    (``mode="no_exchange"``) in the program's place;
   * ``half_batch``: the program with half of each batch left out
     (``faults.py``).
-A state left unchanged reads 1 on ``update_gap`` by construction and needs
-no run.  Needs the cell's chips, like ``run.py``.
+Each is held against the float32 reference of its seed, computed once per
+seed.  A state left unchanged reads 1 on ``update_gap`` by construction and
+needs no run.  Needs the cell's chips, like ``run.py``.
 """
 import argparse
 import json
@@ -41,7 +45,7 @@ def main(argv=None) -> int:
 
     import jax
 
-    from perfbench import check, faults, harness, program, reference
+    from perfbench import check, faults, harness, program, reference, weights
 
     spec = harness.load_cell(ROOT, args.workload)
     model, traffic = spec["model"], spec["traffic"]
@@ -49,7 +53,13 @@ def main(argv=None) -> int:
     devices = harness._devices(spec["cell"]["chips"], require_tpu=True)
     harness.configure_jax(ROOT)
 
-    trainers = {}
+    trainers, refs = {}, {}
+
+    def fp32_ref(seed):
+        if seed not in refs:
+            refs[seed], _ = harness.reference_readings(model, traffic, seed, steps, devices)
+            gc.collect()
+        return refs[seed]
 
     def program_run(seed, fault=None):
         """The program's checked steps from the seed: the compiled step is
@@ -67,39 +77,43 @@ def main(argv=None) -> int:
             tr, harness.stepper(tr, traffic, model["vocab_size"], seed), steps)
         tr.params = tr.opt_state = None
         gc.collect()
-        ref, p0 = harness.reference_readings(model, traffic, seed, steps)
-        prog["update_norms"] = harness.update_norms(snap, p0, devices[0])
-        return prog, ref
+        ref = fp32_ref(seed)
+        p0 = weights.make(model, seed, out_shardings=reference.shardings(model, devices))
+        prog["update_norms"] = harness.update_norms(snap, p0)
+        return [("program" if fault is None else fault, prog, ref)]
+
+    def drop_references():
+        """Each reference's programs leave the device before the next loads."""
+        reference._make_step.cache_clear()
+        jax.clear_caches()
+        gc.collect()
 
     def control_run(seed):
-        # each reference's programs leave the device before the next loads
+        ref = fp32_ref(seed)
         out = []
-        for mode in ("fp8", "fp32"):
-            got, p0 = harness.reference_readings(model, traffic, seed, steps, mode=mode)
-            del p0
-            out.append(got)
-            reference._make_step.cache_clear()
-            jax.clear_caches()
-            gc.collect()
+        for mode in ["fp8"] + (["no_exchange"] if len(devices) > 1 else []):
+            drop_references()
+            got, _ = harness.reference_readings(model, traffic, seed, steps, devices, mode=mode)
+            out.append(("control" if mode == "fp8" else mode, got, ref))
+        drop_references()
         return out
 
     def fault_run(seed):
         return program_run(seed, fault="half_batch")
 
-    jobs = ([("program", s, program_run) for s in args.seeds]
-            + [("control", s, control_run) for s in args.control_seeds]
-            + [("half_batch", s, fault_run) for s in args.fault_seeds])
+    jobs = ([(s, program_run) for s in args.seeds]
+            + [(s, control_run) for s in args.control_seeds]
+            + [(s, fault_run) for s in args.fault_seeds])
     with open(args.out, "a") as out:
-        for kind, seed, fn in jobs:
+        for seed, fn in jobs:
             t = time.perf_counter()
-            got, ref = fn(seed)
-            nums = check.numbers(got, ref)
-            line = {"workload": args.workload, "kind": kind, "seed": seed,
-                    "seconds": time.perf_counter() - t, "losses": got["losses"],
-                    "ref_losses": ref["losses"], **nums}
-            print(json.dumps(line), flush=True)
-            out.write(json.dumps(line) + "\n")
-            out.flush()
+            for kind, got, ref in fn(seed):
+                line = {"workload": args.workload, "kind": kind, "seed": seed,
+                        "seconds": time.perf_counter() - t, "losses": got["losses"],
+                        "ref_losses": ref["losses"], **check.numbers(got, ref)}
+                print(json.dumps(line), flush=True)
+                out.write(json.dumps(line) + "\n")
+                out.flush()
     return 0
 
 

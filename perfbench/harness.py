@@ -149,23 +149,28 @@ def checked_steps(tr, one_step, steps: int):
     return prog, jax.device_get(tr.params)
 
 
-def reference_readings(model: dict, traffic: dict, seed: int, steps: int, mode: str = "fp32"):
-    """The reference's readings over the same steps, and the seed's weights."""
+def reference_readings(model: dict, traffic: dict, seed: int, steps: int, devices,
+                       mode: str = "fp32"):
+    """The reference's readings over the same steps on the cell's devices,
+    and the seed's weights."""
     from perfbench import reference, weights
 
     batches = [TR.batch(traffic, model["vocab_size"], seed, i) for i in range(steps)]
-    ref = reference.trajectory(model, batches, seed, mode)
-    p0 = weights.make(model, seed)
+    ref = reference.trajectory(model, batches, seed, mode, devices)
+    p0 = weights.make(model, seed, out_shardings=reference.shardings(model, devices))
     ref["update_norms"] = [float(x) for x in reference.change_norms(ref.pop("params"), p0)]
     return ref, p0
 
 
-def update_norms(params_host, p0, device) -> list:
+def update_norms(params_host, p0) -> list:
+    """Each leaf's change from ``p0`` of the program's parameters (on the host),
+    put where ``p0`` lies."""
     import jax
 
     from perfbench import reference
 
-    return [float(x) for x in reference.change_norms(jax.device_put(params_host, device), p0)]
+    where = jax.tree.map(lambda w: w.sharding, p0)
+    return [float(x) for x in reference.change_norms(jax.device_put(params_host, where), p0)]
 
 
 def run(root: str, workload: str, seed: int, seconds: float, trace: bool, t0: float,
@@ -250,8 +255,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool, t0: fl
     del tr
     gc.collect()
     t_r = time.perf_counter()
-    ref, p0 = reference_readings(model, traffic, seed, steps_checked)
-    prog["update_norms"] = update_norms(snapshot, p0, devices[0])
+    ref, p0 = reference_readings(model, traffic, seed, steps_checked, devices)
+    prog["update_norms"] = update_norms(snapshot, p0)
     names = reference.leaf_names(snapshot)
     del snapshot, p0
     nums = check.numbers(prog, ref)

@@ -7,6 +7,7 @@ import subprocess
 import sys
 import time
 
+import jax
 import pytest
 
 from perfbench import check, faults, harness
@@ -68,9 +69,10 @@ def test_broken_step_is_not_correct(root, fault):
 
 def test_control_is_not_correct(root):
     spec = harness.load_cell(root, "small-gpt.small")
-    steps = spec["limits"]["steps"]
-    ref, _ = harness.reference_readings(spec["model"], spec["traffic"], SEED, steps)
-    low, _ = harness.reference_readings(spec["model"], spec["traffic"], SEED, steps, mode="fp8")
+    steps, one = spec["limits"]["steps"], jax.devices()[:1]
+    ref, _ = harness.reference_readings(spec["model"], spec["traffic"], SEED, steps, one)
+    low, _ = harness.reference_readings(spec["model"], spec["traffic"], SEED, steps, one,
+                                        mode="fp8")
     ok, shown = check.judge(check.numbers(low, ref), spec["limits"])
     assert not ok, shown
 

@@ -68,10 +68,49 @@ def test_idle_and_exposed_host_transfer_shares():
     assert tracing.busy_s(r.trace) == pytest.approx((500 + 900) / 2 / 1e9)
 
 
+A2A = "%all-to-all.{} = bf16[8]{{0}} all-to-all(bf16[8] %a), replica_groups={{{{0,1,2,3}}}}"
+AG_START = "%all-gather-start.{} = (bf16[2]{{0}}, bf16[8]{{0}}) all-gather-start(bf16[2] %a)"
+AG_DONE = "%all-gather-done.{} = bf16[8]{{0}} all-gather-done(x)"
+
+
+def test_collective_kinds():
+    ops = [_op(A2A.format(1), 0, 1), _op(AG_START.format(1), 0, 1), _op(AG_DONE.format(1), 0, 1),
+           _op("%all-reduce-scatter-fusion.2 = f32[8]{0} fusion(f32[8] %a), kind=kOutput", 0, 1),
+           _op("%async-collective-done.3 = bf16[64,8]{1,0} fusion(bf16[16,8] %a)", 0, 1),
+           _op(FUSION.format(1), 0, 1), _op(KERNEL.format(1), 0, 1), _op(FETCH.format(1), 0, 1)]
+    assert [tracing.is_collective(o) for o in ops] == [True] * 5 + [False] * 3
+
+
+def test_collective_hidden_under_compute_reads_zero():
+    dev = [_op(FUSION.format(1), 0, 600), _op(AG_DONE.format(1), 600, 610),
+           _op(FUSION.format(2), 610, 1000)]
+    asyncs = {"/device:TPU:0": [_op(AG_START.format(1), 100, 600)]}
+    r = _reading({"/device:TPU:0": dev}, asyncs)
+    assert harness.reader("collective_exposed_pct")(r) == 0.0
+
+
+def test_exposed_all_to_all_reads_its_share():
+    # device 0: a synchronous all-to-all over (300, 450) with nothing else
+    # running; device 1: an all-gather in flight over (0, 300), compute over
+    # (0, 100): 200 exposed.  The worst device's share is read.
+    dev0 = [_op(FUSION.format(1), 0, 300), _op(A2A.format(1), 300, 450),
+            _op(FUSION.format(2), 450, 1000)]
+    dev1 = [_op(FUSION.format(1), 0, 100), _op(AG_DONE.format(1), 100, 300),
+            _op(FUSION.format(2), 300, 1000)]
+    asyncs = {"/device:TPU:0": [], "/device:TPU:1": [_op(AG_START.format(1), 0, 300)]}
+    r = _reading({"/device:TPU:0": dev0, "/device:TPU:1": dev1}, asyncs)
+    assert harness.reader("collective_exposed_pct")(r) == pytest.approx(100 * 200 / 1000)
+    dev1[1:2] = []
+    asyncs["/device:TPU:1"] = []
+    r = _reading({"/device:TPU:0": dev0, "/device:TPU:1": dev1}, asyncs)
+    assert harness.reader("collective_exposed_pct")(r) == pytest.approx(100 * 150 / 1000)
+
+
 def test_nothing_to_read():
     r = _reading({"/device:TPU:0": [_op(FUSION.format(1), 0, 900)]})
     assert harness.reader("host_copy_exposed_pct")(r) is None
     assert harness.reader("pallas_kernel_s_per_step")(r) is None
+    assert harness.reader("collective_exposed_pct")(r) is None
 
 
 def test_attention_events_are_matched_by_kernel_name():

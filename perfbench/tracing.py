@@ -12,9 +12,15 @@ each device plane holds two lines the benchmark reads:
 
 An op's ``opcode`` is read from its HLO text.  A compute op is a leaf
 instruction of ``XLA Ops`` that is neither control flow nor the start or
-done of an async op.  A host transfer is an async op with an operand or a
-result in pinned host memory (memory space ``S(5)`` in its HLO text); the
-host calls that XLA's host offloading runs for stores kept there count too.
+done of an async op.  A collective is an all-to-all, all-gather,
+reduce-scatter, all-reduce or collective-permute, read from the opcode
+without ``-start``/``-done``, or, where XLA wraps it in a generic
+``async-start`` or a fusion, from the instruction's name; on a TPU an
+asynchronous collective is a pair of fusions in ``XLA Ops``,
+``%async-collective-start.N`` and ``%async-collective-done.N``.  A host
+transfer is an async op with an operand or a result in pinned host memory
+(memory space ``S(5)`` in its HLO text); the host calls that XLA's host
+offloading runs for stores kept there count too.
 The benchmark's own host spans (``bench.*``, from
 ``jax.profiler.TraceAnnotation``) say what the host was doing; the one
 ``bench.window`` span is the measured window.  Intervals are (start_ns,
@@ -31,6 +37,8 @@ OPS_LINE, ASYNC_LINE = "XLA Ops", "Async XLA Ops"
 SPAN_PREFIX = "bench."
 CONTROL_FLOW = frozenset({"while", "conditional", "call"})
 HOST_MEMORY = "S(5)"
+COLLECTIVES = ("all-to-all", "all-gather", "reduce-scatter", "all-reduce", "collective-permute")
+_WRAPPED_COLLECTIVES = COLLECTIVES + ("async-collective",)
 _OPCODE = re.compile(r"[\]\}\)]\s([a-z][a-z0-9-]*)\(")
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
 
@@ -95,6 +103,12 @@ def from_profile(pd) -> Trace:
 
 def is_compute(o: Op) -> bool:
     return not (o.opcode in CONTROL_FLOW or o.opcode.endswith(("-start", "-done")))
+
+
+def is_collective(o: Op) -> bool:
+    base = re.sub(r"-(start|done)$", "", o.opcode)
+    return base in COLLECTIVES or (base in ("async", "fusion")
+                                   and o.name.lstrip("%").startswith(_WRAPPED_COLLECTIVES))
 
 
 def is_host_transfer(o: Op) -> bool:

@@ -64,14 +64,14 @@ def _leaf(rule, shape, key, dtype):
     return (x * scale).astype(dtype)
 
 
-def _is_spec(x) -> bool:
+def is_spec(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], str)
 
 
 def make_fn(model: dict):
     """key -> parameter tree in ``model['param_dtype']``; jit it (one call)."""
     spec = layout(model)
-    leaves, tree = jax.tree.flatten(spec, is_leaf=_is_spec)
+    leaves, tree = jax.tree.flatten(spec, is_leaf=is_spec)
     dtype = jnp.dtype(model["param_dtype"])
 
     def make(key):
