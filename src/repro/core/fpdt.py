@@ -23,8 +23,8 @@ One implementation covers both the paper's baseline and its contribution:
 
 Two compilation strategies for the u>1 pipeline:
 
-  * scan-compiled (default) — the forward is one ``lax.scan`` over query
-    chunks whose carry holds the KV store as preallocated
+  * scan-compiled (default) — the forward is one ``lax.scan`` over groups
+    of query chunks whose carry holds the KV store as preallocated
     ``[u, b, h, cq, dh]`` buffers (``dynamic_update_slice`` on append,
     ``dynamic_slice`` + placement-routed fetch on read); the inner KV loop
     and the Fig. 7 backward's nested loops are ``fori_loop``s with *traced*
@@ -43,6 +43,17 @@ Double buffering in the scan path carries the prefetched chunk in the loop
 state (``runtime.placement.fori_double_buffered``): the fetch of chunk j+1
 is issued before chunk j's kernels in program order, exactly like the
 generator-based schedule of the unrolled path.
+
+Query chunks go through the scan forward two at a time when u is even
+(``fwd_group``).  Chunks 2m and 2m+1 both attend KV chunks 0..2m-1, so the
+forward fetches each of those once and runs both query chunks' kernels on
+it, each on its own softmax state and only where its own pair is live; a
+chunk is fetched when either pair is (``kv_fetch_live``).  KV chunk 2m is
+taken from the device, where it was just projected.  Each query chunk
+still meets its KV chunks in the same order, so the result is the
+one-chunk schedule's, while the host link carries each KV chunk half as
+often: u(u-2)/4 fetches a layer in place of u(u-1)/2 (``fwd_kv_fetches``:
+56 in place of 120 at u=16).  An odd u keeps one query chunk per step.
 
 Backward is a custom VJP implementing the paper's Fig. 7 nested loop:
 outer loop over KV chunks j, inner loop over query chunks i >= j, using the
@@ -179,7 +190,8 @@ def sparsity_stride(sparsity: float) -> int:
 
 def pair_live(i: int, j: int, *, cq: int, window: int, sparsity: float) -> bool:
     """Is the (query chunk i, KV chunk j) pair attended?  Static indices
-    (unrolled path / tests); ``pair_live_traced`` is the loop twin."""
+    (unrolled path / tests); ``pair_live_traced`` is the loop twin.  The
+    answer depends on i - j alone."""
     if j > i:
         return False
     if window and (i - j) * cq >= window + cq - 1:
@@ -208,6 +220,32 @@ def pair_live_traced(i, j, *, cq: int, window: int, sparsity: float):
     if sparsity > 0.0:
         liv &= (j == i) | ((i - j - 1) % sparsity_stride(sparsity) == 0)
     return liv
+
+
+def fwd_group(u: int) -> int:
+    """Query chunks per step of the scan-compiled forward: 2 when u is even,
+    so each KV chunk fetched from the host serves two query chunks; else 1."""
+    return 2 if u % 2 == 0 else 1
+
+
+def kv_fetch_live(live, i0, j, g: int):
+    """Is KV chunk j fetched for the query group i0 .. i0+g-1?  Yes when any
+    of the group's pairs with it is live; ``live`` is ``pair_live`` or its
+    traced twin."""
+    out = live(i0, j)
+    for d in range(1, g):
+        out = out | live(i0 + d, j)
+    return out
+
+
+def fwd_kv_fetches(u: int, *, cq: int, window: int = 0, sparsity: float = 0.0) -> int:
+    """KV chunk fetches one forward pass of one layer issues on the scan
+    path: each group fetches the live chunks before its first query chunk
+    once, and takes its own chunks from the device (u=16 dense: 56, where
+    one query chunk per step fetches u(u-1)/2 = 120)."""
+    g = fwd_group(u)
+    live = functools.partial(pair_live, cq=cq, window=window, sparsity=sparsity)
+    return sum(bool(kv_fetch_live(live, i0, j, g)) for i0 in range(0, u, g) for j in range(i0))
 
 
 # ---------------------------------------------------------------------------
@@ -415,8 +453,8 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
 
     # ================= scan-compiled path (default for u > 1) ===============
     #
-    # One lax.scan over query chunks; the KV store is a pair of preallocated
-    # [u, b, h, cq, dh] carry buffers living in the offload pool
+    # One lax.scan over query chunk groups; the KV store is a pair of
+    # preallocated [u, b, h, cq, dh] carry buffers living in the offload pool
     # (placement-annotated after every append), appended with
     # dynamic_update_slice and read back chunk-by-chunk through the
     # double-buffered fori_loop.  HLO contains ONE copy of the chunk body.
@@ -435,9 +473,17 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         return to_dev(jax.lax.dynamic_index_in_dim(store, j, axis=0,
                                                    keepdims=False), spec)
 
+    # Query chunks run in groups of g (2 when u is even): each KV chunk
+    # fetched from the host serves every query chunk of the group.
+    g = fwd_group(u)
+    # under a window or sparsity a fetched chunk may be live for one query
+    # of the group only, so each query's kernel checks its own pair
+    gate = g > 1 and bool(window or sparsity > 0.0)
+
     def fwd_scan(x, p):
         b = x.shape[0]
-        xs = x.reshape(b, u, cq, -1).swapaxes(0, 1)  # [u, b, cq, d]
+        # [u, b, cq, d], the shape bwd_scan reads x in, so XLA makes x once
+        xs = x.reshape(b, u, cq, -1).swapaxes(0, 1)
         proj_dtype = jnp.result_type(x.dtype, p["wq"].dtype)
         # stores start in the offload pool so the scan carry's memory
         # placement agrees between loop entry and the to_host'd body outputs
@@ -451,35 +497,56 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             vst0 = to_host(kv0, kv_store_spec)
             qst0 = to_host(q0, q_store_spec)
 
-        def body(carry, inp):
+        def body(carry, m):
             kst, vst, qst = carry
-            i, xi = inp
-            qi, ki, vi = project(p, xi, i)
+            i0 = m * g  # the group's first query chunk
+            qkv = [project(p, jax.lax.dynamic_index_in_dim(xs, i0 + d, keepdims=False), i0 + d)
+                   for d in range(g)]
 
             def fetch_kv(j):
                 return _load(kst, j, kv_spec), _load(vst, j, kv_spec)
 
-            def pair(j, kv, st):
+            def pair(j, kv, sts):
                 kj, vj = kv
-                return tuple(chunk_fwd(qi, kj, vj, tuple(st), **pair_kwargs(i, j)))
+                out = []
+                for d, st in enumerate(sts):
+                    run = functools.partial(chunk_fwd, qkv[d][0], kj, vj,
+                                            **pair_kwargs(i0 + d, j))
+                    st = tuple(st)
+                    out.append(jax.lax.cond(live_tr(i0 + d, j), lambda st: tuple(run(st)),
+                                            lambda st: st, st) if gate else tuple(run(st)))
+                return tuple(out)
 
-            st = fori_double_buffered(
-                0, i, fetch_kv, pair, tuple(zero_state_like(qi)),
-                live=lambda j: live_tr(i, j))
-            st = SoftmaxState(*chunk_fwd(qi, ki, vi, st, **pair_kwargs(i, i)))
-            oi = finalize(st)  # [b, hq, cq, dh] fp32
-            Li = lse(st)
-            kst = _store(kst, ki, i, kv_spec)
-            vst = _store(vst, vi, i, kv_spec)
-            qst = _store(qst, qi, i, q_spec)
-            # back to token layout + seq sharding (inverse all-to-all)
-            ot = oi.astype(x.dtype).transpose(0, 2, 1, 3)  # [b, cq, hq, dh]
-            if par.mesh is not None and kind != "local":
-                ot = par.constrain(ot, par.dp_axes, par.sp_axis, None, None)
-            return (kst, vst, qst), (oi, Li, ot.reshape(b, cq, hq * dh))
+            # KV chunks before the group: each fetched once for all g queries
+            sts = fori_double_buffered(
+                0, i0, fetch_kv, pair, tuple(tuple(zero_state_like(q)) for q, _, _ in qkv),
+                live=lambda j: kv_fetch_live(live_tr, i0, j, g))
+            outs = []
+            for d, st in enumerate(sts):
+                qi = qkv[d][0]
+                # the group's own chunks, projected on the device: no fetch;
+                # liveness depends on i - j alone, so it is known here
+                for e in range(d + 1):
+                    if live_py(d, e):
+                        st = chunk_fwd(qi, qkv[e][1], qkv[e][2], st,
+                                       **pair_kwargs(i0 + d, i0 + e))
+                st = SoftmaxState(*st)
+                oi = finalize(st)  # [b, hq, cq, dh] fp32
+                Li = lse(st)
+                # back to token layout + seq sharding (inverse all-to-all)
+                ot = oi.astype(x.dtype).transpose(0, 2, 1, 3)  # [b, cq, hq, dh]
+                if par.mesh is not None and kind != "local":
+                    ot = par.constrain(ot, par.dp_axes, par.sp_axis, None, None)
+                outs.append((oi, Li, ot.reshape(b, cq, hq * dh)))
+            for d, (qi, ki, vi) in enumerate(qkv):
+                kst = _store(kst, ki, i0 + d, kv_spec)
+                vst = _store(vst, vi, i0 + d, kv_spec)
+                qst = _store(qst, qi, i0 + d, q_spec)
+            return (kst, vst, qst), jax.tree.map(lambda *a: jnp.stack(a), *outs)
 
-        (kst, vst, qst), (ost, Lst, ots) = jax.lax.scan(
-            body, (kst0, vst0, qst0), (jnp.arange(u), xs))
+        (kst, vst, qst), ys = jax.lax.scan(
+            body, (kst0, vst0, qst0), jnp.arange(u // g))
+        ost, Lst, ots = (y.reshape((u,) + y.shape[2:]) for y in ys)  # [u, ...]
         o = ots.swapaxes(0, 1).reshape(b, seq_len, hq * dh)
         if par.mesh is not None:
             o = par.seq_sharded(o)

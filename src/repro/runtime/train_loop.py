@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ModelConfig
+from repro.core import fpdt
 from repro.core.parallel import ParallelContext
 from repro.models import transformer as T
 from repro.optim import adamw
@@ -141,6 +142,16 @@ class TrainLoop:
             except ValueError:
                 pass  # not main thread
 
+    def _record_schedule(self, batch):
+        """Gauge ``fpdt_fwd_kv_fetches``: the KV chunk fetches one forward
+        pass of one attention layer issues on the scan-compiled FPDT path."""
+        cfg = self.cfg
+        u = cfg.fpdt_chunks
+        if u > 1 and cfg.scan_layers and not cfg.fpdt_unroll and "attn" in cfg.layer_kinds():
+            cq = batch["tokens"].shape[1] // u
+            self.telemetry.registry.gauge("fpdt_fwd_kv_fetches").set(
+                fpdt.fwd_kv_fetches(u, cq=cq, sparsity=cfg.attn_sparsity))
+
     def run(self, params, opt_state, start_step: int = 0, put_batch=None,
             on_step: Optional[Callable[[int, Any, Dict], None]] = None):
         """Train to ``tc.steps``; ``on_step(step, params, metrics)`` runs
@@ -150,7 +161,8 @@ class TrainLoop:
         ``train.feed``, ``train.dispatch`` and ``train.block`` spans, so a
         profiler trace puts the host's phases beside the device's ops.  A
         jitted ``step_fn`` that compiles a new program counts it through
-        ``Telemetry.compile_event("train_step")``."""
+        ``Telemetry.compile_event("train_step")``; the first batch sets the
+        gauge ``fpdt_fwd_kv_fetches`` (``_record_schedule``)."""
         self._install_signals()
         if put_batch is None:
             # default batch staging routes through the placement layer
@@ -165,6 +177,8 @@ class TrainLoop:
             with jax.profiler.StepTraceAnnotation("train", step_num=step + 1):
                 with jax.profiler.TraceAnnotation("train.feed"):
                     batch = put_batch(next(self.data))
+                if step == start_step:
+                    self._record_schedule(batch)
                 n_programs = programs()
                 with jax.profiler.TraceAnnotation("train.dispatch"):
                     params, opt_state, metrics = self.step_fn(params, opt_state, batch)[:3]

@@ -1,6 +1,8 @@
 """Differential test: the scan-compiled FPDT pipeline must reproduce the
 Python-unrolled oracle exactly — outputs and every grad — for u in {2, 4, 8},
-offload on/off, both kernel impls.
+offload on/off, both kernel impls, and for the grouped forward (two query
+chunks per fetched KV chunk at even u, one at odd u) under window and
+sparsity schedules.
 
 The two paths trace to different programs (one loop body vs u**2 unrolled
 pair calls), so XLA may fuse/reassociate differently; tolerances are set an
@@ -74,6 +76,33 @@ def test_scan_equals_unrolled_windowed(setup):
     cfg, p, x, do = setup
     o_s, g_s = _run(cfg, p, x, do, 4, True, "xla_flash", unroll=False, window=12)
     o_u, g_u = _run(cfg, p, x, do, 4, True, "xla_flash", unroll=True, window=12)
+    np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_u), **TIGHT)
+    _assert_trees_match(g_s, g_u, **TIGHT)
+
+
+@pytest.mark.parametrize("u,seq,window,sparsity,impl", [
+    (3, 48, 0, 0.0, "xla_flash"),   # odd u: one query chunk per step
+    (6, 48, 0, 0.0, "pallas"),      # even, not a power of two: pairs
+    (4, 32, 5, 0.0, "xla_flash"),   # window: chunk 3 skips KV chunk 1, chunk 2 needs it
+    (4, 32, 1, 0.0, "xla_flash"),   # window 1: the pair (2m+1, 2m) is skipped too
+    (8, 32, 9, 0.0, "xla_flash"),
+    (8, 32, 0, 0.5, "xla_flash"),   # sparsity stride 2: the pair's live KV chunks differ
+    (8, 32, 0, 0.67, "xla_flash"),  # sparsity stride 3
+])
+def test_grouped_forward_equals_unrolled(setup, u, seq, window, sparsity, impl):
+    """The scan forward takes query chunks two at a time when u is even (one
+    fetch of each KV chunk serves both), one at a time when u is odd; under
+    window and sparsity schedules each query runs only its own live pairs.
+    Outputs and every grad equal the unrolled oracle's."""
+    cfg, p, _, _ = setup
+    key = jax.random.PRNGKey(11)
+    x = jax.random.normal(key, (2, seq, cfg.d_model), jnp.float32)
+    do = jax.random.normal(jax.random.fold_in(key, 1), (2, seq, cfg.q_dim), jnp.float32)
+    if sparsity:
+        assert fpdt.sparsity_stride(sparsity) in (2, 3)
+    kw = dict(window=window, sparsity=sparsity)
+    o_s, g_s = _run(cfg, p, x, do, u, True, impl, unroll=False, **kw)
+    o_u, g_u = _run(cfg, p, x, do, u, True, impl, unroll=True, **kw)
     np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_u), **TIGHT)
     _assert_trees_match(g_s, g_u, **TIGHT)
 

@@ -13,7 +13,7 @@ import numpy as np
 
 from _hypothesis_compat import given, settings, strategies as st
 
-from repro.core.fpdt import pair_live, pair_live_traced, sparsity_stride
+from repro.core.fpdt import fwd_kv_fetches, pair_live, pair_live_traced, sparsity_stride
 
 
 def _dense_window_live(i, j, cq, window):
@@ -72,3 +72,30 @@ def test_dense_schedule_keeps_everything():
     for u, cq in [(1, 8), (4, 4), (8, 2)]:
         for i, j in itertools.product(range(u), repeat=2):
             assert pair_live(i, j, cq=cq, window=0, sparsity=0.0) == (j <= i)
+
+
+def _one_chunk_fetches(u, cq, window, sparsity):
+    """Fetches of a forward that takes one query chunk per step: every live
+    off-diagonal pair fetches its KV chunk."""
+    return sum(pair_live(i, j, cq=cq, window=window, sparsity=sparsity)
+               for i in range(u) for j in range(i))
+
+
+def test_fwd_kv_fetches_dense():
+    """u=16 (the 64k cell): 56 fetches a layer, against 120 for one query
+    chunk per step; any even u fetches u(u-2)/4, an odd u u(u-1)/2."""
+    assert fwd_kv_fetches(16, cq=4096) == 56
+    assert _one_chunk_fetches(16, 4096, 0, 0.0) == 120
+    for u in (2, 4, 6, 8, 32):
+        assert fwd_kv_fetches(u, cq=8) == u * (u - 2) // 4
+    for u in (1, 3, 5, 7, 9, 15):
+        assert fwd_kv_fetches(u, cq=8) == u * (u - 1) // 2 == _one_chunk_fetches(u, 8, 0, 0.0)
+
+
+def test_grouped_fetches_never_exceed_one_chunk():
+    """Under every window and sparsity the tests use, the grouped forward
+    fetches no more KV chunks than one query chunk per step does."""
+    for u, cq, window, sparsity in itertools.product(
+            range(2, 17), (4, 8), (0, 1, 5, 9, 12), (0.0, 0.5, 0.67)):
+        assert fwd_kv_fetches(u, cq=cq, window=window, sparsity=sparsity) <= \
+            _one_chunk_fetches(u, cq, window, sparsity), (u, cq, window, sparsity)

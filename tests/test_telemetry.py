@@ -456,3 +456,31 @@ def test_train_loop_counts_compiles_and_profiles_steps(tmp_path):
     for s, t, _, _ in steps:
         inside = [e[2] for e in events if s <= e[0] and e[1] <= t and e[2] != "train"]
         assert inside == ["train.feed", "train.dispatch", "train.block"]
+
+
+def test_train_loop_gauges_fpdt_forward_fetches():
+    """A u=4 FPDT train run sets ``fpdt_fwd_kv_fetches`` to the helper's
+    count when it sees its first batch: the grouped forward fetches KV
+    chunks 0 and 1 once, for query chunks 2 and 3."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import ShapeConfig, get_config, reduced
+    from repro.core import fpdt
+    from repro.data.pipeline import CheckpointableIterator, make_batch_fn
+    from repro.models import transformer as T
+    from repro.optim import adamw
+    from repro.runtime.train_loop import TrainConfig, TrainLoop, make_train_step
+
+    cfg = dataclasses.replace(reduced(get_config("llama3.2-1b")), num_layers=1,
+                              fpdt_chunks=4, fpdt_offload=True)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    oc = adamw.OptConfig(lr=1e-3, warmup_steps=2, total_steps=10)
+    tc = TrainConfig(steps=1, log_every=100)
+    loop = TrainLoop(cfg, None, oc, tc, jax.jit(make_train_step(cfg, None, oc, tc)),
+                     CheckpointableIterator(make_batch_fn(cfg, ShapeConfig("t", 32, 2, "train"))))
+    assert loop.telemetry.registry.value("fpdt_fwd_kv_fetches", None) is None
+    loop.run(params, adamw.init(oc, params),
+             put_batch=lambda b: {k: jnp.asarray(v) for k, v in b.items()})
+    assert loop.telemetry.registry.value("fpdt_fwd_kv_fetches") == \
+        fpdt.fwd_kv_fetches(4, cq=8) == 2
