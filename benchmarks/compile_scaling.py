@@ -1,12 +1,12 @@
 """Compile-time / program-size scaling of the FPDT chunk pipeline in u.
 
 The paper's 2M-token setting needs large chunk counts (u=32/u=64 at 64K
-tokens per chunk).  The original Python-unrolled Fig. 7 backward emitted
-O(u^2) chunk-pair kernels, so jaxpr/HLO size — and with it trace, lower,
-and compile time — grew quadratically, capping practical u at toy scale.
+tokens per chunk).  A Python-unrolled Fig. 7 backward emits O(u^2)
+chunk-pair kernels, so jaxpr/HLO size — and with it trace, lower, and
+compile time — grows quadratically, capping practical u at toy scale.
 The scan-compiled pipeline traces the chunk body once; this benchmark
-measures both paths over a u sweep at fixed chunk length (so sequence
-length grows with u, as in the paper's scaling runs) and reports:
+measures it over a u sweep at fixed chunk length (so sequence length grows
+with u, as in the paper's scaling runs) and reports:
 
   * traced jaxpr equation count (recursive, incl. scan/cond/while bodies)
   * StableHLO op count of the lowered module
@@ -14,7 +14,7 @@ length grows with u, as in the paper's scaling runs) and reports:
 
 Emits name,value rows for benchmarks.run plus a JSON blob; the slow tier-1
 regression test (tests/test_compile_scaling.py) asserts the scan path's
-near-O(1) growth so unrolling never silently regresses.
+near-O(1) growth so an unrolled schedule never silently comes back.
 
 Usage: python benchmarks/compile_scaling.py [--json out.json]
 """
@@ -67,7 +67,7 @@ def count_hlo_ops(lowered) -> int:
     return count_ops(lowered.as_text())
 
 
-def build(u: int, unroll: bool):
+def build(u: int):
     from repro.configs import get_config, reduced
     from repro.core import fpdt
     from repro.core.parallel import ParallelContext
@@ -75,7 +75,7 @@ def build(u: int, unroll: bool):
 
     cfg = dataclasses.replace(
         reduced(get_config("llama3.2-1b")), param_dtype="float32",
-        fpdt_chunks=u, fpdt_offload=True, fpdt_unroll=unroll,
+        fpdt_chunks=u, fpdt_offload=True,
         block_q=CQ, block_k=CQ)
     par = ParallelContext(mesh=None, attn_impl="xla_flash")
     S = u * CQ
@@ -91,8 +91,8 @@ def build(u: int, unroll: bool):
     return jax.value_and_grad(f, argnums=(0, 1)), (x, p)
 
 
-def measure(u: int, unroll: bool) -> dict:
-    f, args = build(u, unroll)
+def measure(u: int) -> dict:
+    f, args = build(u)
     t0 = time.perf_counter()
     jaxpr = jax.make_jaxpr(f)(*args)
     trace_s = time.perf_counter() - t0
@@ -100,23 +100,18 @@ def measure(u: int, unroll: bool) -> dict:
     lowered = jax.jit(f).lower(*args)
     lower_s = time.perf_counter() - t0
     return {
-        "u": u, "path": "unrolled" if unroll else "scan", "seq_len": u * CQ,
+        "u": u, "seq_len": u * CQ,
         "jaxpr_eqns": count_eqns(jaxpr),
         "hlo_ops": count_hlo_ops(lowered),
         "trace_s": round(trace_s, 3), "lower_s": round(lower_s, 3),
     }
 
 
-def sweep(scan_us=(2, 4, 8, 16, 32, 64), unrolled_us=(2, 4, 8, 16)) -> List[dict]:
+def sweep(us=(2, 4, 8, 16, 32, 64)) -> List[dict]:
     recs = []
-    for u in scan_us:
-        recs.append(measure(u, unroll=False))
-        print("{path:>8} u={u:<3d} S={seq_len:<5d} jaxpr_eqns={jaxpr_eqns:<6d} "
-              "hlo_ops={hlo_ops:<6d} trace={trace_s}s lower={lower_s}s"
-              .format(**recs[-1]))
-    for u in unrolled_us:
-        recs.append(measure(u, unroll=True))
-        print("{path:>8} u={u:<3d} S={seq_len:<5d} jaxpr_eqns={jaxpr_eqns:<6d} "
+    for u in us:
+        recs.append(measure(u))
+        print("u={u:<3d} S={seq_len:<5d} jaxpr_eqns={jaxpr_eqns:<6d} "
               "hlo_ops={hlo_ops:<6d} trace={trace_s}s lower={lower_s}s"
               .format(**recs[-1]))
     return recs
@@ -124,14 +119,9 @@ def sweep(scan_us=(2, 4, 8, 16, 32, 64), unrolled_us=(2, 4, 8, 16)) -> List[dict
 
 def run() -> List[str]:
     """benchmarks.run entry: summarized growth factors."""
-    recs = sweep(scan_us=(4, 32), unrolled_us=(4, 8))
-    by = {(r["path"], r["u"]): r for r in recs}
-    rows = ["bench,name,value,derived"]
-    g = by[("scan", 32)]["hlo_ops"] / by[("scan", 4)]["hlo_ops"]
-    rows.append(f"bench,fpdt_scan_hlo_growth_u4_to_u32,{g:.3f},x")
-    g = by[("unrolled", 8)]["hlo_ops"] / by[("unrolled", 4)]["hlo_ops"]
-    rows.append(f"bench,fpdt_unrolled_hlo_growth_u4_to_u8,{g:.3f},x")
-    return rows
+    by = {r["u"]: r for r in sweep(us=(4, 32))}
+    g = by[32]["hlo_ops"] / by[4]["hlo_ops"]
+    return ["bench,name,value,derived", f"bench,fpdt_scan_hlo_growth_u4_to_u32,{g:.3f},x"]
 
 
 def main():
@@ -142,7 +132,7 @@ def main():
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
     recs = sweep()
-    scan = {r["u"]: r for r in recs if r["path"] == "scan"}
+    scan = {r["u"]: r for r in recs}
     print(f"\nscan-path growth u=4 -> u=32: "
           f"jaxpr x{scan[32]['jaxpr_eqns'] / scan[4]['jaxpr_eqns']:.2f}, "
           f"hlo x{scan[32]['hlo_ops'] / scan[4]['hlo_ops']:.2f}")

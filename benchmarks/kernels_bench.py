@@ -46,8 +46,7 @@ def run() -> List[str]:
         us = _time(f, a, x)
         rows.append(f"bench,linear_scan_{impl}_512,{us:.0f},{2*512*64*2/(us*1e-6)/1e6:.1f}Melem/s")
 
-    # FPDT chunk pipeline fwd+bwd: scan-compiled loops vs the unrolled
-    # oracle (same math — the delta is loop overhead vs program size)
+    # FPDT chunk pipeline fwd+bwd (scan-compiled loops)
     import dataclasses
 
     from repro.configs import get_config, reduced
@@ -61,12 +60,8 @@ def run() -> List[str]:
     u, S = 8, 128
     xh = jnp.asarray(rng.standard_normal((1, S, cfg0.d_model)), jnp.float32)
     par = ParallelContext(mesh=None, attn_impl="xla_flash")
-    for unroll in (False, True):
-        cfgu = dataclasses.replace(cfg0, fpdt_chunks=u, fpdt_offload=True,
-                                   fpdt_unroll=unroll)
-        f = jax.jit(jax.grad(
-            lambda x, c=cfgu: FP.fpdt_attention(c, par, p, x, kind="local").sum()))
-        us = _time(f, xh)
-        name = "unrolled" if unroll else "scan"
-        rows.append(f"bench,fpdt_grad_u{u}_{name},{us:.0f},S{S}")
+    cfgu = dataclasses.replace(cfg0, fpdt_chunks=u, fpdt_offload=True)
+    f = jax.jit(jax.grad(lambda x: FP.fpdt_attention(cfgu, par, p, x, kind="local").sum()))
+    us = _time(f, xh)
+    rows.append(f"bench,fpdt_grad_u{u}_scan,{us:.0f},S{S}")
     return rows

@@ -54,6 +54,9 @@ def _probe_cfg(cfg, n_cycles: int):
         scan_layers=False,
         loss_chunks=1,       # no loss scan -> loss counted exactly
         mlp_chunks=1,        # no FFN-chunk scan in probes
+        # cost analysis counts a chunk loop's body once; FPDT's attention
+        # math and all-to-all bytes are u = 1's, whose program has no loop
+        fpdt_chunks=1, fpdt_offload=False,
     )
 
 

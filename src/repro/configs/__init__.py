@@ -61,9 +61,6 @@ class ModelConfig:
     attn_impl: str = "auto"  # auto | ulysses | cp | none
     fpdt_chunks: int = 1  # u; 1 = un-chunked (plain Ulysses/CP baseline)
     fpdt_offload: bool = False  # offload idle KV chunks to pinned_host
-    # True: legacy Python-unrolled chunk loops (O(u^2) HLO; kept for
-    # differential testing against the scan-compiled pipeline)
-    fpdt_unroll: bool = False
     mlp_chunks: int = 1  # paper: 2x attention chunks
     loss_chunks: int = 0  # 0 = auto: ceil(vocab/d_model) * 2 (paper 5.4)
     remat: str = "full"  # none | full | offload (AC. / OC. in Table 3)

@@ -1,11 +1,12 @@
 """FPDT: the paper's sequence-chunk pipelined distributed attention.
 
-One implementation covers both the paper's baseline and its contribution:
+The chunk count u alone picks one of two builders:
 
-  * ``u = 1``     -> plain DeepSpeed-Ulysses attention (project, all-to-all,
-                     flash attention, all-to-all back).  This *is* the
-                     paper's baseline, and the u>1 path must match it
-                     bit-for-bit in expectation (tested).
+  * ``u = 1``     -> the paper's baseline, plain DeepSpeed-Ulysses (or CP)
+                     attention: project, all-to-all, one flash-attention
+                     call, all-to-all back; its custom VJP runs the one
+                     pair's backward kernels.  No chunk store, no offload,
+                     no loop.
   * ``u > 1``     -> the FPDT pipeline: the hidden chunk T_i is projected to
                      (q_i, k_i, v_i), per-chunk all-to-all scatters heads and
                      gathers sequence (GSPMD reshard induced by a sharding
@@ -21,28 +22,19 @@ One implementation covers both the paper's baseline and its contribution:
                      accelerator without one refuses it), and the pipeline
                      matches u=1 either way.
 
-Two compilation strategies for the u>1 pipeline:
-
-  * scan-compiled (default) — the forward is one ``lax.scan`` over groups
-    of query chunks whose carry holds the KV store as preallocated
-    ``[u, b, h, cq, dh]`` buffers (``dynamic_update_slice`` on append,
-    ``dynamic_slice`` + placement-routed fetch on read); the inner KV loop
-    and the Fig. 7 backward's nested loops are ``fori_loop``s with *traced*
-    chunk offsets (the flash kernels take offsets as scalar-prefetch
-    operands), and ``pair_live`` is a traced predicate gating each pair
-    with ``lax.cond`` — window/sparsity chunk skipping skips compute *and*
-    host traffic inside the compiled loop.  HLO size is O(1) in u, so
-    u=32/u=64 schedules (the path to the paper's 2M-token setting) trace
-    and compile in near-constant time (see benchmarks/compile_scaling.py).
-  * unrolled (``cfg.fpdt_unroll=True``) — the original Python-unrolled
-    O(u^2) double loop.  Kept as a differential-testing oracle
-    (tests/test_fpdt_scan.py) and for roofline probes that want per-pair
-    HLO costs; impractical beyond toy u (quadratic HLO growth).
-
-Double buffering in the scan path carries the prefetched chunk in the loop
-state (``runtime.placement.fori_double_buffered``): the fetch of chunk j+1
-is issued before chunk j's kernels in program order, exactly like the
-generator-based schedule of the unrolled path.
+The u > 1 pipeline is compiled as loops: the forward is one ``lax.scan``
+over groups of query chunks whose carry holds the KV store as preallocated
+``[u, b, h, cq, dh]`` buffers (``dynamic_update_slice`` on append,
+``dynamic_slice`` + placement-routed fetch on read); the inner KV loop and
+the Fig. 7 backward's nested loops are ``fori_loop``s with *traced* chunk
+offsets (the flash kernels take offsets as scalar-prefetch operands), and
+``pair_live`` is a traced predicate gating each pair with ``lax.cond`` —
+window/sparsity chunk skipping skips compute *and* host traffic inside the
+compiled loop.  HLO size is O(1) in u, so u=32/u=64 schedules (the path to
+the paper's 2M-token setting) trace and compile in near-constant time (see
+benchmarks/compile_scaling.py).  Double buffering carries the prefetched
+chunk in the loop state (``runtime.placement.fori_double_buffered``): the
+fetch of chunk j+1 is issued before chunk j's kernels in program order.
 
 Query chunks go through the scan forward two at a time when u is even
 (``fwd_group``).  Chunks 2m and 2m+1 both attend KV chunks 0..2m-1, so the
@@ -63,8 +55,9 @@ iterations and finalizes when j == i, at which point it is all-to-all'd back
 (a sharding constraint) and back-projected — overlapping with the next KV
 chunk's fetch, exactly the paper's schedule.
 
-Two distribution modes (DESIGN.md §3):
-  * kind="ulysses" — heads % sp == 0 (GQA KV replicated to the SP degree);
+Distribution modes:
+  * kind="ulysses" — heads % sp == 0 (KV heads fewer than sp stay
+                     replicated over the model axis);
   * kind="cp"      — all-gather context parallelism for archs whose head
                      count doesn't divide the model axis; with u > 1 this
                      becomes chunk-streamed KV all-gather + offload
@@ -84,7 +77,7 @@ from repro.core.online_softmax import SoftmaxState, finalize, lse, zero_state_li
 from repro.core.parallel import ParallelContext
 from repro.kernels.flash_attention import ops as fa
 from repro.models.layers import apply_rope, qkv_proj
-from repro.runtime.placement import double_buffered, fori_double_buffered
+from repro.runtime.placement import fori_double_buffered
 
 Params = Dict[str, Any]
 
@@ -120,14 +113,6 @@ def _shard_kv(par: ParallelContext, kind: str, x: jnp.ndarray) -> jnp.ndarray:
     # (a clean all-gather; never materialize a repeat + reshard, which GSPMD
     # can only realize by full rematerialization)
     return par.replicated_kv(x)
-
-
-def _kv_rep(cfg: ModelConfig, par: ParallelContext, kind: str) -> int:
-    """KV head replication for Ulysses.  Disabled (returns 1) since v2 of the
-    sharding scheme: kv_heads < sp now keeps KV replicated over the model
-    axis instead of repeating heads (see _shard_kv) — GSPMD turned the
-    repeat+reshard into a full rematerialization (measured §Perf A1)."""
-    return 1
 
 
 def _host_spec_kv(par: ParallelContext, kind: str, n_heads: int, chunk_len: int):
@@ -190,8 +175,8 @@ def sparsity_stride(sparsity: float) -> int:
 
 def pair_live(i: int, j: int, *, cq: int, window: int, sparsity: float) -> bool:
     """Is the (query chunk i, KV chunk j) pair attended?  Static indices
-    (unrolled path / tests); ``pair_live_traced`` is the loop twin.  The
-    answer depends on i - j alone."""
+    (a forward group's own pairs, schedules, tests); ``pair_live_traced``
+    is the loop twin.  The answer depends on i - j alone."""
     if j > i:
         return False
     if window and (i - j) * cq >= window + cq - 1:
@@ -256,22 +241,19 @@ def fwd_kv_fetches(u: int, *, cq: int, window: int = 0, sparsity: float = 0.0) -
 @functools.lru_cache(maxsize=None)
 def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
                u: int, offload: bool, seq_len: int, pos_offset: int):
+    """Returns f(x, p) -> o  with x [b, S, d], o [b, S, hq*dh] (seq-sharded):
+    the plain baseline at u = 1, the chunk pipeline at u > 1."""
     sparsity = cfg.attn_sparsity
-    """Returns f(x, p) -> o  with x [b, S, d], o [b, S, hq*dh] (seq-sharded)."""
     hq, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    rep = _kv_rep(cfg, par, kind)
     impl = par.attn_impl
-    chunk_fwd, chunk_bwd_dq, chunk_bwd_dkv = _chunk_kernels(par, kind, impl, hkv * rep)
+    chunk_fwd, chunk_bwd_dq, chunk_bwd_dkv = _chunk_kernels(par, kind, impl, hkv)
     bq, bk = cfg.block_q, cfg.block_k
     assert seq_len % u == 0, (seq_len, u)
     cq = seq_len // u
-    # u=1 has no chunk loop to compile — the unrolled builder IS the plain
-    # Ulysses/CP baseline there, so the scan machinery only engages for u>1.
-    unroll = cfg.fpdt_unroll or u == 1
     # Offload *requested*: capability degradation (no pinned-host pool ->
     # identity + one logged warning) happens inside the placement policy.
-    do_offload = offload and par.offload_to_host and u > 1
-    kv_spec = _host_spec_kv(par, kind, hkv * rep, seq_len // u)
+    do_offload = offload and par.offload_to_host
+    kv_spec = _host_spec_kv(par, kind, hkv, seq_len // u)
     q_spec = _host_spec_kv(par, kind, hq, seq_len // u)
 
     @jax.named_scope("fpdt.project")
@@ -281,9 +263,6 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         pos = jnp.asarray(i, jnp.int32) * cq + pos_offset + jnp.arange(cq)
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
         q = _shard_q(par, kind, q)
         k = _shard_kv(par, kind, k)
         v = _shard_kv(par, kind, v)
@@ -313,101 +292,22 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         return dict(causal=True, window=window, q_offset=i * cq, k_offset=j * cq,
                     block_q=bq, block_k=bk, impl=impl)
 
-    # ================= unrolled path (fpdt_unroll / u == 1) =================
-
-    def fwd_unrolled(x, p):
-        b = x.shape[0]
-        kv_store = []  # (k_j, v_j) in head layout, offloaded when idle
-        outs, Ls, res_q, res_o = [], [], [], []
-        for i in range(u):
-            xi = jax.lax.slice_in_dim(x, i * cq, (i + 1) * cq, axis=1)
-            qi, ki, vi = project(p, xi, i)
-            carry = None
-            # Explicit double buffering (Fig. 6): the fetch of KV chunk j+1
-            # is issued before the chunk-j kernel, so the host->device copy
-            # overlaps compute by program order, not XLA scheduling luck.
-            live = [j for j in range(i) if live_py(i, j)]
-
-            @jax.named_scope("fpdt.fetch")
-            def fetch_kv(j):
-                kj, vj = kv_store[j]
-                return to_dev(kj), to_dev(vj)
-
-            for j, (kj, vj) in zip(live, double_buffered(live, fetch_kv)):
-                carry = chunk_fwd(qi, kj, vj, carry, **pair_kwargs(i, j))
-            carry = chunk_fwd(qi, ki, vi, carry, **pair_kwargs(i, i))
-            st = SoftmaxState(*carry)
-            oi = finalize(st)  # [b, h, cq, dh] fp32
-            Li = lse(st)
-            with jax.named_scope("fpdt.store"):
-                kv_store.append((to_host(ki), to_host(vi)))
-                res_q.append(to_host(qi, q_spec))
-            res_o.append(oi)
-            Ls.append(Li)
-            # back to token layout + seq sharding (inverse all-to-all)
-            ot = oi.astype(x.dtype).transpose(0, 2, 1, 3)  # [b, cq, hq, dh]
-            if par.mesh is not None and kind != "local":
-                ot = par.constrain(ot, par.dp_axes, par.sp_axis, None, None)
-            outs.append(ot.reshape(b, cq, hq * dh))
-        o = jnp.concatenate(outs, axis=1)
-        if par.mesh is not None:
-            o = par.seq_sharded(o)
-        return o, (x, p, kv_store, res_q, res_o, Ls)
-
-    def bwd_unrolled(res, do):
-        x, p, kv_store, res_q, res_o, Ls = res
-        b = x.shape[0]
-        # head-layout do + delta per chunk
-        dos, deltas = [], []
-        for i in range(u):
-            doi = jax.lax.slice_in_dim(do, i * cq, (i + 1) * cq, axis=1)
-            doi = doi.reshape(b, cq, hq, dh)
-            doi = _shard_q(par, kind, doi).transpose(0, 2, 1, 3).astype(jnp.float32)
-            dos.append(doi)
-            deltas.append(jnp.sum(doi * res_o[i], axis=-1))  # [b, h, cq]
-
-        dqs: list = [None] * u
-        dks: list = [None] * u
-        dvs: list = [None] * u
-
-        # Fig. 7 schedule with explicit double buffering on both loops: the
-        # next KV chunk's fetch is issued before this chunk's inner loop,
-        # and the next query chunk's fetch before the current (i, j) pair's
-        # kernels — each copy overlaps the preceding chunk's compute.
-        @jax.named_scope("fpdt.fetch")
-        def fetch_kv(j):
-            kj, vj = kv_store[j]
-            return to_dev(kj), to_dev(vj)
-
-        @jax.named_scope("fpdt.fetch")
-        def fetch_q(i):
-            return to_dev(res_q[i], q_spec)
-
-        for j, (kj, vj) in zip(range(u), double_buffered(range(u), fetch_kv)):
-            inner = [i for i in range(j, u) if live_py(i, j)]
-            for i, qi in zip(inner, double_buffered(inner, fetch_q)):
-                kwargs = pair_kwargs(i, j)
-                dk_c, dv_c = chunk_bwd_dkv(qi, kj, vj, dos[i], Ls[i], deltas[i], **kwargs)
-                dq_c = chunk_bwd_dq(qi, kj, vj, dos[i], Ls[i], deltas[i], **kwargs)
-                dks[j] = dk_c if dks[j] is None else dks[j] + dk_c
-                dvs[j] = dv_c if dvs[j] is None else dvs[j] + dv_c
-                dqs[i] = dq_c if dqs[i] is None else dqs[i] + dq_c
-
-        # per-chunk: a2a back, un-rope, un-project; accumulate dW.  A chunk
-        # with no live pairs at all (reachable only under schedules that
-        # drop the diagonal) contributes exact-zero grads — note dq's zero
-        # has hq heads, NOT the kv-head count zkv carries.
-        zkv = jnp.zeros((b, hkv * rep, cq, dh), jnp.float32)
-        zq = jnp.zeros((b, hq, cq, dh), jnp.float32)
-        dq_stack = jnp.stack([dq if dq is not None else zq for dq in dqs])
-        dk_stack = jnp.stack([dk if dk is not None else zkv for dk in dks])
-        dv_stack = jnp.stack([dv if dv is not None else zkv for dv in dvs])
-        return _unproject_unrolled(x, p, dq_stack, dk_stack, dv_stack)
+    def finish(st, dtype, b):
+        """A query chunk's final softmax state -> (o [b, hq, cq, dh] fp32,
+        row LSE, o back in token layout [b, cq, hq*dh] and seq-sharded)."""
+        st = SoftmaxState(*st)
+        oi = finalize(st)  # [b, hq, cq, dh] fp32
+        Li = lse(st)
+        # back to token layout + seq sharding (inverse all-to-all)
+        ot = oi.astype(dtype).transpose(0, 2, 1, 3)  # [b, cq, hq, dh]
+        if par.mesh is not None and kind != "local":
+            ot = par.constrain(ot, par.dp_axes, par.sp_axis, None, None)
+        return oi, Li, ot.reshape(b, cq, hq * dh)
 
     @jax.named_scope("fpdt.unproject")
     def _unproject_body(p, i, xi, dq, dk, dv, b):
-        """Shared per-chunk grad epilogue: a2a back, un-rope, un-project.
-        Returns (dx_i, dW contributions).  i may be traced (scan path)."""
+        """Per-chunk grad epilogue: a2a back, un-rope, un-project.
+        Returns (dx_i, dW contributions).  i may be traced."""
         dq = dq.astype(xi.dtype).transpose(0, 2, 1, 3)  # [b, cq, h, dh]
         dk = dk.astype(xi.dtype).transpose(0, 2, 1, 3)
         dv = dv.astype(xi.dtype).transpose(0, 2, 1, 3)
@@ -415,9 +315,6 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             dq = par.constrain(dq, par.dp_axes, par.sp_axis, None, None)
             dk = par.constrain(dk, par.dp_axes, par.sp_axis, None, None)
             dv = par.constrain(dv, par.dp_axes, par.sp_axis, None, None)
-        if rep > 1:  # sum grads of replicated KV heads
-            dk = dk.reshape(b, cq, hkv, rep, dh).sum(3)
-            dv = dv.reshape(b, cq, hkv, rep, dh).sum(3)
         dq = unrope_back(dq, i)
         dk = unrope_back(dk, i)
         dqf = dq.reshape(b, cq, hq * dh)
@@ -436,22 +333,31 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     def _cast_like(dp, p):
         return {n: g.astype(p[n].dtype) for n, g in dp.items()}
 
-    def _unproject_unrolled(x, p, dq_stack, dk_stack, dv_stack):
+    # ================= u = 1: the plain Ulysses/CP baseline =================
+
+    def fwd_plain(x, p):
+        q, k, v = project(p, x, 0)
+        o, L, ot = finish(chunk_fwd(q, k, v, None, **pair_kwargs(0, 0)), x.dtype, x.shape[0])
+        if par.mesh is not None:
+            ot = par.seq_sharded(ot)
+        return ot, (x, p, q, k, v, o, L)
+
+    def bwd_plain(res, do):
+        x, p, q, k, v, o, L = res
         b = x.shape[0]
-        dx_chunks, dp = [], None
-        for i in range(u):
-            xi = jax.lax.slice_in_dim(x, i * cq, (i + 1) * cq, axis=1)
-            dx, contrib = _unproject_body(p, i, xi, dq_stack[i], dk_stack[i],
-                                          dv_stack[i], b)
-            dx_chunks.append(dx)
-            dp = contrib if dp is None else jax.tree.map(jnp.add, dp, contrib)
-        dx = jnp.concatenate(dx_chunks, axis=1)
+        do = _shard_q(par, kind, do.reshape(b, cq, hq, dh))
+        do = do.transpose(0, 2, 1, 3).astype(jnp.float32)  # [b, hq, cq, dh]
+        delta = jnp.sum(do * o, axis=-1)
+        kwargs = pair_kwargs(0, 0)
+        dk, dv = chunk_bwd_dkv(q, k, v, do, L, delta, **kwargs)
+        dq = chunk_bwd_dq(q, k, v, do, L, delta, **kwargs)
+        dx, dp = _unproject_body(p, 0, x, dq, dk, dv, b)
         if par.mesh is not None:
             dx = par.seq_sharded(dx)
         # wo is not part of this custom_vjp (out_proj applied by caller)
         return dx, _cast_like(dp, p)
 
-    # ================= scan-compiled path (default for u > 1) ===============
+    # ================= u > 1: the scan-compiled chunk pipeline ==============
     #
     # One lax.scan over query chunk groups; the KV store is a pair of
     # preallocated [u, b, h, cq, dh] carry buffers living in the offload pool
@@ -488,7 +394,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
         # stores start in the offload pool so the scan carry's memory
         # placement agrees between loop entry and the to_host'd body outputs
         with jax.named_scope("fpdt.store"):
-            kv0 = jnp.zeros((u, b, hkv * rep, cq, dh), proj_dtype)
+            kv0 = jnp.zeros((u, b, hkv, cq, dh), proj_dtype)
             q0 = jnp.zeros((u, b, hq, cq, dh), proj_dtype)
             # tied to this layer's x: a pinned-host zero hoisted out of the
             # layer loop is copied to each layer by a host-executed call
@@ -530,14 +436,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
                     if live_py(d, e):
                         st = chunk_fwd(qi, qkv[e][1], qkv[e][2], st,
                                        **pair_kwargs(i0 + d, i0 + e))
-                st = SoftmaxState(*st)
-                oi = finalize(st)  # [b, hq, cq, dh] fp32
-                Li = lse(st)
-                # back to token layout + seq sharding (inverse all-to-all)
-                ot = oi.astype(x.dtype).transpose(0, 2, 1, 3)  # [b, cq, hq, dh]
-                if par.mesh is not None and kind != "local":
-                    ot = par.constrain(ot, par.dp_axes, par.sp_axis, None, None)
-                outs.append((oi, Li, ot.reshape(b, cq, hq * dh)))
+                outs.append(finish(st, x.dtype, b))
             for d, (qi, ki, vi) in enumerate(qkv):
                 kst = _store(kst, ki, i0 + d, kv_spec)
                 vst = _store(vst, vi, i0 + d, kv_spec)
@@ -586,7 +485,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
                 dq_acc = jax.lax.dynamic_update_index_in_dim(dq_acc, dq_i + dq_c, i, axis=0)
                 return dk + dk_c, dv + dv_c, dq_acc
 
-            z = jnp.zeros((b, hkv * rep, cq, dh), jnp.float32)
+            z = jnp.zeros((b, hkv, cq, dh), jnp.float32)
             dk, dv, dq_acc = fori_double_buffered(
                 j, u, fetch_q, pair, (z, z, dq_acc),
                 live=lambda i: live_tr(i, j))
@@ -612,7 +511,7 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             dx = par.seq_sharded(dx)
         return dx, _cast_like(dp, p)
 
-    fwd, bwd = (fwd_unrolled, bwd_unrolled) if unroll else (fwd_scan, bwd_scan)
+    fwd, bwd = (fwd_plain, bwd_plain) if u == 1 else (fwd_scan, bwd_scan)
 
     @jax.custom_vjp
     def f(x, p):
@@ -639,8 +538,8 @@ def fpdt_attention(
 
     x: [b, S, d] (seq-sharded).  Returns [b, S, hq*dh] (seq-sharded),
     ready for the output projection.  u = cfg.fpdt_chunks (1 = Ulysses/CP
-    baseline); offload per cfg.fpdt_offload; scan-compiled chunk loops
-    unless cfg.fpdt_unroll.
+    baseline, > 1 = the scan-compiled chunk pipeline); offload per
+    cfg.fpdt_offload.
     """
     par = par if par is not None else ParallelContext(mesh=None)
     attn_p = {k_: p[k_] for k_ in ("wq", "wk", "wv", "bq", "bk", "bv") if k_ in p}

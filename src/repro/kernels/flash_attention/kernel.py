@@ -1,6 +1,6 @@
 """Pallas TPU flash-attention kernels with FPDT chunk-carry support.
 
-Design (TPU-native, see DESIGN.md §2):
+Design (TPU-native):
   * Layout [b, h, s, d]; grid (b, h, num_q_blocks, num_k_blocks) with the
     k-block dimension innermost and sequential ("arbitrary"), carrying the
     online-softmax state (m, l, acc) in fp32 VMEM scratch.
@@ -76,7 +76,7 @@ def _default_interpret() -> bool:
 def _offsets_operand(q_offset, k_offset) -> jnp.ndarray:
     """[q_offset, k_offset] as the int32 scalar-prefetch operand.
 
-    Accepts Python ints (unrolled FPDT: offsets are trace-time constants)
+    Accepts Python ints (the u = 1 baseline: offsets are trace-time constants)
     and traced int scalars (scan-compiled FPDT: offsets are loop carries).
     """
     return jnp.stack([jnp.asarray(q_offset, jnp.int32),

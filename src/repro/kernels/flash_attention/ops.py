@@ -14,7 +14,7 @@ All expose the chunk-level primitives FPDT schedules:
   chunk_bwd_dkv  per-pair (dk, dv) contribution
 plus ``flash_attention`` — a fused single-call attention with custom VJP.
 
-``q_offset``/``k_offset`` may be Python ints (unrolled FPDT) or *traced*
+``q_offset``/``k_offset`` may be Python ints (the u = 1 baseline) or *traced*
 int scalars (the scan-compiled pipeline passes loop-carried chunk offsets):
 the xla/ref paths consume them as ordinary values and the Pallas kernels
 take them as a scalar-prefetch operand.  Shapes and block sizes stay

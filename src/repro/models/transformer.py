@@ -8,11 +8,10 @@ single cycle body regardless of depth (compile-time and HLO size stay flat
 at 512 devices).  Remainder layers (38 % 3 == 2) run unrolled after the scan.
 Inside each attention block the FPDT chunk pipeline is scan-compiled the
 same way (core/fpdt.py), so HLO size is flat in the chunk count u as well;
-``scan_layers=False`` (roofline probes) unrolls both.
+``scan_layers=False`` (roofline probes) unrolls the layer stack.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -129,12 +128,7 @@ def block_apply(cfg: ModelConfig, par: Optional[ParallelContext], kind: str,
     if kind in ("attn", "local_attn"):
         window = cfg.window if kind == "local_attn" else 0
         hn = L.apply_norm(cfg, p["norm1"], h)
-        # Roofline probes unroll the layer stack so HLO costs scale with the
-        # true layer count; the scan-compiled FPDT chunk loops hide per-pair
-        # costs the same way, so probe mode unrolls the chunk pipeline too
-        # (identical numerics — differentially tested in test_fpdt_scan.py).
-        acfg = cfg if cfg.scan_layers else dataclasses.replace(cfg, fpdt_unroll=True)
-        o = fpdt.fpdt_attention(acfg, par, p["attn"], hn,
+        o = fpdt.fpdt_attention(cfg, par, p["attn"], hn,
                                 kind=attn_kind(cfg, par), window=window,
                                 pos_offset=pos_offset)
         h = h + o @ p["attn"]["wo"]

@@ -33,7 +33,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-from typing import Any, Callable, Iterable, Iterator, Optional, Sequence, TypeVar
+from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -195,41 +195,18 @@ def default_policy(offload_enabled: bool = True) -> PlacementPolicy:
 # explicit double buffering
 # ---------------------------------------------------------------------------
 
-T = TypeVar("T")
-U = TypeVar("U")
-
-
-def double_buffered(items: Iterable[T], fetch: Callable[[T], U]) -> Iterator[U]:
-    """Two-deep prefetch pipeline over ``items`` (FPDT Fig. 6).
-
-    Yields ``fetch(item_k)`` with the guarantee that ``fetch(item_{k+1})``
-    has already been *issued* before the consumer runs compute on item k:
-    the fetch (a ``device_put`` copy-start on offload-capable backends)
-    precedes the chunk kernel in program order, so the host->device copy
-    overlaps chunk compute explicitly instead of relying on XLA to discover
-    the independence.
-    """
-    seq = list(items)
-    if not seq:
-        return
-    ahead = fetch(seq[0])
-    for k in range(len(seq)):
-        cur = ahead
-        ahead = fetch(seq[k + 1]) if k + 1 < len(seq) else None
-        yield cur
-
 
 def fori_double_buffered(lo, hi, fetch: Callable, body: Callable, init,
                          *, live: Optional[Callable] = None):
-    """Scan-carry variant of ``double_buffered`` for traced chunk loops.
+    """Two-deep prefetch pipeline over a traced chunk loop (FPDT Fig. 6).
 
     Runs ``carry = body(idx, fetch(idx), carry)`` for ``idx`` in ``[lo, hi)``
-    — ``lo``/``hi`` may be traced (lowers to a while loop) — with the same
-    Fig. 6 guarantee as the generator version: the fetched value consumed at
-    iteration ``idx`` is carried in the loop state and the *next* consumed
-    chunk's fetch is issued *before* ``body(idx)``'s kernels in program
-    order, so on offload-capable backends the host->device copy of the next
-    chunk overlaps the current chunk's compute.
+    — ``lo``/``hi`` may be traced (lowers to a while loop).  The fetched
+    value consumed at iteration ``idx`` is carried in the loop state and the
+    *next* consumed chunk's fetch is issued *before* ``body(idx)``'s kernels
+    in program order, so on offload-capable backends the host->device copy
+    of the next chunk overlaps the current chunk's compute explicitly
+    instead of relying on XLA to discover the independence.
 
     Carry contract (everything rides a ``fori_loop`` state, so all of it
     must be shape/dtype-stable across iterations):
@@ -247,8 +224,7 @@ def fori_double_buffered(lo, hi, fetch: Callable, body: Callable, init,
     ``live(idx) -> bool tracer`` optionally restricts the schedule to live
     indices: dead (window/sparsity-skipped) iterations are complete no-ops
     — no fetch, no body — and each live iteration prefetches the next
-    *live* index (a traced search, mirroring the unrolled path's
-    ``double_buffered(live_items, fetch)`` over the filtered item list), so
+    *live* index (a traced search), so
     sparse schedules keep the copy/compute overlap instead of issuing
     fetches from skipped iterations that overlap nothing.
     """

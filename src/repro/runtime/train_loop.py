@@ -147,7 +147,7 @@ class TrainLoop:
         pass of one attention layer issues on the scan-compiled FPDT path."""
         cfg = self.cfg
         u = cfg.fpdt_chunks
-        if u > 1 and cfg.scan_layers and not cfg.fpdt_unroll and "attn" in cfg.layer_kinds():
+        if u > 1 and "attn" in cfg.layer_kinds():
             cq = batch["tokens"].shape[1] // u
             self.telemetry.registry.gauge("fpdt_fwd_kv_fetches").set(
                 fpdt.fwd_kv_fetches(u, cq=cq, sparsity=cfg.attn_sparsity))

@@ -1,5 +1,5 @@
 """Compile-scaling regression: the scan-compiled FPDT pipeline's program
-size must stay ~flat in the chunk count u, so nobody silently reintroduces
+size must stay ~flat in the chunk count u, so nobody silently introduces
 an unrolled (O(u^2)) chunk schedule on the path to the paper's 2M-token
 configs.  Measured: traced jaxpr equations and lowered StableHLO op count
 at u=32 vs u=4 (value_and_grad, so the Fig. 7 backward is included).
@@ -16,17 +16,7 @@ import compile_scaling as CS
 
 @pytest.mark.slow
 def test_scan_path_near_constant_in_u():
-    r4 = CS.measure(4, unroll=False)
-    r32 = CS.measure(32, unroll=False)
+    r4 = CS.measure(4)
+    r32 = CS.measure(32)
     assert r32["jaxpr_eqns"] <= 2 * r4["jaxpr_eqns"], (r4, r32)
     assert r32["hlo_ops"] <= 2 * r4["hlo_ops"], (r4, r32)
-
-
-@pytest.mark.slow
-def test_unrolled_path_grows_superlinearly():
-    """Sanity that the counters actually see program size: the legacy
-    unrolled path at 2x the chunks must emit >2x the equations (it is the
-    quadratic oracle the scan path is measured against)."""
-    r4 = CS.measure(4, unroll=True)
-    r8 = CS.measure(8, unroll=True)
-    assert r8["jaxpr_eqns"] > 2 * r4["jaxpr_eqns"], (r4, r8)

@@ -1,16 +1,18 @@
-"""Differential test: the scan-compiled FPDT pipeline must reproduce the
-Python-unrolled oracle exactly — outputs and every grad — for u in {2, 4, 8},
-offload on/off, both kernel impls, and for the grouped forward (two query
-chunks per fetched KV chunk at even u, one at odd u) under window and
-sparsity schedules.
+"""Differential test: the scan-compiled FPDT pipeline and the u = 1 plain
+baseline must reproduce an independent dense reference — materialised fp32
+attention under the exact causal, window and ``pair_live`` token mask —
+outputs and every grad, for u in {1, 2, 4, 8}, offload on/off, both kernel
+impls, and for the grouped forward (two query chunks per fetched KV chunk at
+even u, one at odd u) under window and sparsity schedules.
 
-The two paths trace to different programs (one loop body vs u**2 unrolled
-pair calls), so XLA may fuse/reassociate differently; tolerances are set an
-order of magnitude tighter than the fp32 pipeline's baseline tolerance
-(5e-4) to catch any *algorithmic* divergence while allowing fusion-level
-last-ulp noise.  Also covers the sparse schedule: grads of chunk pairs
-skipped by pair_live must match a dense-mask reference (zero off-schedule
-dk/dv contributions, finite dq everywhere).
+The reference shares no code with the pipeline beyond the projection and
+rope of ``models.layers``, and runs under ``default_matmul_precision
+("highest")`` with it; the tolerance is set an order of magnitude tighter
+than the fp32 pipeline's baseline tolerance (5e-4) to catch any
+*algorithmic* divergence while allowing last-ulp noise.  Also covers the
+sparse schedule: grads of chunk pairs skipped by pair_live must match the
+dense-mask reference (zero off-schedule dk/dv contributions, finite dq
+everywhere).
 """
 import dataclasses
 
@@ -38,9 +40,9 @@ def setup():
     return cfg, p, x, do
 
 
-def _run(cfg, p, x, do, u, offload, impl, *, unroll, window=0, sparsity=0.0):
+def _run(cfg, p, x, do, u, offload, impl, *, window=0, sparsity=0.0):
     c = dataclasses.replace(cfg, fpdt_chunks=u, fpdt_offload=offload, block_q=8,
-                            block_k=8, fpdt_unroll=unroll, attn_sparsity=sparsity)
+                            block_k=8, attn_sparsity=sparsity)
     par = ParallelContext(mesh=None, attn_impl=impl)
 
     def f(x, p):
@@ -59,25 +61,34 @@ def _assert_trees_match(g, gu, **tol):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), **tol)
 
 
+def _assert_matches_dense(cfg, p, x, do, u, offload, impl, *, window=0, sparsity=0.0):
+    with jax.default_matmul_precision("highest"):
+        o, g = _run(cfg, p, x, do, u, offload, impl, window=window, sparsity=sparsity)
+        o_ref, g_ref = _dense_sparse_reference(cfg, p, x, do, u, window, sparsity)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), **TIGHT)
+    _assert_trees_match(g, g_ref, **TIGHT)
+
+
 @pytest.mark.parametrize("u,offload,impl", [
     (2, False, "pallas"), (2, True, "xla_flash"),
     (4, True, "pallas"), (4, False, "xla_flash"),
     (8, True, "xla_flash"), (8, False, "xla_flash"),
 ])
-def test_scan_equals_unrolled(setup, u, offload, impl):
+def test_scan_equals_dense(setup, u, offload, impl):
     cfg, p, x, do = setup
-    o_s, g_s = _run(cfg, p, x, do, u, offload, impl, unroll=False)
-    o_u, g_u = _run(cfg, p, x, do, u, offload, impl, unroll=True)
-    np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_u), **TIGHT)
-    _assert_trees_match(g_s, g_u, **TIGHT)
+    _assert_matches_dense(cfg, p, x, do, u, offload, impl)
 
 
-def test_scan_equals_unrolled_windowed(setup):
+def test_scan_equals_dense_windowed(setup):
     cfg, p, x, do = setup
-    o_s, g_s = _run(cfg, p, x, do, 4, True, "xla_flash", unroll=False, window=12)
-    o_u, g_u = _run(cfg, p, x, do, 4, True, "xla_flash", unroll=True, window=12)
-    np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_u), **TIGHT)
-    _assert_trees_match(g_s, g_u, **TIGHT)
+    _assert_matches_dense(cfg, p, x, do, 4, True, "xla_flash", window=12)
+
+
+@pytest.mark.parametrize("impl,window", [("pallas", 0), ("xla_flash", 0), ("xla_flash", 12)])
+def test_baseline_equals_dense(setup, impl, window):
+    """u = 1: the plain baseline's one diagonal pair, forward and backward."""
+    cfg, p, x, do = setup
+    _assert_matches_dense(cfg, p, x, do, 1, False, impl, window=window)
 
 
 @pytest.mark.parametrize("u,seq,window,sparsity,impl", [
@@ -89,29 +100,25 @@ def test_scan_equals_unrolled_windowed(setup):
     (8, 32, 0, 0.5, "xla_flash"),   # sparsity stride 2: the pair's live KV chunks differ
     (8, 32, 0, 0.67, "xla_flash"),  # sparsity stride 3
 ])
-def test_grouped_forward_equals_unrolled(setup, u, seq, window, sparsity, impl):
+def test_grouped_forward_equals_dense(setup, u, seq, window, sparsity, impl):
     """The scan forward takes query chunks two at a time when u is even (one
     fetch of each KV chunk serves both), one at a time when u is odd; under
     window and sparsity schedules each query runs only its own live pairs.
-    Outputs and every grad equal the unrolled oracle's."""
+    Outputs and every grad equal the dense reference's."""
     cfg, p, _, _ = setup
     key = jax.random.PRNGKey(11)
     x = jax.random.normal(key, (2, seq, cfg.d_model), jnp.float32)
     do = jax.random.normal(jax.random.fold_in(key, 1), (2, seq, cfg.q_dim), jnp.float32)
     if sparsity:
         assert fpdt.sparsity_stride(sparsity) in (2, 3)
-    kw = dict(window=window, sparsity=sparsity)
-    o_s, g_s = _run(cfg, p, x, do, u, True, impl, unroll=False, **kw)
-    o_u, g_u = _run(cfg, p, x, do, u, True, impl, unroll=True, **kw)
-    np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_u), **TIGHT)
-    _assert_trees_match(g_s, g_u, **TIGHT)
+    _assert_matches_dense(cfg, p, x, do, u, True, impl, window=window, sparsity=sparsity)
 
 
 def test_scan_equals_baseline(setup):
     """Transitivity anchor: scan path vs the u=1 un-chunked baseline."""
     cfg, p, x, do = setup
-    o1, g1 = _run(cfg, p, x, do, 1, False, "xla_flash", unroll=False)
-    o, g = _run(cfg, p, x, do, 4, True, "xla_flash", unroll=False)
+    o1, g1 = _run(cfg, p, x, do, 1, False, "xla_flash")
+    o, g = _run(cfg, p, x, do, 4, True, "xla_flash")
     np.testing.assert_allclose(np.asarray(o), np.asarray(o1), rtol=2e-4, atol=2e-4)
     _assert_trees_match(g, g1, rtol=5e-4, atol=5e-4)
 
@@ -162,8 +169,7 @@ def _dense_sparse_reference(cfg, p, x, do, u, window, sparsity):
     return o, grads
 
 
-@pytest.mark.parametrize("unroll", [False, True])
-def test_sparse_skipped_chunks_grads(setup, unroll):
+def test_sparse_skipped_chunks_grads(setup):
     """attn_sparsity=0.5, u=8: pair_live skips diagonal-adjacent-but-one
     chunks (j = i-2, i-4, ...).  Outputs AND grads must match the dense
     masked-attention oracle — in particular dk/dv receive exactly zero from
@@ -174,8 +180,7 @@ def test_sparse_skipped_chunks_grads(setup, unroll):
     # the schedule really skips pairs (otherwise this test is vacuous)
     assert not fpdt.pair_live(4, 2, cq=cq, window=0, sparsity=sparsity)
     assert fpdt.pair_live(4, 3, cq=cq, window=0, sparsity=sparsity)
-    o, g = _run(cfg, p, x, do, u, True, "xla_flash", unroll=unroll,
-                sparsity=sparsity)
+    o, g = _run(cfg, p, x, do, u, True, "xla_flash", sparsity=sparsity)
     for leaf in jax.tree.leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()
     o_ref, g_ref = _dense_sparse_reference(cfg, p, x, do, u, 0, sparsity)

@@ -12,11 +12,7 @@ from repro.configs import get_config, reduced
 from repro.core import fpdt
 from repro.core.parallel import ParallelContext
 from repro.models import layers as L
-from repro.runtime.placement import (
-    PlacementPolicy,
-    default_policy,
-    double_buffered,
-)
+from repro.runtime.placement import PlacementPolicy, default_policy
 
 
 # ---------------------------------------------------------------------------
@@ -126,28 +122,6 @@ def test_parallel_context_routes_through_policy():
     # offload disabled at the context level short-circuits entirely
     par_off = ParallelContext(mesh=None, offload_to_host=False)
     assert par_off.to_host(x) is x
-
-
-# ---------------------------------------------------------------------------
-# explicit double buffering
-# ---------------------------------------------------------------------------
-
-
-def test_double_buffered_prefetches_one_ahead():
-    events = []
-
-    def fetch(k):
-        events.append(("fetch", k))
-        return k
-
-    for k in double_buffered(range(4), fetch):
-        events.append(("compute", k))
-    # fetch of k+1 must be issued before compute of k
-    assert events == [
-        ("fetch", 0), ("fetch", 1), ("compute", 0), ("fetch", 2),
-        ("compute", 1), ("fetch", 3), ("compute", 2), ("compute", 3),
-    ]
-    assert list(double_buffered([], fetch)) == []
 
 
 # ---------------------------------------------------------------------------
