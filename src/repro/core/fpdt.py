@@ -14,9 +14,9 @@ The chunk count u alone picks one of two builders:
                      across KV chunks j <= i, and idle KV chunks are
                      offloaded to host memory and fetched back chunk-by-chunk
                      with *explicit* double buffering: the fetch of chunk
-                     j+1 is issued before the chunk-j kernel, so the async
-                     copy-start/copy-done pair overlaps chunk compute by
-                     program order.  All residency decisions route through
+                     j+1 is issued before chunk j's kernels, and on a dense
+                     schedule it is in flight while they run (below).
+                     All residency decisions route through
                      ``runtime.placement.PlacementPolicy`` (a CPU without a
                      pinned-host pool degrades offload to a no-op; an
                      accelerator without one refuses it), and the pipeline
@@ -27,14 +27,28 @@ over groups of query chunks whose carry holds the KV store as preallocated
 ``[u, b, h, cq, dh]`` buffers (``dynamic_update_slice`` on append,
 ``dynamic_slice`` + placement-routed fetch on read); the inner KV loop and
 the Fig. 7 backward's nested loops are ``fori_loop``s with *traced* chunk
-offsets (the flash kernels take offsets as scalar-prefetch operands), and
-``pair_live`` is a traced predicate gating each pair with ``lax.cond`` —
-window/sparsity chunk skipping skips compute *and* host traffic inside the
-compiled loop.  HLO size is O(1) in u, so u=32/u=64 schedules (the path to
-the paper's 2M-token setting) trace and compile in near-constant time (see
-benchmarks/compile_scaling.py).  Double buffering carries the prefetched
-chunk in the loop state (``runtime.placement.fori_double_buffered``): the
-fetch of chunk j+1 is issued before chunk j's kernels in program order.
+offsets (the flash kernels take offsets as scalar-prefetch operands).
+Under a window or sparsity, ``pair_live`` is a traced predicate gating each
+pair with ``lax.cond`` — chunk skipping skips compute *and* host traffic
+inside the compiled loop.  HLO size is O(1) in u, so u=32/u=64 schedules
+(the path to the paper's 2M-token setting) trace and compile in
+near-constant time (see benchmarks/compile_scaling.py).  Double buffering
+carries the prefetched chunk in the loop state
+(``runtime.placement.fori_double_buffered``): the fetch of chunk j+1 is
+issued before chunk j's kernels in program order.
+
+The overlapped path.  On a dense schedule (no window, no sparsity) every
+pair the loops visit is live, so no ``lax.cond`` gates a pair or a fetch,
+and each loop fetches its chunks exactly once, the last without a prefetch
+after it (the loop runs under one ``lax.cond`` that skips an empty range:
+the forward's first query group has no KV chunk before it).  XLA's TPU
+scheduler overlaps a host fetch in flight with a loop but not with Mosaic
+calls beside it in the same computation, so on one device each chunk
+pair's Pallas kernels run as a loop over T = 2 groups of heads
+(``head_groups``): the kernels read their head window in place and the
+group's outputs are written into the running state.  The fetch of the next
+chunk then starts before that loop and is waited on after it.  Each loop's
+first fetch stays exposed (``overlapped_fetches`` counts the others).
 
 Query chunks go through the scan forward two at a time when u is even
 (``fwd_group``).  Chunks 2m and 2m+1 both attend KV chunks 0..2m-1, so the
@@ -123,6 +137,15 @@ def _host_spec_kv(par: ParallelContext, kind: str, n_heads: int, chunk_len: int)
     if par.sp and chunk_len % par.sp == 0:
         return (par.dp_axes, None, par.sp_axis, None)
     return (par.dp_axes, None, None, None)
+
+
+def head_groups(par: ParallelContext, kind: str, n_kv_heads: int) -> int:
+    """T: on a dense schedule each chunk pair runs its kernels as a loop over
+    T groups of heads, each kernel reading its head window in place.  T = 2
+    where one program runs the Pallas kernels on every head (no mesh) and
+    the KV heads split in two, else 1 (no loop)."""
+    single = par.mesh is None or kind == "local"
+    return 2 if par.attn_impl == "pallas" and single and n_kv_heads % 2 == 0 else 1
 
 
 def _chunk_kernels(par: ParallelContext, kind: str, impl: str, n_kv_heads: int):
@@ -233,6 +256,22 @@ def fwd_kv_fetches(u: int, *, cq: int, window: int = 0, sparsity: float = 0.0) -
     return sum(bool(kv_fetch_live(live, i0, j, g)) for i0 in range(0, u, g) for j in range(i0))
 
 
+def overlapped_fetches(u: int, *, cq: int, head_groups: int, offload: bool, window: int = 0,
+                       sparsity: float = 0.0) -> Tuple[int, int]:
+    """(forward KV chunk fetches, Fig. 7 q chunk fetches) one layer issues on
+    the overlapped path, each from the host while a chunk pair's kernels
+    run: on a dense offloaded schedule whose kernels loop over
+    ``head_groups`` > 1 (``head_groups()``), every fetch but each loop's
+    first (u=16: 49 of the forward's 56 a pass, 120 of Fig. 7's 136).
+    Otherwise (0, 0): windowed and sparse schedules fetch under
+    ``lax.cond``, one head group leaves no loop to overlap, and without
+    offload nothing is fetched from the host."""
+    if window or sparsity > 0.0 or u == 1 or head_groups == 1 or not offload:
+        return 0, 0
+    loops = len(range(fwd_group(u), u, fwd_group(u)))  # groups with KV chunks before them
+    return fwd_kv_fetches(u, cq=cq) - loops, u * (u - 1) // 2
+
+
 # ---------------------------------------------------------------------------
 # the chunk pipeline (forward + Fig.7 backward), cached per static config
 # ---------------------------------------------------------------------------
@@ -247,6 +286,10 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     hq, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     impl = par.attn_impl
     chunk_fwd, chunk_bwd_dq, chunk_bwd_dkv = _chunk_kernels(par, kind, impl, hkv)
+    # every pair the loops visit is live: no lax.cond, and each chunk pair's
+    # kernels run as a loop over T head groups (module docstring)
+    dense = not window and sparsity == 0.0
+    T = head_groups(par, kind, hkv) if dense else 1
     bq, bk = cfg.block_q, cfg.block_k
     assert seq_len % u == 0, (seq_len, u)
     cq = seq_len // u
@@ -333,6 +376,26 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     def _cast_like(dp, p):
         return {n: g.astype(p[n].dtype) for n, g in dp.items()}
 
+    def head_loop(run, carry):
+        """``run(t, carry)`` over the head groups, as a loop of its own: XLA's
+        TPU scheduler overlaps a host fetch in flight with a loop, and not
+        with Mosaic calls beside it."""
+        return jax.lax.fori_loop(0, T, run, carry) if T > 1 else run(0, carry)
+
+    def group(t):
+        """Kernel keywords of head group t: its window of the q heads."""
+        return {} if T == 1 else {"heads": (t * (hq // T), hq // T)}
+
+    def put_group(x, t, v, *, at=(), add=False):
+        """``x`` with head group ``t`` of ``x[at]`` set to ``v``, or ``v``
+        added; heads follow the batch axis."""
+        n, h = len(at), x.shape[len(at) + 1]
+        start = tuple(at) + (0, t * (h // T)) + (0,) * (x.ndim - n - 2)
+        v = v.reshape((1,) * n + v.shape)
+        if add:
+            v = jax.lax.dynamic_slice(x, start, v.shape) + v
+        return jax.lax.dynamic_update_slice(x, v, start)
+
     # ================= u = 1: the plain Ulysses/CP baseline =================
 
     def fwd_plain(x, p):
@@ -382,9 +445,6 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
     # Query chunks run in groups of g (2 when u is even): each KV chunk
     # fetched from the host serves every query chunk of the group.
     g = fwd_group(u)
-    # under a window or sparsity a fetched chunk may be live for one query
-    # of the group only, so each query's kernel checks its own pair
-    gate = g > 1 and bool(window or sparsity > 0.0)
 
     def fwd_scan(x, p):
         b = x.shape[0]
@@ -414,19 +474,24 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
 
             def pair(j, kv, sts):
                 kj, vj = kv
-                out = []
-                for d, st in enumerate(sts):
-                    run = functools.partial(chunk_fwd, qkv[d][0], kj, vj,
-                                            **pair_kwargs(i0 + d, j))
-                    st = tuple(st)
-                    out.append(jax.lax.cond(live_tr(i0 + d, j), lambda st: tuple(run(st)),
-                                            lambda st: st, st) if gate else tuple(run(st)))
-                return tuple(out)
+
+                def run(t, sts):
+                    out = []
+                    for d, st in enumerate(sts):
+                        def attend(st, d=d):
+                            return tuple(put_group(a, t, n) for a, n in zip(st, chunk_fwd(
+                                qkv[d][0], kj, vj, st, **group(t), **pair_kwargs(i0 + d, j))))
+                        # under a window or sparsity a fetched chunk may be
+                        # live for one query of the group only
+                        out.append(attend(st) if dense or g == 1 else jax.lax.cond(
+                            live_tr(i0 + d, j), attend, lambda st: st, tuple(st)))
+                    return tuple(out)
+                return head_loop(run, sts)
 
             # KV chunks before the group: each fetched once for all g queries
             sts = fori_double_buffered(
                 0, i0, fetch_kv, pair, tuple(tuple(zero_state_like(q)) for q, _, _ in qkv),
-                live=lambda j: kv_fetch_live(live_tr, i0, j, g))
+                live=None if dense else lambda j: kv_fetch_live(live_tr, i0, j, g))
             outs = []
             for d, st in enumerate(sts):
                 qi = qkv[d][0]
@@ -474,21 +539,22 @@ def _make_fpdt(cfg: ModelConfig, par: ParallelContext, kind: str, window: int,
             knext, vnext = fetch_kv(jnp.minimum(j + 1, u - 1))  # Fig. 6 prefetch
 
             def pair(i, qi, st):
-                dk, dv, dq_acc = st
-                doi = jax.lax.dynamic_index_in_dim(dos, i, axis=0, keepdims=False)
-                Li = jax.lax.dynamic_index_in_dim(Lst, i, axis=0, keepdims=False)
-                di = jax.lax.dynamic_index_in_dim(deltas, i, axis=0, keepdims=False)
-                kwargs = pair_kwargs(i, j)
-                dk_c, dv_c = chunk_bwd_dkv(qi, kj, vj, doi, Li, di, **kwargs)
-                dq_c = chunk_bwd_dq(qi, kj, vj, doi, Li, di, **kwargs)
-                dq_i = jax.lax.dynamic_index_in_dim(dq_acc, i, axis=0, keepdims=False)
-                dq_acc = jax.lax.dynamic_update_index_in_dim(dq_acc, dq_i + dq_c, i, axis=0)
-                return dk + dk_c, dv + dv_c, dq_acc
+                doi, Li, di = (jax.lax.dynamic_index_in_dim(a, i, axis=0, keepdims=False)
+                               for a in (dos, Lst, deltas))
+                args, kwargs = (qi, kj, vj, doi, Li, di), pair_kwargs(i, j)
+
+                def run(t, st):
+                    dk, dv, dq_acc = st
+                    dk_c, dv_c = chunk_bwd_dkv(*args, **group(t), **kwargs)
+                    dq_c = chunk_bwd_dq(*args, **group(t), **kwargs)
+                    return (put_group(dk, t, dk_c, add=True), put_group(dv, t, dv_c, add=True),
+                            put_group(dq_acc, t, dq_c, at=(i,), add=True))
+                return head_loop(run, st)
 
             z = jnp.zeros((b, hkv, cq, dh), jnp.float32)
             dk, dv, dq_acc = fori_double_buffered(
                 j, u, fetch_q, pair, (z, z, dq_acc),
-                live=lambda i: live_tr(i, j))
+                live=None if dense else lambda i: live_tr(i, j))
             return (dq_acc, knext, vnext), (dk, dv)
 
         dq0 = jnp.zeros((u, b, hq, cq, dh), jnp.float32)

@@ -27,6 +27,11 @@ Design (TPU-native):
   * GQA is native: k/v index maps fold the q-head -> kv-head group mapping;
     the dkv backward kernel accumulates over the q heads of each group in its
     sequential inner grid dimension.
+  * ``heads=(h0, n)`` runs a kernel on q heads [h0, h0 + n) only (KV heads
+    [h0 / g, (h0 + n) / g)): the inputs are read in place, h0 (traced or not)
+    rides the scalar-prefetch operand beside the chunk offsets, and the
+    outputs hold the n heads.  The FPDT pipeline calls the kernels of a chunk
+    pair this way, one head group per trip of a loop.
 
 On a TPU backend the kernels compile through Mosaic; elsewhere they run with
 interpret=True (pure-Python execution), which is how the CPU tests run them.
@@ -73,14 +78,29 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _offsets_operand(q_offset, k_offset) -> jnp.ndarray:
-    """[q_offset, k_offset] as the int32 scalar-prefetch operand.
+def _offsets_operand(q_offset, k_offset, heads=None) -> jnp.ndarray:
+    """[q_offset, k_offset] (then the first head h0 under ``heads``) as the
+    int32 scalar-prefetch operand.
 
     Accepts Python ints (the u = 1 baseline: offsets are trace-time constants)
     and traced int scalars (scan-compiled FPDT: offsets are loop carries).
     """
-    return jnp.stack([jnp.asarray(q_offset, jnp.int32),
-                      jnp.asarray(k_offset, jnp.int32)])
+    offs = (q_offset, k_offset) + (() if heads is None else (heads[0],))
+    return jnp.stack([jnp.asarray(o, jnp.int32) for o in offs])
+
+
+def _head_window(heads, hq: int, g: int):
+    """(q heads in the grid, map from a grid head index to the operand's).
+
+    ``heads=(h0, n)``: the grid covers n q heads and head h reads operand
+    head h + h0 (from the scalar-prefetch operand); n and h0 keep whole GQA
+    groups.  ``None``: every head, read where it is."""
+    if heads is None:
+        return hq, lambda h, offs: h
+    n = heads[1]
+    if n % g or hq % n:
+        raise ValueError(f"a window of {n} of {hq} q heads splits a GQA group of {g}")
+    return n, lambda h, offs: h + offs[2]
 
 
 def _grid_spec(grid, in_specs, out_specs, scratch_shapes):
@@ -209,15 +229,18 @@ def flash_fwd(
     block_q: int = 512,
     block_k: int = 512,
     interpret: Optional[bool] = None,
+    heads: Optional[tuple] = None,
 ):
     """Unnormalized online attention of q (at q_offset) over k/v (at k_offset).
 
-    Returns (acc, m, l): fp32 running state (continuing ``carry`` if given).
+    Returns (acc, m, l): fp32 running state (continuing ``carry`` if given),
+    of the q heads ``heads=(h0, n)`` selects (all by default).
     """
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
+    nh, qh = _head_window(heads, hq, g)
     interpret = _default_interpret() if interpret is None else interpret
     block_q = _fit_block(sq, block_q, tiled=not interpret)
     block_k = _fit_block(sk, block_k, tiled=not interpret)
@@ -236,29 +259,34 @@ def flash_fwd(
         sm_scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, nk=nk,
     )
-    grid = (b, hq, nq, nk)
-    q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, h, iq, 0))
-    kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, iq, ik, offs: (b_, h // g, ik, 0))
-    row_spec = pl.BlockSpec((None, None, 1, block_q), lambda b_, h, iq, ik, offs: (b_, h, 0, iq))
-    row_shape = jax.ShapeDtypeStruct((b, hq, 1, sq), jnp.float32)
+    grid = (b, nh, nq, nk)
+    q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, qh(h, offs), iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, iq, ik, offs: (b_, qh(h, offs) // g, ik, 0))
+    row_spec = pl.BlockSpec((None, None, 1, block_q), lambda b_, h, iq, ik, offs: (b_, qh(h, offs), 0, iq))
+    if heads is None:
+        q_out, row_out = q_spec, row_spec
+    else:
+        q_out = pl.BlockSpec((None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, h, iq, 0))
+        row_out = pl.BlockSpec((None, None, 1, block_q), lambda b_, h, iq, ik, offs: (b_, h, 0, iq))
+    row_shape = jax.ShapeDtypeStruct((b, nh, 1, sq), jnp.float32)
 
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
             grid,
             [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            [q_spec, row_spec, row_spec],
+            [q_out, row_out, row_out],
             [
                 pltpu.VMEM((block_q, LANES), jnp.float32),
                 pltpu.VMEM((block_q, LANES), jnp.float32),
                 pltpu.VMEM((block_q, d), jnp.float32),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32), row_shape, row_shape],
+        out_shape=[jax.ShapeDtypeStruct((b, nh, sq, d), jnp.float32), row_shape, row_shape],
         interpret=interpret,
         compiler_params=_compiler_params(),
         name=FWD,
-    )(_offsets_operand(q_offset, k_offset), q, k, v, acc0, _rows(m0), _rows(l0))
+    )(_offsets_operand(q_offset, k_offset, heads), q, k, v, acc0, _rows(m0), _rows(l0))
     return acc, _unrows(m), _unrows(l)
 
 
@@ -305,12 +333,14 @@ def _dq_kernel(
 def flash_bwd_dq(
     q, k, v, do, L, delta,
     *, causal=True, window=0, q_offset=0, k_offset=0, sm_scale=None,
-    block_q=512, block_k=512, interpret=None,
+    block_q=512, block_k=512, interpret=None, heads=None,
 ):
-    """dq contribution of this (q-chunk, kv-chunk) pair. fp32 output."""
+    """dq contribution of this (q-chunk, kv-chunk) pair, of the q heads
+    ``heads=(h0, n)`` selects (all by default). fp32 output."""
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv
+    nh, qh = _head_window(heads, hq, g)
     interpret = _default_interpret() if interpret is None else interpret
     block_q = _fit_block(sq, block_q, tiled=not interpret)
     block_k = _fit_block(sk, block_k, tiled=not interpret)
@@ -321,24 +351,26 @@ def flash_bwd_dq(
         _dq_kernel, sm_scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, nk=nk,
     )
-    q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, h, iq, 0))
-    kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, iq, ik, offs: (b_, h // g, ik, 0))
-    row_spec = pl.BlockSpec((None, None, 1, block_q), lambda b_, h, iq, ik, offs: (b_, h, 0, iq))
+    q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, qh(h, offs), iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, iq, ik, offs: (b_, qh(h, offs) // g, ik, 0))
+    row_spec = pl.BlockSpec((None, None, 1, block_q), lambda b_, h, iq, ik, offs: (b_, qh(h, offs), 0, iq))
+    q_out = q_spec if heads is None else pl.BlockSpec(
+        (None, None, block_q, d), lambda b_, h, iq, ik, offs: (b_, h, iq, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
-            (b, hq, nq, nk),
+            (b, nh, nq, nk),
             [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            q_spec,
+            q_out,
             [pltpu.VMEM((block_q, d), jnp.float32),
              pltpu.VMEM((block_q, LANES), jnp.float32),
              pltpu.VMEM((block_q, LANES), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, nh, sq, d), jnp.float32),
         interpret=interpret,
         compiler_params=_compiler_params(),
         name=DQ,
-    )(_offsets_operand(q_offset, k_offset), q, k, v, do, _rows(L), _rows(delta))
+    )(_offsets_operand(q_offset, k_offset, heads), q, k, v, do, _rows(L), _rows(delta))
 
 
 # ===========================================================================
@@ -388,12 +420,16 @@ def _dkv_kernel(
 def flash_bwd_dkv(
     q, k, v, do, L, delta,
     *, causal=True, window=0, q_offset=0, k_offset=0, sm_scale=None,
-    block_q=512, block_k=512, interpret=None,
+    block_q=512, block_k=512, interpret=None, heads=None,
 ):
-    """(dk, dv) contribution of this (q-chunk, kv-chunk) pair (GQA-summed)."""
+    """(dk, dv) contribution of this (q-chunk, kv-chunk) pair (GQA-summed),
+    of the KV heads of the q heads ``heads=(h0, n)`` selects (all by default)."""
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv
+    nh, qh = _head_window(heads, hq, g)
+    # the grid runs over KV heads: a window starts at KV head h0 / g
+    kh = (lambda h, offs: h) if heads is None else (lambda h, offs: h + offs[2] // g)
     interpret = _default_interpret() if interpret is None else interpret
     block_q = _fit_block(sq, block_q, tiled=not interpret)
     block_k = _fit_block(sk, block_k, tiled=not interpret)
@@ -406,25 +442,28 @@ def flash_bwd_dkv(
     )
     # inner sequential dim covers q heads of the kv group x q blocks
     q_spec = pl.BlockSpec(
-        (None, None, block_q, d), lambda b_, h, ik, t, offs: (b_, h * g + t // nq, t % nq, 0)
+        (None, None, block_q, d), lambda b_, h, ik, t, offs: (b_, kh(h, offs) * g + t // nq, t % nq, 0)
     )
-    kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, ik, t, offs: (b_, h, ik, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, h, ik, t, offs: (b_, kh(h, offs), ik, 0))
     row_spec = pl.BlockSpec(
-        (None, None, 1, block_q), lambda b_, h, ik, t, offs: (b_, h * g + t // nq, 0, t % nq)
+        (None, None, 1, block_q), lambda b_, h, ik, t, offs: (b_, kh(h, offs) * g + t // nq, 0, t % nq)
     )
+    kv_out = kv_spec if heads is None else pl.BlockSpec(
+        (None, None, block_k, d), lambda b_, h, ik, t, offs: (b_, h, ik, 0))
+    nkv = nh // g
     return pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
-            (b, hkv, nk, g * nq),
+            (b, nkv, nk, g * nq),
             [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            [kv_spec, kv_spec],
+            [kv_out, kv_out],
             [pltpu.VMEM((block_k, d), jnp.float32), pltpu.VMEM((block_k, d), jnp.float32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, nkv, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, nkv, sk, d), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
         name=DKV,
-    )(_offsets_operand(q_offset, k_offset), q, k, v, do, _rows(L), _rows(delta))
+    )(_offsets_operand(q_offset, k_offset, heads), q, k, v, do, _rows(L), _rows(delta))

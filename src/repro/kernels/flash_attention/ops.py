@@ -18,7 +18,8 @@ plus ``flash_attention`` — a fused single-call attention with custom VJP.
 int scalars (the scan-compiled pipeline passes loop-carried chunk offsets):
 the xla/ref paths consume them as ordinary values and the Pallas kernels
 take them as a scalar-prefetch operand.  Shapes and block sizes stay
-static.
+static.  ``heads=(h0, n)`` (Pallas only) runs a chunk primitive on q heads
+[h0, h0 + n) of its operands, read in place; its outputs hold those heads.
 """
 from __future__ import annotations
 
@@ -155,11 +156,12 @@ def _xla_chunk_bwd_dkv(q, k, v, do, L, delta, *, causal, window, q_offset, k_off
 
 
 def chunk_fwd(q, k, v, carry=None, *, causal=True, window=0, q_offset=0, k_offset=0,
-              sm_scale=None, block_q=512, block_k=512, impl="pallas"):
+              sm_scale=None, block_q=512, block_k=512, impl="pallas", heads=None):
     if impl == "pallas":
         return _k.flash_fwd(q, k, v, carry, causal=causal, window=window,
                             q_offset=q_offset, k_offset=k_offset, sm_scale=sm_scale,
-                            block_q=block_q, block_k=block_k)
+                            block_q=block_q, block_k=block_k, heads=heads)
+    assert heads is None, "a head window needs impl='pallas'"
     if impl == "xla_flash":
         return _xla_chunk_fwd(q, k, v, carry, causal=causal, window=window,
                               q_offset=q_offset, k_offset=k_offset,
@@ -171,22 +173,24 @@ def chunk_fwd(q, k, v, carry=None, *, causal=True, window=0, q_offset=0, k_offse
 
 
 def chunk_bwd_dq(q, k, v, do, L, delta, *, causal=True, window=0, q_offset=0, k_offset=0,
-                 sm_scale=None, block_q=512, block_k=512, impl="pallas"):
+                 sm_scale=None, block_q=512, block_k=512, impl="pallas", heads=None):
     if impl == "pallas":
         return _k.flash_bwd_dq(q, k, v, do, L, delta, causal=causal, window=window,
                                q_offset=q_offset, k_offset=k_offset, sm_scale=sm_scale,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k, heads=heads)
+    assert heads is None, "a head window needs impl='pallas'"
     return _xla_chunk_bwd_dq(q, k, v, do, L, delta, causal=causal, window=window,
                              q_offset=q_offset, k_offset=k_offset, sm_scale=sm_scale,
                              block_k=block_k)
 
 
 def chunk_bwd_dkv(q, k, v, do, L, delta, *, causal=True, window=0, q_offset=0, k_offset=0,
-                  sm_scale=None, block_q=512, block_k=512, impl="pallas"):
+                  sm_scale=None, block_q=512, block_k=512, impl="pallas", heads=None):
     if impl == "pallas":
         return _k.flash_bwd_dkv(q, k, v, do, L, delta, causal=causal, window=window,
                                 q_offset=q_offset, k_offset=k_offset, sm_scale=sm_scale,
-                                block_q=block_q, block_k=block_k)
+                                block_q=block_q, block_k=block_k, heads=heads)
+    assert heads is None, "a head window needs impl='pallas'"
     return _xla_chunk_bwd_dkv(q, k, v, do, L, delta, causal=causal, window=window,
                               q_offset=q_offset, k_offset=k_offset, sm_scale=sm_scale,
                               block_q=block_q)

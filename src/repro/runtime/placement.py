@@ -205,29 +205,42 @@ def fori_double_buffered(lo, hi, fetch: Callable, body: Callable, init,
     value consumed at iteration ``idx`` is carried in the loop state and the
     *next* consumed chunk's fetch is issued *before* ``body(idx)``'s kernels
     in program order, so on offload-capable backends the host->device copy
-    of the next chunk overlaps the current chunk's compute explicitly
-    instead of relying on XLA to discover the independence.
+    of the next chunk can overlap the current chunk's compute.  Whether it
+    does is the compiler's schedule: XLA's TPU scheduler overlaps a host
+    transfer with a loop but not with a Mosaic kernel in the same
+    computation, so a ``body`` whose work is Pallas kernels runs them inside
+    a loop of its own (``core/fpdt.py``).
 
     Carry contract (everything rides a ``fori_loop`` state, so all of it
     must be shape/dtype-stable across iterations):
       * the loop state is ``(prefetch_buffer, carry)``; ``fetch(idx)`` must
         return the same pytree structure/shapes/dtypes for every ``idx``
         (it is probed once via ``jax.eval_shape`` on the live path);
-      * ``init`` must match the structure ``body`` returns — ``body`` is
-        traced once and may not change the carry's shape;
+      * ``init`` must match the structure ``body`` returns — ``body`` may
+        not change the carry's shape;
       * ``fetch``/``body``/``live`` must be pure; ``fetch`` runs under
         ``lax.cond`` on the live path, so its placement ops must be legal
         in traced context (``device_put`` with memory-kind shardings is).
-    Returns the final user carry (the prefetch buffer is discarded; the
-    tail iteration's clamped prefetch is never consumed).
+    Returns the final user carry.
+
+    Without ``live`` every index is consumed, and ``fetch`` runs exactly
+    ``hi - lo`` times, with no ``lax.cond`` inside the loop: the loop runs
+    ``[lo, hi - 1)`` and the last index after it, so no tail prefetch is
+    issued.  An empty range returns ``init`` without a fetch (a traced
+    range through one ``lax.cond`` around the whole).
 
     ``live(idx) -> bool tracer`` optionally restricts the schedule to live
     indices: dead (window/sparsity-skipped) iterations are complete no-ops
     — no fetch, no body — and each live iteration prefetches the next
     *live* index (a traced search), so
     sparse schedules keep the copy/compute overlap instead of issuing
-    fetches from skipped iterations that overlap nothing.
+    fetches from skipped iterations that overlap nothing.  Here fetches
+    run under ``lax.cond`` and the tail iteration's clamped prefetch is
+    never consumed.
     """
+    static = isinstance(lo, int) and isinstance(hi, int)
+    if live is None and static and lo >= hi:
+        return init
     lo = jnp.asarray(lo, jnp.int32)
     hi = jnp.asarray(hi, jnp.int32)
 
@@ -237,13 +250,15 @@ def fori_double_buffered(lo, hi, fetch: Callable, body: Callable, init,
     if live is None:
         def step(idx, state):
             buf, carry = state
-            nxt = fetch(clamp(idx + 1))  # clamped tail prefetch: never consumed
-            carry = body(idx, buf, carry)
-            return nxt, carry
+            nxt = fetch(idx + 1)
+            return nxt, body(idx, buf, carry)
 
-        buf0 = fetch(clamp(lo))
-        _, carry = jax.lax.fori_loop(lo, hi, step, (buf0, init))
-        return carry
+        def run():
+            # clamp(lo) is lo on a non-empty range; a traced index keeps the
+            # first fetch's op_name in the compiled program
+            buf, carry = jax.lax.fori_loop(lo, hi - 1, step, (fetch(clamp(lo)), init))
+            return body(hi - 1, buf, carry)
+        return run() if static else jax.lax.cond(hi > lo, run, lambda: init)
 
     def next_live(idx):
         """Smallest live index in (idx, hi); hi when none (live() must be

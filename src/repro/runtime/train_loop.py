@@ -143,14 +143,25 @@ class TrainLoop:
                 pass  # not main thread
 
     def _record_schedule(self, batch):
-        """Gauge ``fpdt_fwd_kv_fetches``: the KV chunk fetches one forward
-        pass of one attention layer issues on the scan-compiled FPDT path."""
+        """Gauges of one global attention layer on the scan-compiled FPDT
+        path: ``fpdt_fwd_kv_fetches``, the KV chunk fetches of one forward
+        pass, and of those and of the Fig. 7 q chunk fetches the ones issued
+        on the overlapped path (``fpdt_fwd_kv_fetches_overlapped``,
+        ``fpdt_bwd_q_fetches_overlapped``; ``fpdt.overlapped_fetches``)."""
         cfg = self.cfg
         u = cfg.fpdt_chunks
         if u > 1 and "attn" in cfg.layer_kinds():
             cq = batch["tokens"].shape[1] // u
-            self.telemetry.registry.gauge("fpdt_fwd_kv_fetches").set(
+            gauge = self.telemetry.registry.gauge
+            gauge("fpdt_fwd_kv_fetches").set(
                 fpdt.fwd_kv_fetches(u, cq=cq, sparsity=cfg.attn_sparsity))
+            par = self.par if self.par is not None else ParallelContext(mesh=None)
+            fwd, bwd = fpdt.overlapped_fetches(
+                u, cq=cq, sparsity=cfg.attn_sparsity,
+                head_groups=fpdt.head_groups(par, T.attn_kind(cfg, self.par), cfg.num_kv_heads),
+                offload=cfg.fpdt_offload and par.offload_to_host)
+            gauge("fpdt_fwd_kv_fetches_overlapped").set(fwd)
+            gauge("fpdt_bwd_q_fetches_overlapped").set(bwd)
 
     def run(self, params, opt_state, start_step: int = 0, put_batch=None,
             on_step: Optional[Callable[[int, Any, Dict], None]] = None):
@@ -162,7 +173,7 @@ class TrainLoop:
         profiler trace puts the host's phases beside the device's ops.  A
         jitted ``step_fn`` that compiles a new program counts it through
         ``Telemetry.compile_event("train_step")``; the first batch sets the
-        gauge ``fpdt_fwd_kv_fetches`` (``_record_schedule``)."""
+        FPDT schedule's gauges (``_record_schedule``)."""
         self._install_signals()
         if put_batch is None:
             # default batch staging routes through the placement layer

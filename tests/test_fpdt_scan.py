@@ -114,6 +114,44 @@ def test_grouped_forward_equals_dense(setup, u, seq, window, sparsity, impl):
     _assert_matches_dense(cfg, p, x, do, u, True, impl, window=window, sparsity=sparsity)
 
 
+@pytest.mark.parametrize("u", [2, 3, 4, 6])
+def test_overlapped_path_equals_dense(setup, u):
+    """A dense schedule with the Pallas kernels takes the overlapped path:
+    each chunk pair's kernels in a loop over two head groups
+    (``fpdt.head_groups``), the last chunk's prefetch not issued, and no
+    ``lax.cond`` but one around each chunk loop, which skips an empty range
+    (the forward's KV loop, Fig. 7's q loop).  Outputs and every grad equal
+    the dense reference's; a window puts a ``lax.cond`` around each pair."""
+    cfg, p, _, _ = setup
+    assert fpdt.head_groups(ParallelContext(mesh=None), "local", cfg.num_kv_heads) == 2
+    key = jax.random.PRNGKey(13)
+    seq = 8 * u
+    x = jax.random.normal(key, (2, seq, cfg.d_model), jnp.float32)
+    do = jax.random.normal(jax.random.fold_in(key, 1), (2, seq, cfg.q_dim), jnp.float32)
+    _assert_matches_dense(cfg, p, x, do, u, True, "pallas")
+
+    def conds(window):  # lax.cond outside the kernels' own bodies
+        c = dataclasses.replace(cfg, fpdt_chunks=u, fpdt_offload=True, block_q=8, block_k=8)
+        par = ParallelContext(mesh=None, attn_impl="pallas")
+        return _primitives(jax.make_jaxpr(jax.grad(lambda x: fpdt.fpdt_attention(
+            c, par, p, x, kind="local", window=window).sum()))(x).jaxpr).count("cond")
+
+    assert conds(0) == 2 and conds(12) > 2
+
+
+def _primitives(jaxpr):
+    """Primitive names of a jaxpr and the jaxprs nested in it, except inside
+    ``pallas_call`` (a kernel's own control flow)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _primitives(sub)
+    return out
+
+
 def test_scan_equals_baseline(setup):
     """Transitivity anchor: scan path vs the u=1 un-chunked baseline."""
     cfg, p, x, do = setup

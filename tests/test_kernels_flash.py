@@ -99,3 +99,33 @@ def test_causality_property(rng):
     np.testing.assert_allclose(np.asarray(base[:, :, :20]), np.asarray(pert[:, :, :20]),
                                rtol=1e-6, atol=1e-6)
     assert not np.allclose(np.asarray(base[:, :, 21:]), np.asarray(pert[:, :, 21:]))
+
+
+@pytest.mark.parametrize("hq,hkv,n", [(4, 2, 2), (4, 4, 1), (8, 2, 4), (4, 1, 4)])
+def test_head_window_equals_full_call(rng, hq, hkv, n):
+    """``heads=(h0, n)``: each kernel run on a window of q heads (h0 traced)
+    returns exactly those heads of the full call, GQA groups kept whole."""
+    b, sq, d = 2, 32, 16
+    q, k, v = _mk(rng, b, hq, hkv, sq, sq, d, jnp.float32)
+    do = jnp.asarray(rng.standard_normal((b, hq, sq, d)), jnp.float32)
+    acc0 = jnp.asarray(rng.standard_normal((b, hq, sq, d)), jnp.float32)
+    m0 = jnp.asarray(rng.standard_normal((b, hq, sq)), jnp.float32)
+    l0 = jnp.asarray(rng.uniform(1.0, 2.0, (b, hq, sq)), jnp.float32)
+    L = jnp.asarray(rng.standard_normal((b, hq, sq)), jnp.float32)
+    delta = jnp.asarray(rng.standard_normal((b, hq, sq)), jnp.float32)
+    kw = dict(causal=True, q_offset=32, k_offset=16, block_q=8, block_k=8)
+    g = hq // hkv
+
+    def calls(heads):
+        return (K.flash_fwd(q, k, v, (acc0, m0, l0), heads=heads, **kw),
+                K.flash_bwd_dq(q, k, v, do, L, delta, heads=heads, **kw),
+                K.flash_bwd_dkv(q, k, v, do, L, delta, heads=heads, **kw))
+
+    full = calls(None)
+    win = jax.jit(lambda h0: calls((h0, n)))
+    for h0 in range(0, hq, n):
+        for got, want in zip(jax.tree.leaves(win(h0)), jax.tree.leaves(full)):
+            sl = slice(h0, h0 + n) if got.shape[1] == n else slice(h0 // g, (h0 + n) // g)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want[:, sl]))
+    with pytest.raises(ValueError, match="GQA group"):  # half a group, or 3 of 4 heads
+        K.flash_fwd(q, k, v, heads=(0, g // 2 if g > 1 else 3), **kw)

@@ -6,6 +6,7 @@ token-level mask: a chunk pair is live iff at least one (q, k) token pair
 inside it survives the causal+window band.  Runs under real hypothesis when
 installed, else the deterministic fixed grid (tests/_hypothesis_compat.py).
 """
+import functools
 import itertools
 
 import jax.numpy as jnp
@@ -13,7 +14,8 @@ import numpy as np
 
 from _hypothesis_compat import given, settings, strategies as st
 
-from repro.core.fpdt import fwd_kv_fetches, pair_live, pair_live_traced, sparsity_stride
+from repro.core.fpdt import (fwd_kv_fetches, overlapped_fetches, pair_live, pair_live_traced,
+                             sparsity_stride)
 
 
 def _dense_window_live(i, j, cq, window):
@@ -99,3 +101,25 @@ def test_grouped_fetches_never_exceed_one_chunk():
             range(2, 17), (4, 8), (0, 1, 5, 9, 12), (0.0, 0.5, 0.67)):
         assert fwd_kv_fetches(u, cq=cq, window=window, sparsity=sparsity) <= \
             _one_chunk_fetches(u, cq, window, sparsity), (u, cq, window, sparsity)
+
+
+def test_overlapped_fetches():
+    """Dense u=16 (the 64k cell): 49 of the forward's 56 KV fetches a pass and
+    120 of Fig. 7's 136 q fetches are issued while a chunk pair's kernels
+    run, every fetch but each loop's first; u=4: 1 of 2 and 6 of 10.  A
+    window or sparsity schedule, u=1, one head group and no offload take no
+    such path."""
+    over = functools.partial(overlapped_fetches, head_groups=2, offload=True)
+    assert over(16, cq=4096) == (49, 120)
+    assert sum(16 - j for j in range(16)) == 136
+    assert over(4, cq=8) == (1, 6)
+    for u in range(2, 17):
+        fwd, bwd = over(u, cq=8)
+        loops = len([i0 for i0 in range(0, u, 2 if u % 2 == 0 else 1) if i0])
+        assert fwd == fwd_kv_fetches(u, cq=8) - loops
+        assert bwd == sum(u - j for j in range(u)) - u
+    assert over(1, cq=8) == (0, 0)
+    assert over(16, cq=4096, window=4096) == (0, 0)
+    assert over(16, cq=4096, sparsity=0.5) == (0, 0)
+    assert overlapped_fetches(16, cq=4096, head_groups=1, offload=True) == (0, 0)
+    assert overlapped_fetches(16, cq=4096, head_groups=2, offload=False) == (0, 0)

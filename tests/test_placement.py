@@ -146,3 +146,46 @@ def test_fpdt_offload_matches_baseline_without_pinned_memory():
     o4 = run(4, True)  # seed: ValueError before any math on this backend
     np.testing.assert_allclose(np.asarray(o4), np.asarray(o1),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# fori_double_buffered without ``live``: every index fetched once, no tail
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("lo,hi,traced", [(0, 5, False), (0, 5, True), (3, 4, True),
+                                          (2, 2, False), (4, 2, False), (2, 2, True),
+                                          (4, 2, True)])
+def test_dense_double_buffering_fetches_each_index_once(lo, hi, traced):
+    """Without ``live`` ``fetch`` runs exactly hi - lo times, for lo..hi-1 in
+    order, with no ``lax.cond`` inside the loop (no clamped tail fetch);
+    each body sees its own index's buffer.  An empty range fetches nothing
+    and returns ``init``: a traced range through the one ``lax.cond`` around
+    the loop, a range of Python ints without it."""
+    from repro.runtime.placement import fori_double_buffered
+
+    fetched = []
+
+    def fetch(idx):
+        jax.debug.callback(lambda i: fetched.append(int(i)), idx, ordered=True)
+        return jnp.stack([idx, 10 * idx])
+
+    def body(idx, buf, carry):
+        total, wrong = carry
+        return total + buf[1], wrong + (buf[0] != idx)
+
+    def run(lo, hi):
+        return fori_double_buffered(lo, hi, fetch, body, (jnp.int32(0), jnp.int32(0)))
+
+    if traced:
+        jaxpr = jax.make_jaxpr(run)(lo, hi).jaxpr
+        (guard,) = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+        (loop,) = [e for b in guard.params["branches"] for e in b.jaxpr.eqns
+                   if e.primitive.name == "while"]
+        assert "cond[" not in str(loop.params["body_jaxpr"])
+        total, wrong = jax.jit(run)(lo, hi)
+    else:
+        total, wrong = run(lo, hi)
+    jax.effects_barrier()
+    assert fetched == list(range(lo, hi))
+    assert int(total) == 10 * sum(range(lo, hi)) and int(wrong) == 0

@@ -484,3 +484,33 @@ def test_train_loop_gauges_fpdt_forward_fetches():
              put_batch=lambda b: {k: jnp.asarray(v) for k, v in b.items()})
     assert loop.telemetry.registry.value("fpdt_fwd_kv_fetches") == \
         fpdt.fwd_kv_fetches(4, cq=8) == 2
+    assert loop.telemetry.registry.value("fpdt_fwd_kv_fetches_overlapped") == 1
+    assert loop.telemetry.registry.value("fpdt_bwd_q_fetches_overlapped") == 6
+
+
+@pytest.mark.parametrize("sparsity,impl,offload,want", [
+    (0.0, "pallas", True, (56, 49, 120)), (0.5, "pallas", True, (56, 0, 0)),
+    (0.0, "xla_flash", True, (56, 0, 0)), (0.0, "pallas", False, (56, 0, 0))])
+def test_train_loop_gauges_fpdt_overlapped_fetches(sparsity, impl, offload, want):
+    """The 64k cell's schedule, u=16: the first batch sets
+    ``fpdt_fwd_kv_fetches_overlapped`` and ``fpdt_bwd_q_fetches_overlapped``
+    to 49 and 120 on a dense offloaded schedule with the Pallas kernels
+    (two head groups), and to 0 on a sparse one, with the XLA kernels (no
+    head loop) and without offload."""
+    import jax.numpy as jnp
+
+    from repro.configs import ShapeConfig, get_config, reduced
+    from repro.core.parallel import ParallelContext
+    from repro.data.pipeline import CheckpointableIterator, make_batch_fn
+    from repro.optim import adamw
+    from repro.runtime.train_loop import TrainConfig, TrainLoop
+
+    cfg = dataclasses.replace(reduced(get_config("llama3.2-1b")), num_layers=1,
+                              fpdt_chunks=16, fpdt_offload=offload, attn_sparsity=sparsity)
+    loop = TrainLoop(cfg, ParallelContext(mesh=None, attn_impl=impl), adamw.OptConfig(),
+                     TrainConfig(steps=1), None,
+                     CheckpointableIterator(make_batch_fn(cfg, ShapeConfig("t", 64, 1, "train"))))
+    loop._record_schedule({"tokens": jnp.zeros((1, 16 * 4096), jnp.int32)})
+    value = loop.telemetry.registry.value
+    assert (value("fpdt_fwd_kv_fetches"), value("fpdt_fwd_kv_fetches_overlapped"),
+            value("fpdt_bwd_q_fetches_overlapped")) == want

@@ -207,6 +207,117 @@ def test_fpdt_train_step_has_no_host_executed_copies(one_chip):
     assert stores and all("S(5)" in i[3] for i in stores)
 
 
+def _computations(hlo):
+    """{computation: [(name, opcode, line)]}, instructions in schedule order."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%(\S+) .*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            m = re.match(r"\s*(?:ROOT )?%(\S+) = .*?[\]\}\)] ([a-z][a-z0-9-]*)\(", line)
+            if m:
+                cur.append((m.group(1), m.group(2), line))
+    return comps
+
+
+def _called(line):
+    """Computations a while, call or conditional instruction runs."""
+    names = re.findall(r"(?:body|condition|to_apply|true_computation|false_computation)=%([\w.\-]+)",
+                       line)
+    m = re.search(r"branch_computations=\{([^}]*)\}", line)
+    return names + (re.findall(r"%([\w.\-]+)", m.group(1)) if m else [])
+
+
+def _host_fetches(hlo):
+    """(op_name, flash kernels it overlaps) of every host fetch: an async
+    dynamic-slice-start that reads pinned host memory (``S(5)``).  It
+    overlaps the flash kernels scheduled between it and its done, directly
+    or inside a while, call or conditional scheduled there."""
+    comps = _computations(hlo)
+    memo = {}
+
+    def kernels(name, line):
+        if 'custom_call_target="tpu_custom_call"' in line and name.startswith("flash_attention"):
+            return {name.split(".")[0]}
+        out = set()
+        for c in _called(line):
+            if c not in memo:
+                memo[c] = set()  # cycle guard
+                memo[c] = set().union(*(kernels(n, l) for n, _, l in comps.get(c, ())))
+            out |= memo[c]
+        return out
+
+    fetches = []
+    for ins in comps.values():
+        for k, (name, opcode, line) in enumerate(ins):
+            if opcode != "dynamic-slice-start" or "S(5)" not in line:
+                continue
+            done = next(j for j, (_, op2, l2) in enumerate(ins)
+                        if op2 == "dynamic-slice-done" and f"(%{name})" in l2)
+            op = re.search(r'op_name="([^"]*)"', line)
+            fetches.append((op.group(1) if op else "",
+                            set().union(*(kernels(n, l) for n, _, l in ins[k + 1:done]))))
+    return fetches
+
+
+def test_fpdt_dense_fetches_overlap_flash_kernels(one_chip):
+    """A 2-layer dense train step, u=8, offload on, remat full: every host
+    fetch a dense schedule issues inside a double-buffered chunk loop (two
+    loops deep in the FPDT function) has a flash kernel scheduled between
+    its start and its done -- the KV chunk fetch of the forward and of its
+    recomputation, the q chunk fetch of the Fig. 7 backward -- and the
+    loops' first fetches (prologues) are the only others besides the Fig. 7
+    KV prefetch.  The schedule holds sites, not counts: each in-loop site
+    runs once per loop trip past the first, which ``overlapped_fetches``
+    counts.  (At u=4 the forward has one group with a one-trip KV loop, and
+    XLA flattens it: no loop is left to overlap.)"""
+    from repro.configs import ModelConfig
+    from repro.core import fpdt
+    from repro.core.parallel import ParallelContext
+    from repro.models import transformer as T
+    from repro.optim import adamw
+    from repro.runtime import train_loop
+    from repro.runtime.placement import PlacementPolicy
+
+    u, seq = 8, 8192
+    cfg = ModelConfig(name="dense-2l", family="dense", num_layers=2, d_model=512,
+                      num_heads=4, num_kv_heads=4, head_dim=128, d_ff=2048,
+                      vocab_size=1024, fpdt_chunks=u, mlp_chunks=2 * u,
+                      fpdt_offload=True, remat="full")
+    par = ParallelContext(mesh=None, attn_impl="pallas",
+                          placement=PlacementPolicy.probe(one_chip))
+    oc = adamw.OptConfig()
+    params = jax.eval_shape(lambda k: T.init_params(cfg, k), jax.random.PRNGKey(0))
+    opt = jax.eval_shape(lambda p: adamw.init(oc, p), params)
+    params, opt = (jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), t)
+                   for t in (params, opt))
+    batch = {k: _sds(one_chip, (1, seq), jnp.int32) for k in ("tokens", "labels")}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(FK, "_default_interpret", lambda: False)
+        fetches = _host_fetches(_hlo(train_loop.make_train_step(cfg, par, oc), params, opt, batch))
+
+    def loops(op_name):  # loops around the fetch inside the FPDT function
+        return op_name.partition("jit(f)/")[2].count("while/body")
+
+    def fig7(op_name):  # the backward proper, not the forward it recomputes
+        return "transpose" in op_name and "rematted_computation" not in op_name
+
+    in_loop = [(op, ks) for op, ks in fetches if loops(op) >= 2]
+    # K and V of the forward and of its recomputation; q of Fig. 7
+    assert sorted(sorted(ks) for op, ks in in_loop if not fig7(op)) == [[FK.FWD]] * 4
+    assert [ks for op, ks in in_loop if fig7(op)] == [{FK.DQ, FK.DKV}]
+    # the rest: K and V prefetched by Fig. 7's outer loop, and the prologues
+    # (the forward's K and V in two passes, Fig. 7's first q, K and V)
+    assert len(fetches) - len(in_loop) == 2 + 4 + 3
+    # the sites the helper counts, from the compiled step's own settings
+    assert fpdt.overlapped_fetches(
+        u, cq=seq // u, head_groups=fpdt.head_groups(par, "local", cfg.num_kv_heads),
+        offload=cfg.fpdt_offload and par.offload_to_host) == (9, 28)
+
+
 def test_paged_serve_keeps_host_pool_off_device(one_chip):
     """The host-streamed paged serve programs at llama3.2-1b widths: the
     pool's K/V enter and leave in pinned host memory, and no program
